@@ -51,9 +51,6 @@ def main():
         )
 
     import jax
-
-    if args.virtual_devices:
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
     import optax

@@ -6,8 +6,8 @@
 # local-cluster[2,1,1024] run mirroring the reference's 2-worker
 # Standalone posture, /root/reference/test/run_tests.sh:16-27) only
 # executes where pyspark + a JDK are present: CI, or any dev machine
-# via this script.  The produced ci_logs/spark_*.log is the artifact
-# STATUS.md points to; CI uploads the same log as `spark-e2e-log`.
+# via this script.  CI uploads the produced ci_logs/spark_*.log as
+# `spark-e2e-log`.
 #
 # Usage: scripts/run_spark_suite.sh   (from the repo root)
 set -euo pipefail

@@ -83,6 +83,8 @@ class ClusterMonitor(object):
         self._first_dead = {}
         self._known_gen = {}
         self._error_peek = error_peek  # fn(node_meta) -> str | None
+        #: silence is not judged before this monotonic time (see _tick)
+        self._blind_until = 0.0
         self._stop = threading.Event()
         self._thread = None
 
@@ -98,13 +100,37 @@ class ClusterMonitor(object):
         return self.server.liveness.interval
 
     def _run(self):
-        while not self._stop.wait(self.interval / 2.0):
+        period = self.interval / 2.0
+        last = time.monotonic()
+        while not self._stop.wait(period):
+            now = time.monotonic()
+            self._tick(now - last - period)
+            last = now
             try:
                 self._poll()
             except Exception:  # noqa: BLE001 - monitor must not die quiet
                 logger.warning("cluster monitor poll failed", exc_info=True)
             if self.error is not None:
                 return
+
+    def _tick(self, overslept):
+        """Account for a stall of THIS process.  When the monitor itself
+        lost more than a heartbeat interval — the whole host frozen
+        (initialising a TPU stalls every process of the VM for ~6 s,
+        measured on a v5e host, CHANGES.md PR 21), a long GC pause, a
+        suspended laptop — the beats of that span sit unread in the
+        server's socket, or were never sent by nodes frozen alongside.
+        Silence seen through a stall is not evidence of death: judge it
+        again only after a full deadline of running normally."""
+        if overslept > self.interval:
+            self._blind_until = (
+                time.monotonic() + self.server.liveness.deadline
+            )
+            logger.warning(
+                "cluster monitor was stalled for %.1fs; not judging "
+                "heartbeat silence for the next %.1fs",
+                overslept, self.server.liveness.deadline,
+            )
 
     def _poll(self):
         snapshot = self.server.liveness.snapshot()
@@ -138,6 +164,8 @@ class ClusterMonitor(object):
                 logger.info("monitor: executor %d recovered", eid)
                 self._first_dead.pop(eid)
         for eid, diag in dead.items():
+            if diag.get("silent") and now < self._blind_until:
+                continue
             if not self.elastic:
                 self._fail(eid, diag)
                 return
@@ -1265,7 +1293,12 @@ def run(
       eval_node: dedicate one node as ``'evaluator'``
         (reference: TFCluster.py:236).
       num_chips_per_node: TPU chips visible per node (replaces the
-        reference's ``num_gpus``-via-resources allocation).
+        reference's ``num_gpus``-via-resources allocation).  Required
+        when several compute executors share one TPU host (executors x
+        chips must tile the host's four chips; the processes then form
+        one slice through ``ctx.initialize_distributed()``) — leaving
+        it unset there raises
+        :class:`~tensorflowonspark_tpu.cluster.tpu_info.ChipLayoutError`.
       elastic: treat worker death as a recoverable event: the node's
         supervisor respawns the compute process under a new rendezvous
         generation, survivors park/respawn at the re-rendezvous barrier,
@@ -1450,6 +1483,12 @@ def run(
         cluster_info = server.await_reservations(
             status=_HandleStatus(handle), timeout=reservation_timeout
         )
+        # fail fast, by name, on a layout whose compute processes would
+        # fight over a host's chips (every node refuses it too, from
+        # the same cluster_info, before spawning its compute process)
+        from tensorflowonspark_tpu.cluster import tpu_info
+
+        tpu_info.check_chip_layout(cluster_info, num_chips_per_node)
     except Exception:
         for shard in driver_ps:
             shard.stop()

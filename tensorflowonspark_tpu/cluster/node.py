@@ -405,8 +405,15 @@ def _compute_process_main(fn_bytes, args, ctx):
     except ImportError:  # pragma: no cover
         import pickle as _cp
 
+    from tensorflowonspark_tpu.utils.compile_cache import (
+        ensure_compile_cache,
+    )
     from tensorflowonspark_tpu.utils.retry import retry_call
 
+    # this process owns the node's chips: give its compiles the
+    # program-wide persistent cache before the user fn's first jit (a
+    # cluster start — or a supervisor respawn — otherwise compiles cold)
+    ensure_compile_cache()
     authkey = bytes.fromhex(ctx.manager_authkey)
     multiprocessing.current_process().authkey = authkey
     # a freshly spawned (or supervisor-respawned) compute process can
@@ -623,10 +630,18 @@ def run(fn, args, cluster_meta, input_mode, log_dir=None, tensorboard=False):
         # moral equivalent of the reference's TF gRPC port,
         # TFSparkNode.py:330-335): bound now so it can't be stolen
         # between registration and jax.distributed.initialize.
-        coord_sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-        coord_sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-        coord_sock.bind(("", 0))
-        coord_port = coord_sock.getsockname()[1]
+        # ... and a second one for libtpu's own slice-builder, used when
+        # co-hosted compute processes split a host's chips into one
+        # slice (tpu_info.set_visible_chips)
+        held_socks = []
+        for _ in range(2):
+            sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+            sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+            sock.bind(("", 0))
+            held_socks.append(sock)
+        coord_port, tpu_port = (
+            sock.getsockname()[1] for sock in held_socks
+        )
 
         # tensorboard on exactly one node: the chief when one exists, else
         # worker:0 (reference: TFSparkNode.py:260-297; the reference's
@@ -654,6 +669,7 @@ def run(fn, args, cluster_meta, input_mode, log_dir=None, tensorboard=False):
             "addr": list(adv_addr),
             "authkey": authkey.hex(),
             "port": coord_port,
+            "tpu_port": tpu_port,
             "tb_pid": tb_pid,
             "tb_port": tb_port,
             "device_info": _safe_device_info(),
@@ -677,21 +693,36 @@ def run(fn, args, cluster_meta, input_mode, log_dir=None, tensorboard=False):
         # node's position among same-host nodes, not the global task_index
         # (reference: TFSparkNode.py:149-207 + gpu_info.py:74-86).
         # Visibility env vars are set before the compute process spawns.
+        # A layout whose processes would fight over the chips is refused
+        # here, by name, on every node (the driver raises the same error
+        # from the same cluster_info — see cluster.run).
         num_chips = cluster_meta.get("num_chips_per_node")
-        if num_chips:
+        tpu_info.check_chip_layout(cluster_info, num_chips)
+        if num_chips and not is_service_node:
             cohosted = sorted(
-                n["executor_id"] for n in cluster_info if n["host"] == host
+                (n for n in cluster_info
+                 if n["host"] == host
+                 and n["job_name"] in ("chief", "master", "worker")),
+                key=lambda n: n["executor_id"],
             )
-            local_rank = cohosted.index(executor_id)
+            local_rank = [n["executor_id"] for n in cohosted].index(
+                executor_id
+            )
             tpu_info.set_visible_chips(
-                tpu_info.get_chips(num_chips, worker_index=local_rank)
+                tpu_info.get_chips(num_chips, worker_index=local_rank),
+                process_index=local_rank,
+                process_ports=(
+                    [n["tpu_port"] for n in cohosted]
+                    if len(cohosted) > 1 else None
+                ),
             )
 
-        # The coordination port was held only across the registration
-        # barrier so no co-located node could grab it; release it now —
-        # jax.distributed.initialize (or a user server) must be able to
-        # bind it from the compute process.
-        coord_sock.close()
+        # The ports were held only across the registration barrier so
+        # no co-located node could grab them; release them now —
+        # jax.distributed.initialize / libtpu (or a user server) must be
+        # able to bind them from the compute process.
+        for sock in held_socks:
+            sock.close()
 
         ctx = NodeContext(
             executor_id=executor_id,
@@ -1067,11 +1098,10 @@ def train(cluster_info, cluster_meta, feed_timeout=600, qname="input"):
                 return 0
             return total
 
-        # Ring-vs-queue policy (measured, BASELINE.md 'spark feed'):
-        # at image-scale rows the shm ring sustains ~3.9x the queue,
-        # but at kilobyte rows the e2e pipeline is consumer-bound and
-        # the ring's extra encode/decode buys nothing (~0.95x within
-        # jitter) — so blocks whose rows are below the threshold ship
+        # Ring-vs-queue policy: at image-scale rows the shm ring's
+        # zero-copy path carries the feed, but at kilobyte rows the e2e
+        # pipeline is consumer-bound and the ring's extra encode/decode
+        # buys nothing — so blocks whose rows are below the threshold ship
         # via the queue even when the ring is up.  TFOS_SHM_FEED=force
         # pins the ring for every block (benchmarks; threshold tuning).
         ring_min_row = int(

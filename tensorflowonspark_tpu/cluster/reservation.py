@@ -184,6 +184,7 @@ class Liveness(object):
                 elif age > self.deadline:
                     out[eid] = {
                         "age": age,
+                        "silent": True,  # inferred, not reported
                         "reason": (
                             "no heartbeat for {0:.1f}s "
                             "(> {1} x {2:.1f}s interval)".format(

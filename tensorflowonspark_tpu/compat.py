@@ -2,11 +2,11 @@
 
 The reference's ``compat.py`` papered over TF 2.0/2.1 API drift
 (``export_saved_model``, ``disable_auto_shard``, ``is_gpu_available`` —
-reference: tensorflowonspark/compat.py:10-31).  The JAX surface this
-framework uses is stable, so the shims here are thin by design: a
-chief-aware export helper matching the reference's calling convention,
-an accelerator probe, and a no-op kept for source compatibility with
-code ported from the reference.
+reference: tensorflowonspark/compat.py:10-31).  This build targets ONE
+installed JAX (0.9.x), so nothing here probes for API spellings: a
+chief-aware export helper matching the reference's calling convention, an
+accelerator probe, the pallas interpret switch, and a no-op kept for
+source compatibility with code ported from the reference.
 """
 
 import logging
@@ -14,86 +14,34 @@ import logging
 logger = logging.getLogger(__name__)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax promotes ``shard_map`` to the top-level namespace (with a
-    ``check_vma`` flag); the builds this repo also supports only ship
-    ``jax.experimental.shard_map.shard_map`` (where the same knob is
-    spelled ``check_rep``).  Every in-repo call site
-    (ops/ring_attention.py, ops/ulysses.py via ops/attention.py's
-    dispatcher, parallel/pp.py) routes through this shim so the kernels
-    run on either build.
-    """
+def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
+    """``jax.shard_map``: the one spelling every in-repo call site uses
+    (ops/flash_attention.py, ops/ring_attention.py, ops/ulysses.py,
+    parallel/pp.py, parallel/hier_ps.py)."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if check_vma is not None:
-        # same semantics, pre-rename spelling
-        kwargs["check_rep"] = check_vma
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` across jax versions: falls back to the
-    static mesh-axis size from the trace's axis env on builds that
-    predate the public accessor (the shard_map-era companion of the
-    :func:`shard_map` shim above — sizes are static either way)."""
+    """Static size of a bound mesh axis (inside ``shard_map``)."""
     from jax import lax
 
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    import jax.core as core
-
-    frame = core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
+    return lax.axis_size(axis_name)
 
 
 def pallas_interpret():
     """True off-TPU: the repo's pallas kernels (flash/gmm/paged
     attention) run under ``interpret=True`` on CPU so tier-1 exercises
-    the real kernel path without TPU hardware."""
+    the real kernel path without TPU hardware.  THE single switch — a
+    process that expected a chip must assert its platform itself
+    (``chip_smoke.py`` does), because with ``JAX_PLATFORMS`` unset JAX
+    falls back to CPU when TPU init fails and this then interprets."""
     import jax
 
     return jax.default_backend() != "tpu"
-
-
-def pallas_compiler_params(dimension_semantics):
-    """Mosaic compiler params across jax versions (the
-    ``TPUCompilerParams`` → ``CompilerParams`` rename); every pallas
-    call site routes its ``dimension_semantics`` through here."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    params_cls = getattr(pltpu, "CompilerParams", None) or (
-        pltpu.TPUCompilerParams
-    )
-    return params_cls(dimension_semantics=tuple(dimension_semantics))
-
-
-def supports_cpu_multiprocess():
-    """True when this jax build can form multi-process groups on the
-    CPU backend (Gloo cross-process collectives).  Some builds compile
-    XLA:CPU without collectives support and raise ``Multiprocess
-    computations aren't implemented on the CPU backend`` at dispatch —
-    tests that need a real 2-process CPU group gate on this."""
-    try:
-        from jax._src import distributed  # noqa: F401
-        from jax._src.lib import xla_client
-
-        return hasattr(
-            xla_client._xla, "collectives"
-        ) and xla_client._xla.collectives is not None
-    except Exception:  # noqa: BLE001 - any probe failure = unsupported
-        return False
 
 
 def export_saved_model(params, export_dir, is_chief=False, metadata=None):

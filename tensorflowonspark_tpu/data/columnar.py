@@ -108,7 +108,7 @@ class WireSpec(object):
     @staticmethod
     def wire_bytes(columns):
         """Total wire bytes of a dict/tuple column set (what one batch
-        costs on the tunnel) — the accounting half of the narrowing
+        costs on the wire) — the accounting half of the narrowing
         claim (``feed.wire_stats()`` aggregates the same number on the
         consumer side)."""
         vals = columns.values() if isinstance(columns, dict) else columns
@@ -144,6 +144,12 @@ def _configure(lib):
 
 def _load_native():
     return _native.load_library(_LIB_NAME, _configure)
+
+
+def native_available():
+    """True when the C++ Example codec loaded (else extraction runs the
+    pure-python fallback)."""
+    return _load_native() is not None
 
 
 def _extract_native(lib, records, name, width, dtype, recs=None, lens=None):

@@ -107,6 +107,10 @@ class DataFeed(object):
         self.wire_bytes = 0
         self.wire_records = 0
         self.wire_rows = 0
+        #: records that arrived through the shm ring (the rest came by
+        #: manager queue) — how a caller proves the native ring carried
+        #: the feed instead of the silent queue fallback
+        self.ring_records = 0
         # fleet telemetry twins of the wire accounting (null
         # singletons when TFOS_TELEMETRY=0): the same numbers
         # wire_stats() reports, published into the process registry so
@@ -143,13 +147,15 @@ class DataFeed(object):
     def wire_stats(self):
         """Cumulative feed-plane wire accounting: ``wire_bytes`` (ring
         records at exact wire length, queue blocks at payload bytes),
-        ``records``, ``rows``, and derived ``bytes_per_row`` — the
+        ``records`` (``ring_records`` of them through the shm ring),
+        ``rows``, and derived ``bytes_per_row`` — the
         number the narrow-dtype plane shrinks (docs/data_plane.md;
         asserted >= 3x smaller for uint8-vs-float32 image columns in
         tests/test_dataplane.py)."""
         return {
             "wire_bytes": self.wire_bytes,
             "records": self.wire_records,
+            "ring_records": self.ring_records,
             "rows": self.wire_rows,
             "bytes_per_row": (
                 self.wire_bytes / self.wire_rows if self.wire_rows else 0.0
@@ -178,8 +184,7 @@ class DataFeed(object):
                 # produced LAST (the hot source) so either path runs at
                 # full rate; switching sources costs one 50ms miss.  (A
                 # fixed non-blocking queue poll throttled to 10/s capped
-                # queue-fed rows at ~2.5k rows/s — the ADVICE.md r1
-                # finding; blocking on the wrong source starved the
+                # queue-fed rows at ~2.5k rows/s; blocking on the wrong source starved the
                 # other.)
                 if self._hot_source == "queue":
                     try:
@@ -218,9 +223,10 @@ class DataFeed(object):
 
     def _install_ring_record(self, rec):
         """Decode one ring record, install it as pending, and account
-        its EXACT wire length (the ring frame is the tunnel payload)."""
+        its EXACT wire length (the ring frame is the wire payload)."""
         self._set_pending(_decode_ring_record(rec))
         self._account(len(rec), self._pending_left())
+        self.ring_records += 1
 
     def _ring_pop(self, timeout):
         """Ring pop with producer-liveness handling: a dead feeder

@@ -114,9 +114,9 @@ class Replica(object):
       fault_fn: chunk-dispatch fault hook (chaos ``kill_replica`` /
         ``slow_replica``); defaults to the plan's
         :func:`~tensorflowonspark_tpu.testing.chaos.replica_fault_fn`.
-      device: optional ``jax.Device`` the worker pins as default
-        (benches spread replicas over virtual CPU devices; real
-        fleets give each replica its own chip by construction).
+      device: optional ``jax.Device`` the worker pins as default, so
+        this replica's weights, KV pools and programs live on its own
+        chip (:class:`ReplicaSet` hands out one per local device).
       poll_sec: idle feed-poll interval (the heartbeat cadence — also
         how often an IDLE replica runs its lifecycle pass).
     """
@@ -441,29 +441,29 @@ class ReplicaSet(object):
       input_mapping: engine-level mapping (see :class:`Replica`).
       completions: the router's completion queue (built here when the
         set is used standalone).
-      devices: ``"spread"`` pins replica ``i`` to
-        ``jax.devices()[i % len]`` (benches on the virtual CPU mesh);
-        None leaves placement to jax (real fleets: one chip per
-        replica by construction).
       num_slots / chunk / queue_depth / engine_opts / poll_sec:
         per-replica engine knobs, forwarded to :class:`Replica`.
+
+    On a host with several local devices replica ``i`` is pinned to
+    ``jax.local_devices()[i % len]`` — one replica per chip.  Left to
+    JAX's default placement every replica would land on device 0 and
+    the other chips would idle.
     """
 
     def __init__(self, predict, n, input_mapping, *, completions=None,
                  predict_factory=None, num_slots=4, chunk=None,
-                 queue_depth=None, engine_opts=None, devices=None,
-                 poll_sec=0.02):
+                 queue_depth=None, engine_opts=None, poll_sec=0.02):
+        import jax
+
         n = int(n)
         if n < 1:
             raise ValueError("need at least one replica, got %d" % n)
         self.completions = (
             completions if completions is not None else queue_mod.Queue()
         )
-        devs = None
-        if devices == "spread":
-            import jax
-
-            devs = jax.devices()
+        devs = jax.local_devices()
+        if len(devs) < 2:
+            devs = None
         # construction knobs kept for spawn(): the autoscaling verb
         # (ISSUE 16) builds late replicas exactly like the initial set
         self._predict = predict

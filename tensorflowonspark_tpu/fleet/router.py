@@ -197,7 +197,7 @@ class FleetRouter(object):
         applied router-side (replica engines emit raw outputs).
       replicas: replica count (or pass a prebuilt ``replica_set``
         whose engines were built with :meth:`engine_input_mapping`).
-      num_slots / chunk / replica_queue_depth / engine_opts / devices
+      num_slots / chunk / replica_queue_depth / engine_opts
         / predict_factory / poll_sec: forwarded to
         :class:`ReplicaSet` / :class:`Replica`.
       policy: FLEET admission policy — ``block`` (backpressure the
@@ -235,7 +235,7 @@ class FleetRouter(object):
     def __init__(self, predict, input_mapping, output_mapping=None, *,
                  replicas=2, num_slots=4, chunk=None,
                  replica_queue_depth=None, engine_opts=None,
-                 devices=None, predict_factory=None, replica_set=None,
+                 predict_factory=None, replica_set=None,
                  policy="block", dispatch="least_loaded",
                  queue_depth=None, degrade_floor=1, on_error="record",
                  replica_weights=None, imbalance=None,
@@ -298,7 +298,7 @@ class FleetRouter(object):
                 self.engine_input_mapping(input_mapping),
                 num_slots=num_slots, chunk=chunk,
                 queue_depth=replica_queue_depth,
-                engine_opts=engine_opts, devices=devices,
+                engine_opts=engine_opts,
                 predict_factory=predict_factory,
             )
         self.replica_set = replica_set.start()
@@ -1343,8 +1343,7 @@ def predict_rows_fleet(predict, rows, input_mapping,
                        policy="block", watchdog_timeout=None,
                        default_deadline=None,
                        replica_policy="least_loaded",
-                       fleet_queue_depth=None, chunk=None,
-                       devices=None):
+                       fleet_queue_depth=None, chunk=None):
     """The fleet twin of ``predict_rows(schedule="continuous")``
     (serving.py routes here when ``replicas > 1``): N in-process
     engine replicas behind a :class:`FleetRouter`.  Same contract —
@@ -1362,7 +1361,7 @@ def predict_rows_fleet(predict, rows, input_mapping,
         replica_queue_depth=queue_depth, engine_opts=engine_opts,
         policy=policy, dispatch=replica_policy,
         queue_depth=fleet_queue_depth, on_error=on_error,
-        stats=stats, devices=devices,
+        stats=stats,
     )
     try:
         for r in router.serve(rows):

@@ -37,8 +37,8 @@ def check_drop_rate(drop_rate, capacity_factor=None, where="MoE"):
     Callers that PUBLISH a throughput number (bench rows, training
     logs) attach the returned string to the same record, so a reader
     of the headline sees the quality caveat next to it — the CF=1.0
-    vs CF=1.25 convergence smoke in tests/test_moe.py and the
-    BASELINE.md tradeoff note quantify what the drops cost.  Raise
+    vs CF=1.25 convergence smoke in tests/test_moe.py quantifies
+    what the drops cost.  Raise
     ``capacity_factor`` (1.25 keeps drops rare on balanced routers) or
     switch ``dispatch="dropless"`` to eliminate them.
     """
@@ -49,8 +49,7 @@ def check_drop_rate(drop_rate, capacity_factor=None, where="MoE"):
         "%s drop_rate %.1f%% exceeds %.0f%% (capacity_factor=%s): "
         "throughput at this setting silently drops token updates — "
         "raise capacity_factor (e.g. 1.25) or use dispatch='dropless'; "
-        "see the CF convergence smoke in tests/test_moe.py and "
-        "BASELINE.md 'MoE capacity tradeoff'"
+        "see the CF convergence smoke in tests/test_moe.py"
         % (
             where, 100.0 * rate, 100.0 * DROP_RATE_WARN,
             capacity_factor if capacity_factor is not None else "?",

@@ -140,10 +140,11 @@ def attention(q, k, v, impl="dot", causal=True, scale=None, mesh=None,
     inputs must be local shards and the call must already be inside
     ``shard_map``-decorated code where ``seq_axis`` is bound; with a mesh
     given, the inputs are *global* arrays and the op wraps itself in a
-    ``shard_map`` over the mesh's ``seq`` axis (via
-    :func:`tensorflowonspark_tpu.compat.shard_map`, which falls back to
-    ``jax.experimental.shard_map`` on builds without ``jax.shard_map``;
-    do NOT pass a mesh from code that is itself under ``shard_map``).  ``flash`` runs the pallas
+    ``shard_map`` over the mesh's ``seq`` axis (do NOT pass a mesh from
+    code that is itself under ``shard_map``).  ``flash`` under a
+    multi-device ``mesh`` wraps itself the same way over the batch and
+    head axes (a model jitted over real chips MUST pass its mesh:
+    GSPMD cannot partition a Mosaic kernel); it runs the pallas
     kernels in interpret mode off-TPU so the same model runs in CPU
     tests.  ``block_q``/``block_k`` bound the pallas tiles for both the
     ``flash`` impl and ``ring``'s flash inner step; ``ring_impl``
@@ -153,8 +154,16 @@ def attention(q, k, v, impl="dot", causal=True, scale=None, mesh=None,
     if impl not in _IMPLS:
         raise ValueError("unknown attention impl {0!r}; one of {1}".format(impl, _IMPLS))
     if impl == "flash":
-        from tensorflowonspark_tpu.ops.flash_attention import flash_attention
+        from tensorflowonspark_tpu.ops.flash_attention import (
+            flash_attention,
+            flash_attention_sharded,
+        )
 
+        if mesh is not None and mesh.size > 1:
+            return flash_attention_sharded(
+                q, k, v, mesh, causal=causal, scale=scale,
+                block_q=block_q, block_k=block_k, window=window,
+            )
         return flash_attention(
             q, k, v, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, window=window,

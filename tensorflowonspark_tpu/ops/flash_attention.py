@@ -40,11 +40,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from tensorflowonspark_tpu import compat
+
 NEG_INF = -1e30  # finite mask sentinel: keeps exp() at 0 without NaNs
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def _scratch(shape, dtype):
@@ -62,11 +60,7 @@ def _compiler_params():
     dims freely instead of assuming a fully sequential grid."""
     from jax.experimental.pallas import tpu as pltpu
 
-    # renamed TPUCompilerParams -> CompilerParams across jax versions
-    params_cls = getattr(pltpu, "CompilerParams", None) or (
-        pltpu.TPUCompilerParams
-    )
-    return params_cls(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
     )
 
@@ -427,7 +421,7 @@ def _fwd_core(qt, kt, vt, scale, causal, block_q, block_k, out_dtype=None,
             _scratch((bq, 1), jnp.float32),  # running normalizer
             _scratch((bq, d), jnp.float32),  # output accumulator
         ],
-        interpret=_interpret(),
+        interpret=compat.pallas_interpret(),
         compiler_params=_compiler_params(),
     )(qt, kt, vt)
     return out, lse
@@ -504,7 +498,7 @@ def _bwd_core(scale, causal, block_q, block_k, qt, kt, vt, dot_, lse,
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), qt.dtype),
         scratch_shapes=[_scratch((bq, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=compat.pallas_interpret(),
         compiler_params=_compiler_params(),
     )(qt, kt, vt, dot_, lse, delta)
 
@@ -556,7 +550,7 @@ def _bwd_core(scale, causal, block_q, block_k, qt, kt, vt, dot_, lse,
             _scratch((bk, d), jnp.float32),
             _scratch((bk, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=compat.pallas_interpret(),
         compiler_params=_compiler_params(),
     )(qt, kt, vt, dot_, lse, delta)
 
@@ -623,3 +617,54 @@ def flash_attention(q, k, v, causal=True, scale=None, block_q=1024,
     return _flash(
         q, k, v, float(scale), bool(causal), block_q, block_k, int(window)
     )
+
+
+def flash_attention_sharded(q, k, v, mesh, causal=True, scale=None,
+                            block_q=1024, block_k=1024, window=0):
+    """Global-array entry point for a model jitted over ``mesh``.
+
+    Mosaic kernels cannot be partitioned by GSPMD (on real chips the
+    jit raises ``Mosaic kernels cannot be automatically partitioned``;
+    CPU meshes never see it because interpret mode lowers the kernel
+    to plain XLA ops), so the kernel is wrapped in a ``shard_map``:
+    batch over the ``data``/``fsdp`` axes, heads over ``model`` —
+    attention is independent per (batch row, kv-head group), so each
+    device runs the unchanged kernel on its shard with no collective.
+    A dim the axes do not divide (a batch-1 ``model.init`` trace, a kv
+    head count narrower than ``model``) stays replicated instead.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from tensorflowonspark_tpu.parallel.mesh import (
+        AXIS_DATA,
+        AXIS_FSDP,
+        AXIS_TENSOR,
+        mesh_axis_size,
+    )
+
+    batch_axes = tuple(
+        a for a in (AXIS_DATA, AXIS_FSDP) if mesh.shape.get(a, 1) > 1
+    )
+    if q.shape[0] % mesh_axis_size(mesh, *batch_axes) != 0:
+        batch_axes = ()
+    tp = mesh.shape.get(AXIS_TENSOR, 1)
+    head_axis = (
+        AXIS_TENSOR
+        if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0
+        else None
+    )
+    spec = P(batch_axes or None, None, head_axis, None)
+
+    def _local(ql, kl, vl):
+        return flash_attention(
+            ql, kl, vl, causal=causal, scale=scale, block_q=block_q,
+            block_k=block_k, window=window,
+        )
+
+    return compat.shard_map(
+        _local,
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )(q, k, v)

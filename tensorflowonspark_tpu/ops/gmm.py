@@ -39,9 +39,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from tensorflowonspark_tpu import compat
 
-def _interpret():
-    return jax.default_backend() != "tpu"
+#: per-call working-set budget under Mosaic's 16MB scoped-VMEM limit
+_VMEM_BUDGET = 14 * 1024 * 1024
 
 
 def _compiler_params(ndim=2):
@@ -75,16 +76,16 @@ def _gmm_kernel(te_ref, x_ref, w_ref, y_ref):
     ).astype(y_ref.dtype)
 
 
-def _pick_bf(bm, d, f, bf=None):
+def _pick_bf(bm, d, f, bf=None, itemsize=2):
     """Pick a legal f-stripe width.
 
     Mosaic requires the LAST block dim to be a multiple of 128 or the
     full array dim, and wider stripes amortize per-step overhead — so:
-    the largest 128·2^k divisor of ``f`` whose double-buffered bf16
-    working set fits the 16MB scoped-VMEM budget, capped at ``bf``
-    when the caller pins one (else 2048), falling back to the full
-    width when ``f`` has no such divisor (odd widths like 576) or is
-    ≤128 (legality trumps the cap there).
+    the largest 128·2^k divisor of ``f`` whose double-buffered working
+    set (``itemsize``-byte operands) fits the scoped-VMEM budget,
+    capped at ``bf`` when the caller pins one (else 2048), falling
+    back to the full width when ``f`` has no such divisor (odd widths
+    like 576) or is ≤128 (legality trumps the cap there).
     """
     if bf is not None and f % bf == 0:
         # caller pinned a legal divisor — honor it exactly (tests pin
@@ -92,15 +93,14 @@ def _pick_bf(bm, d, f, bf=None):
         # interpret mode; hardware callers own their legality)
         return min(bf, f)
     cap = 2048 if bf is None else max(128, bf)
-    budget = 14 * 1024 * 1024
 
     def working(c):
-        return 2 * 2 * (bm * d + d * c + bm * c)  # bf16 bytes
+        return 2 * itemsize * (bm * d + d * c + bm * c)
 
     best = 0
     c = 128
     while c <= min(f // 2, cap):
-        if f % c == 0 and working(c) <= budget:
+        if f % c == 0 and working(c) <= _VMEM_BUDGET:
             best = c
         c *= 2
     return best if best else f
@@ -114,14 +114,14 @@ def gmm_call(x, w, tile_expert, *, bm=256, bf=None, interpret=None):
     has no registered gradient).
     """
     if interpret is None:
-        interpret = _interpret()
+        interpret = compat.pallas_interpret()
     n, d = x.shape
     e, dw_, f = w.shape
     assert d == dw_, (x.shape, w.shape)
     assert n % bm == 0, (n, bm)
     t = n // bm
     assert tile_expert.shape == (t,), (tile_expert.shape, t)
-    bf = _pick_bf(bm, d, f, bf)
+    bf = _pick_bf(bm, d, f, bf, itemsize=x.dtype.itemsize)
     assert f % bf == 0, (f, bf)
     grid_spec = _grid_spec(
         1,
@@ -160,13 +160,11 @@ def _pick_bd(bm, d, f, bd, itemsize=2):
     across its consecutive row tiles exactly like the forward.  Returns
     0 when ``f`` is too wide for any resident block (caller falls back
     to the transposed-copy path).  ``itemsize`` is the operand byte
-    width (ADVICE: the old hardcoded 2 undercounted float32 working
-    sets 2x, so a near-budget block could fail Mosaic VMEM
-    allocation)."""
-    budget = 14 * 1024 * 1024
+    width (a hardcoded 2 undercounts float32 working sets 2x, so a
+    near-budget block fails Mosaic VMEM allocation)."""
 
     def fits(c):
-        return 2 * itemsize * (bm * f + c * f + bm * c) <= budget
+        return 2 * itemsize * (bm * f + c * f + bm * c) <= _VMEM_BUDGET
 
     if bd is not None and d % bd == 0 and fits(bd):
         return min(bd, d)
@@ -188,7 +186,7 @@ def gmm_dxt_call(dy, w, tile_expert, *, bm=256, bd=None, interpret=None):
     step; ADVICE r4 #4).  Returns None when no resident block exists
     for this ``f`` (then the caller takes the transposed-copy path)."""
     if interpret is None:
-        interpret = _interpret()
+        interpret = compat.pallas_interpret()
     n, f = dy.shape
     e, d, f2 = w.shape
     assert f == f2, (dy.shape, w.shape)
@@ -251,7 +249,7 @@ def tgmm_call(x, dy, tile_expert, num_experts, *, bm=256, bd=None,
     absent experts are zeroed explicitly after the kernel.
     """
     if interpret is None:
-        interpret = _interpret()
+        interpret = compat.pallas_interpret()
     from jax.experimental.pallas import tpu as pltpu
 
     n, d = x.shape
@@ -262,11 +260,12 @@ def tgmm_call(x, dy, tile_expert, num_experts, *, bm=256, bd=None,
     # bf, and dw's bf) — legalize each with the same 128-rule picker,
     # then shrink until the (bd, bf) f32 accumulator scratch ALSO fits
     # (the picker budgets the double-buffered blocks only)
-    bd = _pick_bf(bm, min(bf or 512, f), d, bd)
-    bf = _pick_bf(bm, bd, f, bf)
+    itemsize = x.dtype.itemsize
+    bd = _pick_bf(bm, min(bf or 512, f), d, bd, itemsize=itemsize)
+    bf = _pick_bf(bm, bd, f, bf, itemsize=itemsize)
     while (
-        2 * 2 * (bm * bd + bm * bf + bd * bf) + 4 * bd * bf
-        > 14 * 1024 * 1024
+        2 * itemsize * (bm * bd + bm * bf + bd * bf) + 4 * bd * bf
+        > _VMEM_BUDGET
     ):
         side = "bd" if bd >= bf else "bf"
         cur = bd if side == "bd" else bf

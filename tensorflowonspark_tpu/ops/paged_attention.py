@@ -244,6 +244,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
       interpret: force/deny interpret mode (default: off-TPU).
     Returns ``[B, H, D]`` in ``q.dtype``.
     """
+    from jax.experimental.pallas import tpu as pltpu
+
     if interpret is None:
         interpret = compat.pallas_interpret()
     b, h, d = q.shape
@@ -297,8 +299,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=compat.pallas_compiler_params(
-            ("parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32),

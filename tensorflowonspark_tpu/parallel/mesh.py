@@ -78,7 +78,7 @@ def distributed_init_from_env(environ=None):
         return False
     import jax
 
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return False
     kwargs = {"coordinator_address": coord}
     if env.get("TFOS_PROCESS_ID") is not None:
@@ -190,7 +190,12 @@ def build_mesh(axes=None, devices=None, allow_split_physical=True):
     except (ValueError, AssertionError, NotImplementedError) as e:
         if not allow_split_physical:
             raise
-        logger.debug("mesh_utils rejected shape %s (%s); plain reshape", shape, e)
+        # a plain reshape ignores ICI adjacency: on real chips the
+        # fastest-varying axes may then cross the slowest links
+        logger.warning(
+            "mesh_utils rejected shape %s (%s); falling back to a plain "
+            "reshape of the device list", shape, e,
+        )
         import numpy as np
 
         device_array = np.asarray(devices).reshape(shape)

@@ -146,7 +146,7 @@ def send_msg(sock, header, tensors=None, codec=None):
     :class:`~tensorflowonspark_tpu.compress.ErrorFeedback`), each
     tensor ships as the codec's encoded parts and the per-tensor meta
     gains the codec header ``recv_msg`` decodes by.  Returns the total
-    bytes laid on the wire (header + payloads) — the tunnel-traffic
+    bytes laid on the wire (header + payloads) — the wire-traffic
     accounting the wire tests and bench rows use.
     """
     tensors = tensors or {}
@@ -1042,7 +1042,7 @@ class _GradDrain(object):
 
     ``max_inflight`` is the bounded-staleness window: at most that many
     gradient windows may be queued-or-flying before ``submit`` blocks
-    the dispatch thread, so a slow tunnel backpressures training
+    the dispatch thread, so a slow wire backpressures training
     instead of accumulating unbounded staleness.
     """
 
@@ -1069,7 +1069,7 @@ class _GradDrain(object):
 
         from tensorflowonspark_tpu import telemetry
 
-        # the measured async-PS bottleneck (BENCH_r05) gets its own
+        # the async-PS plane's per-step readback gets its own
         # span + histogram so the step trace shows where the wall went
         t0 = time.perf_counter()
         out = jax.device_get(tree)
@@ -1176,7 +1176,7 @@ class AsyncTrainer(object):
         measured "per-step device->host grad transfer" bottleneck.
         Staleness is bounded by ``max_inflight`` windows.
       push_every: accumulate this many steps' gradients ON DEVICE
-        (mean) per push — the tunnel sees 1/k the traffic and the PS
+        (mean) per push — the wire sees 1/k the traffic and the PS
         applies the averaged gradient (local accumulation; exact for
         the leafwise optimizers up to the usual async staleness).
       max_inflight: bounded-staleness cap for ``overlap`` mode.

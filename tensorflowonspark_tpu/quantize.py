@@ -4,10 +4,10 @@ New TPU-first capability with no reference analogue (the reference
 serves f32 TF SavedModels; `/root/reference/src/main/scala/com/yahoo/
 tensorflowonspark/TFModel.scala` has no quantized path).  Rationale:
 single-token decode and small-batch serving are HBM-bandwidth-bound on
-the *weight read* (BASELINE.md decode row), and the MXU dequantizes
+the *weight read*, and the MXU dequantizes
 int8 operands on the fly — storing matmul weights as int8 + per-channel
-scales halves their HBM traffic.  Measured on the flagship decode
-config: 1.48× on an isolated HBM-bound weight-read probe; the
+scales halves their HBM traffic (whether that shows in a decode step
+on this round's machine: not measured — ROADMAP S2); the
 activations, cache, and numerics-sensitive small tensors stay bf16.
 
 Scheme: symmetric per-channel int8.  For a flax kernel the contraction

@@ -838,7 +838,11 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     from tensorflowonspark_tpu.data import interchange
+    from tensorflowonspark_tpu.utils.compile_cache import (
+        ensure_compile_cache,
+    )
 
+    ensure_compile_cache()
     rows, schema = interchange.load_tfrecords(
         args.input, schema=args.schema_hint
     )

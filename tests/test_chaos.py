@@ -72,6 +72,38 @@ def test_dropped_heartbeats_declare_dead_within_miss_threshold(server):
         hb.stop()
 
 
+def test_monitor_does_not_judge_silence_through_its_own_stall(server):
+    """A frozen HOST (TPU initialisation stalls every process of the VM
+    for seconds) silences the nodes and the monitor alike: waking up,
+    the monitor must not read the gap as death — but real silence is
+    still caught one deadline later, and an explicit compute-dead
+    report is never discounted."""
+    from tensorflowonspark_tpu.cluster.cluster import ClusterMonitor
+
+    monitor = ClusterMonitor(server, [{"executor_id": 3}])
+    server.liveness.beat(3)
+    deadline = server.liveness.deadline  # 3 x 0.1 s
+    time.sleep(deadline + 0.1)  # everyone was frozen: no beats, no polls
+    monitor._tick(overslept=deadline + 0.1)
+    monitor._poll()
+    assert monitor.error is None
+    server.liveness.beat(3)  # the node thawed too
+    monitor._tick(overslept=0.0)
+    monitor._poll()
+    assert monitor.error is None
+    # genuine silence after the stall: judged once the blind window ends
+    time.sleep(2 * deadline + 0.1)
+    monitor._poll()
+    assert "no heartbeat" in monitor.error
+    # a REPORTED death is evidence whatever the monitor slept through
+    reported = ClusterMonitor(server, [{"executor_id": 5}])
+    server.liveness.forget(3)
+    server.liveness.beat(5, compute_alive=False)
+    reported._tick(overslept=60.0)
+    reported._poll()
+    assert "compute process dead" in reported.error
+
+
 def test_compute_dead_flag_is_immediate(server):
     hb = reservation.Heartbeater(
         server.addr, 5, interval=0.1, alive_fn=lambda: False

@@ -1,0 +1,104 @@
+"""What a CPU mesh cannot show: the programs must lower and compile for
+real chips.
+
+On CPU every Pallas kernel runs interpreted — plain XLA ops that GSPMD
+partitions silently — so a model that works on the 8-device CPU mesh can
+still be refused by ``jax.jit`` on more than one TPU (``Mosaic kernels
+cannot be automatically partitioned``), and a kernel whose blocks exceed
+VMEM never meets the Mosaic allocator.  Two levers work without a chip:
+``lower(lowering_platforms=("tpu",))`` runs the TPU lowering rules over a
+CPU mesh, and ``jax.experimental.topologies`` hands out v5e devices to
+AOT-compile for.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from tensorflowonspark_tpu import compat
+from tensorflowonspark_tpu.ops import gmm
+from tensorflowonspark_tpu.ops.attention import attention
+
+#: flagship attention geometry (bench/chip_smoke): B8 S2048 H8 Dh128
+B, S, H, D = 8, 2048, 8, 128
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Lower the kernels as on a TPU (compiled by Mosaic, not
+    interpreted) — the one switch every kernel reads."""
+    monkeypatch.setattr(compat, "pallas_interpret", lambda: False)
+
+
+def _lower_for_tpu(mesh, kv_heads, use_mesh):
+    spec = P(("data",) if "data" in mesh.shape else None, None,
+             "model" if "model" in mesh.shape else None, None)
+    sharding = NamedSharding(mesh, spec)
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct(
+        (B, S, kv_heads, D), jnp.bfloat16, sharding=sharding)
+
+    def loss(q, k, v):
+        return attention(
+            q, k, v, impl="flash", mesh=mesh if use_mesh else None,
+        ).astype(jnp.float32).sum()
+
+    step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    return step.trace(q, kv, kv).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("axes,kv_heads", [
+    ({"data": 4}, H),
+    ({"data": 2, "model": 2}, 2),  # GQA: kv heads split over `model`
+])
+def test_sharded_flash_lowers_for_tpu_on_a_mesh(mosaic, axes, kv_heads):
+    mesh = Mesh(
+        np.array(jax.devices()[:4]).reshape(tuple(axes.values())),
+        tuple(axes),
+    )
+    text = _lower_for_tpu(mesh, kv_heads, use_mesh=True).as_text()
+    assert "tpu_custom_call" in text  # the Mosaic kernel, not interpreted
+    # each device runs the kernel on ITS shard of batch and heads
+    per_dev = "tensor<%dx%dx%dx%dxbf16>" % (
+        B // axes["data"], H // axes.get("model", 1), S, D)
+    assert per_dev in text
+
+
+def test_unwrapped_flash_is_refused_on_a_mesh(mosaic):
+    # the failure the shard_map wrap exists for — if a future JAX learns
+    # to partition Mosaic calls this starts passing and the wrap can go
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    with pytest.raises(NotImplementedError, match="Mosaic kernels cannot"):
+        _lower_for_tpu(mesh, H, use_mesh=False)
+
+
+def test_gmm_float32_backward_compiles_for_v5e(mosaic):
+    """E8 D1024 F4096 float32: the dw/dx/forward block pickers must count
+    4-byte operands, or Mosaic runs out of its 16MB scoped VMEM."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
+        pytest.skip("no TPU AOT topology here: %s" % e)
+    dev = SingleDeviceSharding(topo.devices[0])
+    e, d, f, bm, t = 8, 1024, 4096, 256, 32
+
+    def fwd_bwd(x, w, te, dy):
+        out, vjp = jax.vjp(
+            lambda x, w: gmm.grouped_matmul(x, w, te, bm), x, w)
+        return (out,) + vjp(dy)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    for dtype in (jnp.float32, jnp.bfloat16):
+        jax.jit(fwd_bwd).lower(
+            arg((t * bm, d), dtype), arg((e, d, f), dtype),
+            arg((t,), jnp.int32), arg((t * bm, f), dtype),
+        ).compile()

@@ -30,10 +30,7 @@ def test_accelerator_probe_runs():
     assert compat.is_gpu_available is compat.is_accelerator_available
 
 
-def test_shard_map_shim_runs_on_this_build():
-    # the shim must resolve to a WORKING shard_map whether or not this
-    # jax build has the top-level alias (the 3 tier-1 env failures'
-    # root cause), translating check_vma for the experimental spelling
+def test_shard_map_runs_on_this_build():
     import jax
     import jax.numpy as jnp
 
@@ -48,7 +45,7 @@ def test_shard_map_shim_runs_on_this_build():
     np.testing.assert_array_equal(np.asarray(out), [2.0, 2.0])
 
 
-def test_axis_size_shim_inside_shard_map():
+def test_axis_size_inside_shard_map():
     import jax
     import jax.numpy as jnp
 
@@ -67,6 +64,3 @@ def test_axis_size_shim_inside_shard_map():
     )(jnp.ones((2,), jnp.float32))
     assert sizes["x"] == 1
 
-
-def test_cpu_multiprocess_probe_is_bool():
-    assert compat.supports_cpu_multiprocess() in (True, False)

@@ -5,7 +5,7 @@ Unit: codec round trips (property-style over shapes/dtypes), int8
 error-feedback convergence on a quadratic bowl, top-k index
 correctness, non-contiguous inputs, wire-byte accounting.
 Wire: truncated/garbage frame rejection (mirroring the tfrecord
-corruption tests), bytes-on-tunnel shrink under codecs, delta-reply
+corruption tests), bytes-on-wire shrink under codecs, delta-reply
 bit-consistency between the server's client view and the client's.
 Overlap: the background drain keeps device dispatch non-blocking — no
 readback ever runs on the training-loop thread.
@@ -192,7 +192,7 @@ def test_wire_codec_roundtrip_int8_and_topk():
 
 
 def test_wire_bytes_shrink_3x_under_int8_and_more_under_topk():
-    # the acceptance gate: bytes-on-tunnel per push, same gradients
+    # the acceptance gate: bytes-on-wire per push, same gradients
     grads = {"w": np.random.RandomState(0).randn(1000, 64)
              .astype(np.float32)}
     dense, _, _ = _xfer(grads)
@@ -397,7 +397,7 @@ def test_overlap_drain_keeps_dispatch_thread_free(two_shards,
 
 
 def test_overlap_with_push_every_converges(two_shards):
-    # accumulation window k=4: the tunnel sees 1/4 the pushes, the PS
+    # accumulation window k=4: the wire sees 1/4 the pushes, the PS
     # applies window means — convergence on the bowl must survive
     target = np.asarray([2.0, -1.0, 0.25, -3.0], np.float32)
 

@@ -704,6 +704,13 @@ class TestRealFleet:
                 None, {"prompt": "tokens"}, replicas=2, num_slots=2,
                 predict_factory=_shared_factory(predicts),
                 dispatch=name, poll_sec=0.01,
+                # compare the POLICIES, not the replicas' start-up
+                # timing: each replica compiles for its own device, and
+                # whichever is later would be routed around (straggler
+                # detector) or spilled away from (no room / imbalance)
+                # while the other serves its families
+                slow_factor=float("inf"), imbalance=10 ** 6,
+                replica_queue_depth=len(rows),
             )
             out = list(router.serve([dict(r) for r in rows]))
             router.close()

@@ -7,10 +7,8 @@ Integration: pure-ICI convergence with the ZERO-host-readback telemetry
 assert, DCN-tier convergence with exactly-once window applies, leader
 failover with ledger/EF-epoch audit, the AsyncTrainer
 ``topology="hierarchical"`` facade, and the supervisor's leader
-publication.  Multi-process ICI gates on
-``compat.supports_cpu_multiprocess()`` (skip-with-reason on builds
-without CPU cross-process collectives); the single-process mesh tests
-cover the collective math everywhere.
+publication.  Multi-process ICI runs a real 2-process Gloo group; the
+single-process mesh tests cover the collective math as well.
 """
 
 
@@ -20,7 +18,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tensorflowonspark_tpu import compat, telemetry
+from tensorflowonspark_tpu import telemetry
 from tensorflowonspark_tpu.parallel import hier_ps, ps
 from tensorflowonspark_tpu.parallel.mesh import AXIS_PS, build_mesh
 
@@ -118,16 +116,15 @@ def test_ici_helpers_width_one_is_identity():
 
 @pytest.mark.slow
 def test_two_process_ici_mean(tmp_path):
-    # REAL cross-process ICI aggregation (Gloo collectives); the
-    # single-process tests above cover the math on every build
-    if not compat.supports_cpu_multiprocess():
-        pytest.skip("this jax build has no CPU cross-process collectives")
+    # REAL cross-process ICI aggregation (Gloo collectives)
     from conftest import launch_two_workers
 
     worker_src = """
 import os, sys
 rank, port = int(sys.argv[1]), int(sys.argv[2])
 os.environ["JAX_PLATFORMS"] = "cpu"
+# ONE device per process (the suite's 8-device forcing is inherited)
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
 sys.path.insert(0, os.environ["TFOS_REPO"])
 import numpy as np
 import jax
@@ -138,12 +135,15 @@ jax.distributed.initialize(
 from tensorflowonspark_tpu.parallel import hier_ps
 from tensorflowonspark_tpu.parallel.mesh import AXIS_PS, build_mesh
 mesh = build_mesh({AXIS_PS: 2})
-member = np.full((1, 4), float(rank + 1), np.float32)
-got = hier_ps.ici_mean({"g": np.repeat(member, 1, 0)}, mesh)
-# NOTE: each process contributes its own member row; global mean of
-# [1, 2] rows is 1.5 everywhere
-out = np.asarray(jax.experimental.multihost_utils.process_allgather(
-    np.asarray(got["g"])))
+# ici_mean takes the GLOBAL [width, ...] stack (every process passes the
+# same value; each holds only its own member row on its device), so the
+# psum of rows [1, 2] crosses the process boundary: 1.5 everywhere
+members = np.stack([np.full((4,), 1.0), np.full((4,), 2.0)]).astype(
+    np.float32)
+got = hier_ps.ici_mean({"g": members}, mesh)
+from jax.experimental import multihost_utils
+out = np.asarray(multihost_utils.process_allgather(np.asarray(got["g"])))
+assert out.shape == (2, 4) and np.allclose(out, 1.5), out
 print("ICI_OK", out.reshape(-1)[:2])
 """
     outputs = launch_two_workers(worker_src, tmp_path)

@@ -347,8 +347,7 @@ class TestCapacityHonesty:
     """The drop-rate honesty guard (VERDICT r5 weak #2): throughput
     numbers taken at a capacity factor that drops >2% of token updates
     must say so, and the quality cost must be quantified somewhere a
-    reader can check — the CF=1.0 vs CF=1.25 convergence smoke below
-    and the BASELINE.md 'MoE capacity tradeoff' note."""
+    reader can check — the CF=1.0 vs CF=1.25 convergence smoke below."""
 
     def test_check_drop_rate_quiet_below_threshold(self):
         assert moe_models.check_drop_rate(0.0) is None
@@ -423,8 +422,7 @@ class TestCapacityHonesty:
         # the quality/throughput tradeoff, measured: tighter capacity
         # (CF=1.0) drops more (token, choice) updates than CF=1.25,
         # and the converged loss stays comparable at this scale — the
-        # cost is bounded, not free (BASELINE.md 'MoE capacity
-        # tradeoff' carries the flagship-scale numbers)
+        # cost is bounded, not free
         loss_tight, drop_tight = self._train(cf=1.0)
         loss_ample, drop_ample = self._train(cf=1.25)
         assert drop_tight >= drop_ample

@@ -87,17 +87,6 @@ def test_pod_env_rendezvous_forms_process_group(tmp_path):
     import socket
     import time
 
-    import pytest
-
-    from tensorflowonspark_tpu import compat
-
-    if not compat.supports_cpu_multiprocess():
-        # some jax builds ship XLA:CPU without the Gloo cross-process
-        # collectives; the children then die with "Multiprocess
-        # computations aren't implemented on the CPU backend" — an
-        # environment gap, not a launcher bug
-        pytest.skip("this jax build has no CPU cross-process collectives")
-
     child = tmp_path / "pod_child.py"
     child.write_text(
         "import os\n"
