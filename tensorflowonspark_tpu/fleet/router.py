@@ -814,7 +814,7 @@ class FleetRouter(object):
         self._m["dispatched"].inc()
         if self._tracer.enabled:
             self._tracer.add(
-                "fleet_dispatch", time.perf_counter(), 0.0,
+                "fleet_dispatch", self._tracer.now(), 0.0,
                 trace=req["rid"], replica=rid, request_index=fid,
                 resumed_tokens=len(committed),
             )

@@ -142,10 +142,12 @@ def load_sources(paths):
     with ``events``/``clocks``), a journal JSONL file, or a directory
     (every ``*.json``/``*.jsonl`` inside).  Returns
     ``[{"path", "executor", "pid", "events": [dict], "spans": [dict],
-    "epoch_wall": float|None, "offset": float}]`` — ``offset`` is
-    pre-filled from the source's own clock data when it has any
-    (journal exports carry the fleet ClockSync snapshot) and 0.0
-    otherwise.
+    "offset": float}]`` — ``offset`` is pre-filled from the source's
+    own clock data when it has any (journal exports carry the fleet
+    ClockSync snapshot) and 0.0 otherwise.  A span's ``t0`` is Unix
+    seconds, the clock the journal events are on; a bundle written
+    before the tracer kept absolute starts carries
+    ``clock.epoch_wall``, which is added to its spans here.
     """
     files = []
     for p in paths:
@@ -175,13 +177,16 @@ def load_sources(paths):
         if not isinstance(data, dict):
             continue
         if data.get("format") == _blackbox.BUNDLE_FORMAT:
+            spans = data.get("spans") or []
+            epoch_wall = (data.get("clock") or {}).get("epoch_wall")
+            if epoch_wall:
+                spans = [dict(s, t0=epoch_wall + s["t0"]) for s in spans]
             sources.append(_source(
                 f,
                 executor=data.get("executor"),
                 pid=data.get("pid"),
                 events=data.get("events") or [],
-                spans=data.get("spans") or [],
-                epoch_wall=(data.get("clock") or {}).get("epoch_wall"),
+                spans=spans,
                 metrics=data.get("metrics"),
             ))
         elif "events" in data:
@@ -204,7 +209,7 @@ def load_sources(paths):
 
 
 def _source(path, executor=None, pid=None, events=None, spans=None,
-            epoch_wall=None, offset=0.0, metrics=None):
+            offset=0.0, metrics=None):
     if executor is None and events:
         execs = {e.get("executor") for e in events}
         execs.discard(None)
@@ -213,7 +218,7 @@ def _source(path, executor=None, pid=None, events=None, spans=None,
     return {
         "path": path, "executor": executor, "pid": pid,
         "events": events or [], "spans": spans or [],
-        "epoch_wall": epoch_wall, "offset": float(offset),
+        "offset": float(offset),
         "metrics": metrics,
     }
 
@@ -324,13 +329,18 @@ def critical_path(spans):
     }
 
 
+#: the serving scheduler's own trace: the phases of every pass of the
+#: job, so always the most span time and never a request's story
+SCHEDULER_TRACE = "engine"
+
+
 def _busiest_trace(spans):
     """The trace id with the most recorded span time (the incident's
     busiest request/step — where the critical path is computed)."""
     totals = {}
     for s in spans:
         t = s.get("trace")
-        if t is not None:
+        if t is not None and t != SCHEDULER_TRACE:
             totals[t] = totals.get(t, 0.0) + s.get("dur", 0.0)
     if not totals:
         return None
@@ -477,15 +487,13 @@ def merged_chrome(paths, offsets=None, request=None):
         if not src["spans"]:
             continue
         off = offsets.get(src["executor"], src["offset"])
-        # span t0 is relative to the tracer epoch; epoch_wall anchors
-        # it on the wall clock, the offset aligns executors — merged
-        # ts therefore share one absolute timebase (large, but Chrome
+        # span t0 is Unix seconds and the offset aligns executors, so
+        # merged ts share one absolute timebase (large, but Chrome
         # renders relative to the trace minimum)
-        base = src["epoch_wall"] or 0.0
         trace = {"traceEvents": [
             {
                 "name": s["name"], "ph": "X",
-                "ts": round((base + s["t0"]) * 1e6, 3),
+                "ts": round(s["t0"] * 1e6, 3),
                 "dur": round(s.get("dur", 0.0) * 1e6, 3),
                 "pid": src.get("pid") or 0,
                 "tid": s.get("tid", 0),

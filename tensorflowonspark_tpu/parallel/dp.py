@@ -337,6 +337,7 @@ class SyncTrainer(object):
         # the step/feed-wait histograms — null-object no-ops when
         # TFOS_TELEMETRY=0 (docs/observability.md)
         from tensorflowonspark_tpu import telemetry
+        from tensorflowonspark_tpu import tensorboard as _tb
 
         tracer = telemetry.get_tracer()
         reg = telemetry.get_registry()
@@ -358,7 +359,8 @@ class SyncTrainer(object):
             limit = steps_per_execution
             if max_steps is not None:
                 limit = min(limit, max_steps - steps)
-            t_feed0 = _time.perf_counter()
+            trace_id = "step%d" % steps
+            t_feed0 = tracer.now()
             group, stop = collect_ready_group(
                 feed, batch_size, limit, columnar=columnar,
                 preprocess=preprocess,
@@ -371,16 +373,15 @@ class SyncTrainer(object):
                 subs.append(sub)
             if not group:
                 break
-            feed_wait = _time.perf_counter() - t_feed0
+            feed_wait = tracer.now() - t_feed0
             m_feed_hist.observe(feed_wait)
             tracer.add(
                 "feed_wait", t_feed0, feed_wait,
-                trace="step%d" % steps, batches=len(group),
+                trace=trace_id, batches=len(group),
             )
             if step_callback is not None:
                 step_callback(steps)
             t_step0 = _time.perf_counter()
-            trace_id = "step%d" % steps
             if len(group) == 1:
                 t_h2d = _time.perf_counter()
                 with tracer.span("h2d", trace=trace_id):
@@ -416,11 +417,13 @@ class SyncTrainer(object):
             steps += len(group)
             # feed the env-var-driven jax.profiler capture, if one is
             # live in this process (tensorboard.start_profile)
-            from tensorflowonspark_tpu import tensorboard as _tb
-
             _tb.profile_step(len(group))
             if metrics_callback is not None:
-                metrics_callback(steps, metrics)
+                # where a caller reads the step's metrics it waits for
+                # the step: the span separates that wait from the
+                # host's own part of the step
+                with tracer.span("train.callback", trace=trace_id):
+                    metrics_callback(steps, metrics)
             if (
                 checkpointer is not None
                 and checkpoint_every
@@ -428,8 +431,9 @@ class SyncTrainer(object):
             ):
                 # durable BEFORE commit: a committed partition must
                 # never be lost to a crash between the two
-                checkpointer.save(steps, state, wait=True)
-                feed.commit_partitions()
+                with tracer.span("train.checkpoint", trace=trace_id):
+                    checkpointer.save(steps, state, wait=True)
+                    feed.commit_partitions()
             if log_every and (steps % log_every < len(group)):
                 logger.info(
                     "step %d loss %.4f", steps, float(metrics["loss"])
@@ -451,8 +455,9 @@ class SyncTrainer(object):
         if checkpointer is not None and checkpointer.latest_step() != steps:
             # final durable save (skipped when a resumed run made no
             # progress — that step already exists on disk)
-            checkpointer.save(steps, state, wait=True)
-            feed.commit_partitions()
+            with tracer.span("train.checkpoint", trace="step%d" % steps):
+                checkpointer.save(steps, state, wait=True)
+                feed.commit_partitions()
         return state
 
 

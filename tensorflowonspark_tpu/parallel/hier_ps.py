@@ -50,7 +50,6 @@ diagram and tuning guidance.
 import logging
 import queue as _queue
 import threading
-import time
 
 import numpy as np
 
@@ -454,9 +453,9 @@ class DcnLink(object):
                     # pending for the successor instead of pushing on
                     # a broken epoch
                     continue
-                t0 = time.perf_counter()
+                t0 = self._tracer.now()
                 host = jax.device_get(delta)
-                dur = time.perf_counter() - t0
+                dur = self._tracer.now() - t0
                 self._m_rb_hist.observe(dur)
                 self._tracer.add(
                     "hier.dcn_readback", t0, dur, trace="hier", window=seq
@@ -471,7 +470,7 @@ class DcnLink(object):
                         host,
                         header_extra={"pod": self.pod_id, "window": seq},
                     )
-                self._m_push_hist.observe(time.perf_counter() - t0)
+                self._m_push_hist.observe(self._tracer.now() - t0)
                 self._m_windows.inc()
                 with self._lock:
                     self._fresh = (fresh, base)
@@ -769,7 +768,6 @@ class HierTrainer(object):
         """One overlapped step: dispatch backward, close the PREVIOUS
         step's apply span (it was held open across this dispatch — the
         recorded overlap), dispatch apply, leave its span open."""
-        t_grad = time.perf_counter()
         with self._tracer.span(
             "hier.overlap_grad", trace="hier", step=self._step_idx,
         ):
@@ -780,11 +778,10 @@ class HierTrainer(object):
             # AFTER this step's backward was dispatched: that ordering
             # is the overlap, and the span records it
             self._tracer.add(
-                "hier.overlap_apply", t0, time.perf_counter() - t0,
+                "hier.overlap_apply", t0, self._tracer.now() - t0,
                 trace="hier", step=idx,
             )
-        self._apply_open = (time.perf_counter(), self._step_idx)
-        del t_grad
+        self._apply_open = (self._tracer.now(), self._step_idx)
         new_params, new_opt = self._apply_fn(params, opt_state, grads)
         self._step_idx += 1
         return new_params, new_opt, loss
@@ -794,7 +791,7 @@ class HierTrainer(object):
             t0, idx = self._apply_open
             self._apply_open = None
             self._tracer.add(
-                "hier.overlap_apply", t0, time.perf_counter() - t0,
+                "hier.overlap_apply", t0, self._tracer.now() - t0,
                 trace="hier", step=idx,
             )
 

@@ -1071,13 +1071,14 @@ class _GradDrain(object):
 
         # the async-PS plane's per-step readback gets its own
         # span + histogram so the step trace shows where the wall went
-        t0 = time.perf_counter()
+        tracer = telemetry.get_tracer()
+        t0 = tracer.now()
         out = jax.device_get(tree)
-        dur = time.perf_counter() - t0
+        dur = tracer.now() - t0
         telemetry.get_registry().histogram(
             "ps.grad_readback_sec"
         ).observe(dur)
-        telemetry.get_tracer().add("grad_readback", t0, dur, trace="ps")
+        tracer.add("grad_readback", t0, dur, trace="ps")
         return out
 
     def submit(self, device_grads):

@@ -979,6 +979,9 @@ class ServingEngine(object):
             "eos_at": None,
             "out": None,
             "submit": now,
+            # the same moment on the tracer's clock: where the
+            # request's queue_wait span starts
+            "t_submit": self._tracer.now(),
             "deadline_at": None if deadline is None else now + deadline,
         }
 
@@ -1029,7 +1032,11 @@ class ServingEngine(object):
         a row arrives) whenever the engine is otherwise idle."""
         while not self._exhausted:
             try:
-                row = next(it)
+                with self._tracer.span(
+                    "engine.pull", trace="engine",
+                    chunk=self._chunk_index,
+                ):
+                    row = next(it)
             except StopIteration:
                 self._exhausted = True
                 return None
@@ -1182,8 +1189,8 @@ class ServingEngine(object):
                 # queue wait ended the instant this admit pass reached
                 # the request — record the interval just spent waiting
                 self._tracer.add(
-                    "queue_wait", time.perf_counter() - wait, wait,
-                    trace=rid,
+                    "queue_wait", req["t_submit"],
+                    self._tracer.now() - req["t_submit"], trace=rid,
                 )
             try:
                 # admit is a single ASYNC dispatch; the first token
@@ -1417,6 +1424,50 @@ class ServingEngine(object):
 
     # -- decode + recovery ---------------------------------------------
 
+    def _step_chunk(self, idx):
+        """Dispatch chunk ``idx`` and wait for its tokens, under the
+        watchdog when there is one; None when the watchdog fired (state
+        already recovered).  The synchronising half is the span
+        ``engine.chunk.wait``: from the moment the chunk had been
+        dispatched (read on whichever thread dispatched it) until the
+        scheduler holds the tokens."""
+        wedge = self._wedge
+        wd = self._watchdog
+        tracer = self._tracer
+        dispatch_chunk = getattr(self.decoder, "dispatch_chunk", None)
+        dispatched = []
+
+        def step():
+            if wedge is not None:
+                wedge(idx)
+            if wd is not None and wd.abandoned:
+                # the scheduler timed this dispatch out while the
+                # fault gate held it; never touch the decoder from
+                # the stale thread
+                return None
+            # a decoder with no split (tests' fakes): the whole step
+            # is the wait
+            split = dispatch_chunk is not None
+            pending = dispatch_chunk() if split else None
+            dispatched.append(tracer.now())
+            return (self.decoder.resolve_chunk(pending) if split
+                    else self.decoder.step_chunk())
+
+        try:
+            toks = step() if wd is None else wd.call(
+                step, self.watchdog_timeout)
+        except WatchdogTimeout as e:
+            logger.warning("serving watchdog: %s — recovering "
+                           "%d in-flight request(s)", e,
+                           len(self._slot_req))
+            self._recover()
+            return None
+        tracer.add(
+            "engine.chunk.wait", dispatched[0],
+            tracer.now() - dispatched[0], trace="engine", chunk=idx,
+        )
+        return toks
+
     def _run_chunk(self):
         """One decode chunk under the watchdog; returns a
         ``(tokens [B, T], valid [B])`` pair — row ``r``'s tokens are
@@ -1428,44 +1479,27 @@ class ServingEngine(object):
         idx = self._chunk_index
         self._chunk_index += 1
         t_chunk0 = time.perf_counter()
-        wedge = self._wedge
-        wd = self._watchdog
-        if wd is None:
-            if wedge is not None:
-                wedge(idx)
-            toks = self.decoder.step_chunk()
-        else:
-            def dispatch():
-                if wedge is not None:
-                    wedge(idx)
-                if wd.abandoned:
-                    # the scheduler timed this dispatch out while the
-                    # fault gate held it; never touch the decoder from
-                    # the stale thread
-                    return None
-                return self.decoder.step_chunk()
-
-            try:
-                toks = wd.call(dispatch, self.watchdog_timeout)
-            except WatchdogTimeout as e:
-                logger.warning("serving watchdog: %s — recovering "
-                               "%d in-flight request(s)", e,
-                               len(self._slot_req))
-                self._recover()
+        with self._tracer.span(
+            "engine.chunk", trace="engine", chunk=idx,
+            live=len(self._slot_req), slots=self.num_slots,
+        ) as chunk_span:
+            toks = self._step_chunk(idx)
+            if toks is None:
                 return None
-        self.stats["chunks"] += 1
-        self._m["chunks"].inc()
-        if self._profile is not None:
-            self._profile.step()
+            self.stats["chunks"] += 1
+            self._m["chunks"].inc()
+            if self._profile is not None:
+                self._profile.step()
         dur = time.perf_counter() - t_chunk0
         self.stats["decode_wall_sec"] += dur
         if self._tracer.enabled:
             # one dispatch serves every in-flight lane: attribute the
-            # SAME interval to each request's trace so a single
-            # request's trace stays connected admission→…→emit
+            # SAME interval (engine.chunk's, timed once) to each
+            # request's trace so a single request's trace stays
+            # connected admission→…→emit
             for req in self._slot_req.values():
                 self._tracer.add(
-                    "decode_chunk", t_chunk0, dur,
+                    "decode_chunk", chunk_span.t0, chunk_span.dur,
                     trace=req["rid"], chunk=idx,
                 )
         if self._slot_req and self._ledger.enabled:
@@ -1929,6 +1963,20 @@ class ServingEngine(object):
 
     # -- the scheduling loop -------------------------------------------
 
+    def _emit_ready(self, chunk):
+        """Yield the rows that are ready, in input order.  The time
+        suspended at each ``yield`` is the consumer's: the span
+        ``engine.yielded``, recorded after the fact so that it is never
+        the parent of a span the consumer opens on this thread."""
+        tracer = self._tracer
+        for r in self._drain_ready():
+            t0 = tracer.now()
+            yield r
+            tracer.add(
+                "engine.yielded", t0, tracer.now() - t0,
+                trace="engine", chunk=chunk,
+            )
+
     def serve(self, rows):
         """Run the engine over ``rows``; yields output rows/records in
         input order.  Fills ``self.stats`` with ``latency_sec`` /
@@ -1937,28 +1985,34 @@ class ServingEngine(object):
         ``errors`` / ``shed`` / ``expired`` / ``degraded`` /
         ``watchdog_fires`` / ``recovered``."""
         it = iter(rows)
+        tracer = self._tracer
         try:
             while True:
+                # one pass: every phase is a span of the trace
+                # "engine", tagged with the pass's chunk index
+                chunk = self._chunk_index
                 # lifecycle plane first: probation rollback, then at
                 # most one validated swap per pass — both run between
                 # chunks, never concurrently with a dispatch
-                self._maybe_swap()
-                self._maybe_retune()
-                self._maybe_reap()
-                self._refill(it)
-                self._expire_pending()
-                if self._draining:
-                    self._drain_pending()
-                progressed = self._admit_free(it)
-                for r in self._drain_ready():
-                    yield r
+                with tracer.span("engine.lifecycle", trace="engine",
+                                 chunk=chunk):
+                    self._maybe_swap()
+                    self._maybe_retune()
+                    self._maybe_reap()
+                    self._refill(it)
+                    self._expire_pending()
+                    if self._draining:
+                        self._drain_pending()
+                with tracer.span("engine.admit", trace="engine",
+                                 chunk=chunk):
+                    progressed = self._admit_free(it)
+                yield from self._emit_ready(chunk)
                 if not self._slot_req:
                     if self._draining:
                         # drained: nothing in flight, nothing may be
                         # admitted — the job is over regardless of
                         # what the source still holds
-                        for r in self._drain_ready():
-                            yield r
+                        yield from self._emit_ready(chunk)
                         return
                     if self._pending or not self._exhausted:
                         if progressed:
@@ -1981,32 +2035,32 @@ class ServingEngine(object):
                             "continuous scheduler cannot make progress "
                             "(no slots available)"
                         )
-                    for r in self._drain_ready():
-                        yield r
+                    yield from self._emit_ready(chunk)
                     return
                 block = self._run_chunk()
                 if block is None:
                     continue  # watchdog fired; state already recovered
                 toks, valid = block
-                t_chunk = self._clock()
-                for slot, req in list(self._slot_req.items()):
-                    row = (
-                        toks[slot] if valid is None
-                        else toks[slot][:int(valid[slot])]
-                    )
-                    if self._consume(req, row):
-                        self._finalize(req, t_chunk)
-                        self.decoder.evict(slot)
-                        del self._slot_req[slot]
-                    elif (req["deadline_at"] is not None
-                          and t_chunk > req["deadline_at"]):
-                        self._expire_slot(slot, req, t_chunk)
-                if (self._draining
-                        and self._drain_deadline_at is not None
-                        and t_chunk > self._drain_deadline_at):
-                    self._drain_cancel_slots(t_chunk)
-                for r in self._drain_ready():
-                    yield r
+                with tracer.span("engine.consume", trace="engine",
+                                 chunk=chunk):
+                    t_chunk = self._clock()
+                    for slot, req in list(self._slot_req.items()):
+                        row = (
+                            toks[slot] if valid is None
+                            else toks[slot][:int(valid[slot])]
+                        )
+                        if self._consume(req, row):
+                            self._finalize(req, t_chunk)
+                            self.decoder.evict(slot)
+                            del self._slot_req[slot]
+                        elif (req["deadline_at"] is not None
+                              and t_chunk > req["deadline_at"]):
+                            self._expire_slot(slot, req, t_chunk)
+                    if (self._draining
+                            and self._drain_deadline_at is not None
+                            and t_chunk > self._drain_deadline_at):
+                        self._drain_cancel_slots(t_chunk)
+                yield from self._emit_ready(chunk)
         finally:
             self._update_reuse_stats()
             if self._profile is not None:

@@ -28,10 +28,10 @@ call-site code:
   SloEngine).
 
 A dump bundle is one JSON file: the trigger event, the journal rings,
-the tracer's span ring (plus its wall-clock epoch, so the forensics
-analyzer can align spans across executors with the heartbeat-estimated
-clock offsets), the registry snapshot and the delta since the
-recorder started, and process identity.  Dumps are rate-limited per
+the tracer's span ring (starts in Unix seconds, the journal events'
+clock, so the forensics analyzer can align spans across executors
+with the heartbeat-estimated clock offsets), the registry snapshot
+and the delta since the recorder started, and process identity.  Dumps are rate-limited per
 trigger kind and capped per process — a crash loop must not fill the
 disk.
 
@@ -237,11 +237,6 @@ class FlightRecorder(object):
             "pid": os.getpid(),
             "executor": self.journal.executor,
             "trigger": trigger.to_dict() if trigger is not None else None,
-            # the alignment anchor: span t0/dur are relative to the
-            # tracer's perf_counter epoch; epoch_wall places them on
-            # the wall clock the journal events and the heartbeat
-            # clock-offset estimates live on
-            "clock": {"epoch_wall": self.tracer.epoch_wall},
             "events": [e.to_dict() for e in self.journal.events()],
             "spans": self.tracer.spans(),
             "metrics": snap,

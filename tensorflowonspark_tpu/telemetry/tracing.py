@@ -10,13 +10,24 @@ to reconstruct the tree.  Two recording styles:
 - ``tracer.add("decode_chunk", t0, dur, trace="req3", chunk=2)`` —
   post-hoc, for hot loops that time once and attribute the SAME
   interval to several traces (the serving engine labels one chunk
-  dispatch onto every in-flight request's trace this way).
+  dispatch onto every in-flight request's trace this way); ``t0`` is
+  a reading of :meth:`Tracer.now`, the one clock call sites use.
+
+**One clock.**  A span's ``t0`` is seconds on the clock
+``jax.profiler`` stamps its events with — the Unix epoch, what
+``time.time_ns()`` reads (checked on the CPU and on a TPU v5e: an
+exported ``.xplane.pb`` holds its events relative to the session's
+start and that start, in Unix ns, as ``profile_start_time`` on its
+``Task Environment`` plane; :func:`profile_start_ns` reads it).  ``dur``
+comes from the monotonic clock.  A context-managed span also enters a
+``jax.profiler.TraceAnnotation`` named ``tfos.<span name>``, so a
+profiler capture (``tensorboard.start_profile``) shows the scheduler
+beside the device, and the same region can be read from the ring and
+from the capture.  :func:`attribute` says which span covers each piece
+of a set of intervals (the device's idle gaps, say).
 
 Export is Chrome-trace JSON (``{"traceEvents": [...]}``) loadable in
-``chrome://tracing`` / Perfetto; ``ts``/``dur`` are microseconds since
-the tracer's epoch.  On-demand *device* traces (XLA timelines) are the
-:mod:`tensorflowonspark_tpu.tensorboard` profiler hook's job — this
-module covers the host-side scheduling story those traces lack.
+``chrome://tracing`` / Perfetto; ``ts`` is Unix microseconds.
 
 Disabled mode (``TFOS_TELEMETRY=0`` or ``set_enabled(False)``):
 ``span()`` returns a shared null context manager and ``add`` is a
@@ -27,6 +38,7 @@ import collections
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -54,12 +66,26 @@ class _NullSpan(object):
 
 _NULL_SPAN = _NullSpan()
 
+#: how long :meth:`Tracer.now` runs on the monotonic clock before it
+#: reads the wall clock again: a stepped wall clock (the profiler
+#: follows it) is followed within this
+ANCHOR_REFRESH_NS = 1000000000
+
+
+def _annotation(name):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` — the null span
+    in a process that has not imported jax: it has no profiler to
+    annotate for, and nothing is imported on its behalf."""
+    profiler = sys.modules.get("jax.profiler")
+    return _NULL_SPAN if profiler is None else profiler.TraceAnnotation(name)
+
 
 class _SpanCtx(object):
-    """Live span context: records on ``__exit__``."""
+    """Live span context: records on ``__exit__``, after which ``t0``
+    and ``dur`` hold what was recorded."""
 
-    __slots__ = ("_tracer", "name", "trace", "attrs", "_t0", "_id",
-                 "_parent")
+    __slots__ = ("_tracer", "name", "trace", "attrs", "_p0", "_id",
+                 "_parent", "_annotation", "t0", "dur")
 
     def __init__(self, tracer, name, trace, attrs):
         self._tracer = tracer
@@ -81,18 +107,23 @@ class _SpanCtx(object):
         self._parent = stack[-1][1] if stack else None
         self._id = next(tr._ids)
         stack.append((self.trace, self._id))
-        self._t0 = time.perf_counter()
+        self._annotation = _annotation("tfos." + self.name)
+        self._annotation.__enter__()
+        self._p0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
+        p1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         tr = self._tracer
         stack = tr._stack()
         if stack:
             stack.pop()
+        self.t0 = tr._on_wall(self._p0)
+        self.dur = (p1 - self._p0) * 1e-9
         tr._record(
             self.name, self.trace, self._id, self._parent,
-            self._t0, dur, self.attrs,
+            self.t0, self.dur, self.attrs,
         )
         return False
 
@@ -114,13 +145,7 @@ class Tracer(object):
         #: spans without this counter reads as "nothing happened"
         self.dropped_spans = 0
         self._m_dropped = None
-        #: perf_counter at construction — span timestamps are relative
-        #: to this epoch (Chrome-trace ``ts`` microseconds)
-        self.epoch = time.perf_counter()
-        #: wall clock at the same instant: ``epoch_wall + span["t0"]``
-        #: maps a span onto the journal/clock-sync wall timeline — what
-        #: the forensics analyzer aligns cross-executor traces with
-        self.epoch_wall = time.time()
+        self._anchor()
         #: journal every mark() bridges into (ISSUE 11): None = the
         #: process-wide default, resolved lazily; pass an explicit
         #: EventJournal to isolate (tests)
@@ -137,6 +162,38 @@ class Tracer(object):
 
     def set_enabled(self, flag):
         self._enabled = bool(flag)
+
+    # -- the clock ------------------------------------------------------
+
+    def _anchor(self):
+        """Read the wall clock between two readings of the monotonic
+        one, and keep the tightest of three tries: a read the
+        scheduler interrupted would move every start by as much."""
+        best = None
+        for _ in range(3):
+            p0 = time.perf_counter_ns()
+            wall = time.time_ns()
+            p1 = time.perf_counter_ns()
+            if best is None or p1 - p0 < best[0]:
+                best = (p1 - p0, wall - (p0 + p1) // 2, p1)
+        self._wall_less_mono_ns = best[1]
+        self._anchored_ns = best[2]
+
+    def _on_wall(self, mono_ns):
+        """Seconds on the profiler's clock of a ``perf_counter_ns``
+        reading."""
+        if mono_ns - self._anchored_ns > ANCHOR_REFRESH_NS:
+            self._anchor()
+        return (mono_ns + self._wall_less_mono_ns) * 1e-9
+
+    def now(self):
+        """Seconds on the profiler's clock (see the module docstring):
+        the start of every span, and the one clock a call site of
+        :meth:`add` reads.  Between two looks at the wall clock (at
+        most ``ANCHOR_REFRESH_NS`` apart) it advances with the
+        monotonic clock, so the difference of two readings is a
+        duration."""
+        return self._on_wall(time.perf_counter_ns())
 
     # -- recording ------------------------------------------------------
 
@@ -155,8 +212,8 @@ class Tracer(object):
         return _SpanCtx(self, name, trace, attrs or None)
 
     def add(self, name, t0, dur, trace=None, **attrs):
-        """Record an already-measured interval (``t0`` from
-        ``time.perf_counter()``)."""
+        """Record an already-measured interval (``t0`` a reading of
+        :meth:`now`, ``dur`` seconds)."""
         if not self._enabled:
             return
         self._record(
@@ -182,7 +239,7 @@ class Tracer(object):
         if extra:
             merged.update(extra)
         self._record(
-            name, trace, next(self._ids), None, time.perf_counter(),
+            name, trace, next(self._ids), None, self.now(),
             0.0, merged or None, severity=severity,
         )
         j = self._journal
@@ -214,7 +271,7 @@ class Tracer(object):
             "name": name,
             "trace": trace,
             "id": span_id,
-            "t0": t0 - self.epoch,
+            "t0": t0,
             "dur": dur,
             "tid": threading.get_ident(),
         }
@@ -253,8 +310,9 @@ class Tracer(object):
 
     def export_chrome(self, trace=None):
         """Chrome-trace / Perfetto JSON object.  Spans map to complete
-        ('X') events; the trace id rides ``args.trace`` and the span
-        tree rides ``args.parent``.  Also carries ``process_name`` /
+        ('X') events with ``ts`` in Unix microseconds (a viewer shows
+        them from the trace's first event); the trace id rides
+        ``args.trace`` and the span tree rides ``args.parent``.  Also carries ``process_name`` /
         ``thread_name`` metadata ('M') events — appended AFTER the
         spans, so old consumers indexing ``traceEvents[0]`` still see
         the first span — keeping a merged multi-executor trace
@@ -355,6 +413,58 @@ def merge_traces(parts):
             })
     events.sort(key=lambda e: e.get("ts", 0.0))
     return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+
+
+def attribute(intervals, spans):
+    """Which span covers each piece of ``intervals``: ``{span name or
+    "unattributed": seconds}``, summing to the intervals' total.
+
+    ``intervals`` are ``(start, end)`` pairs in seconds on the spans'
+    clock (the device's idle gaps from a profiler capture, moved by
+    :func:`profile_start_ns`); ``spans`` are records as
+    :meth:`Tracer.spans` returns them.  Every interval is cut where a
+    span begins or ends, and each piece goes to the innermost span
+    that covers it — the shortest; of equals, the one recorded first.
+    Marks (zero duration) cover nothing."""
+    intervals = [iv for iv in intervals if iv[1] > iv[0]]
+    if not intervals:
+        return {}
+    first = min(iv[0] for iv in intervals)
+    last = max(iv[1] for iv in intervals)
+    timed = [
+        (s["t0"], s["t0"] + s["dur"], s["name"])
+        for s in spans
+        if s["dur"] > 0.0 and s["t0"] < last and s["t0"] + s["dur"] > first
+    ]
+    total = {}
+    for start, end in intervals:
+        over = [sp for sp in timed if sp[0] < end and sp[1] > start]
+        cuts = sorted({start, end} | {
+            t for sp in over for t in sp[:2] if start < t < end})
+        for a, b in zip(cuts, cuts[1:]):
+            mid = 0.5 * (a + b)
+            inside = [sp for sp in over if sp[0] <= mid <= sp[1]]
+            name = (
+                min(inside, key=lambda sp: sp[1] - sp[0])[2]
+                if inside else "unattributed"
+            )
+            total[name] = total.get(name, 0.0) + (b - a)
+    return total
+
+
+def profile_start_ns(xplane_path):
+    """Unix nanoseconds at which the profiler session that wrote
+    ``xplane_path`` began, or None where the file does not say.  An
+    exported capture holds every event relative to this moment, so a
+    span of the ring starts ``t0 * 1e9 - profile_start_ns(path)``
+    nanoseconds into it."""
+    from jax.profiler import ProfileData
+
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if plane.name == "Task Environment":
+            start = dict(plane.stats).get("profile_start_time")
+            return None if start is None else int(start)
+    return None
 
 
 _GLOBAL = None

@@ -93,12 +93,32 @@ def test_bundle_contents_and_clock_anchor(tmp_path):
     bundle = blackbox_mod.load_dump(rec.dumps[0]["path"])
     assert bundle["format"] == blackbox_mod.BUNDLE_FORMAT
     assert bundle["pid"] == os.getpid()
-    assert bundle["clock"]["epoch_wall"] == pytest.approx(
-        tr.epoch_wall
+    # span starts are absolute (Unix seconds, the events' clock): the
+    # bundle carries no epoch to add to them
+    assert "clock" not in bundle
+    assert all(
+        abs(s["t0"] - bundle["time"]) < 60.0 for s in bundle["spans"]
     )
     assert {s["name"] for s in bundle["spans"]} >= {"step", "dispatch"}
     assert "counters" in bundle["metrics"]
     rec.stop()
+
+
+def test_old_bundle_with_epoch_wall_loads_onto_absolute_starts(tmp_path):
+    # a dump written before span starts were absolute: relative t0
+    # plus clock.epoch_wall; forensics moves them as it loads
+    path = tmp_path / "old.json"
+    path.write_text(json.dumps({
+        "format": blackbox_mod.BUNDLE_FORMAT, "executor": 0, "pid": 1,
+        "events": [], "clock": {"epoch_wall": 1000.0},
+        "spans": [{"name": "step", "trace": "t", "id": 1, "t0": 2.5,
+                   "dur": 0.1, "tid": 1}],
+    }))
+    src, = forensics.load_sources([str(path)])
+    assert src["spans"][0]["t0"] == pytest.approx(1002.5)
+    ev, = [e for e in forensics.merged_chrome([str(path)])["traceEvents"]
+           if e.get("ph") == "X"]
+    assert ev["ts"] == pytest.approx(1002.5e6)
 
 
 def test_load_dump_rejects_non_bundles(tmp_path):
@@ -171,6 +191,15 @@ def test_critical_path_ignores_marks_and_handles_empty():
     assert forensics.critical_path(marks_only)["path"] == []
 
 
+def test_busiest_trace_is_a_request_never_the_scheduler():
+    # the scheduler's own trace holds every pass of the job: the most
+    # span time by far, and no request's story
+    spans = [_span("engine.chunk", i, float(i), 0.9, trace="engine")
+             for i in range(1, 9)]
+    spans.append(_span("prefill", 20, 1.0, 0.5, trace="req7"))
+    assert forensics._busiest_trace(spans) == "req7"
+
+
 # ----------------------------------------------------------------------
 # timeline alignment + explain
 # ----------------------------------------------------------------------
@@ -181,19 +210,19 @@ def test_build_timeline_applies_offsets_and_dedups():
         {"path": "a", "executor": 0, "pid": 10, "offset": 0.0,
          "events": [Event("restart", ts=100.0, seq=1, pid=10,
                           executor=0, severity="warn").to_dict()],
-         "spans": [], "epoch_wall": None},
+         "spans": []},
         # executor 1's clock runs 5s ahead; its event REALLY happened
         # first — only the -5s offset reveals that
         {"path": "b", "executor": 1, "pid": 11, "offset": -5.0,
          "events": [Event("watchdog_fire", ts=104.0, seq=1, pid=11,
                           executor=1, severity="page").to_dict()],
-         "spans": [], "epoch_wall": None},
+         "spans": []},
         # the same executor-0 event again (journal export + dump both
         # present): deduped
         {"path": "c", "executor": 0, "pid": 10, "offset": 0.0,
          "events": [Event("restart", ts=100.0, seq=1, pid=10,
                           executor=0, severity="warn").to_dict()],
-         "spans": [], "epoch_wall": None},
+         "spans": []},
     ]
     tl = forensics.build_timeline(sources)
     assert [e["kind"] for e in tl] == ["watchdog_fire", "restart"]

@@ -587,11 +587,11 @@ class TestForensicsExemplars:
         h.observe(0.8, exemplar="flt1-req3")
         spans = [
             {"name": "prefill", "trace": "flt1-req3", "id": 1,
-             "t0": 0.0, "dur": 0.1, "tid": 1},
+             "t0": 100.0, "dur": 0.1, "tid": 1},
             {"name": "decode_chunk", "trace": "flt1-req3", "id": 2,
-             "parent": 1, "t0": 0.02, "dur": 0.7, "tid": 1},
+             "parent": 1, "t0": 100.02, "dur": 0.7, "tid": 1},
             {"name": "emit", "trace": "other", "id": 3,
-             "t0": 0.0, "dur": 0.9, "tid": 1},
+             "t0": 100.0, "dur": 0.9, "tid": 1},
         ]
         bundle = {
             "format": bb.BUNDLE_FORMAT, "executor": 0, "pid": 1234,
@@ -601,7 +601,6 @@ class TestForensicsExemplars:
                 "trace": "serve", "attrs": {},
             }],
             "spans": spans,
-            "clock": {"epoch_wall": 100.0},
             "metrics": {"histograms": {
                 "serving.request_latency_sec": h.snapshot(),
             }},
