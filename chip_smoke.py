@@ -291,6 +291,35 @@ def phase_kernels(tiny):
         )(None, q, kp, vp, tables, lengths, scales)
         errs[name] = check(name, err, TOL_FWD)
 
+    # -- the same kernel over contiguous banks vs the masked einsums ----
+    # the serving cell's own geometry on the chip (8 kv heads of 128,
+    # 4 query heads each, banks of 1536), mixed positions and pads
+    bk = dict(hkv=2, group=2, bank=256, slots=4) if tiny else dict(
+        hkv=8, group=4, bank=1536, slots=16)
+    ks = jax.random.split(jax.random.PRNGKey(27), 3)
+    shape = (bk["slots"], bk["bank"], bk["hkv"], 128)
+    q = jax.random.normal(
+        ks[0], (bk["slots"], bk["hkv"] * bk["group"], 128), jnp.bfloat16)
+    kb = jax.random.normal(ks[1], shape, jnp.bfloat16)
+    vb = jax.random.normal(ks[2], shape, jnp.bfloat16)
+    positions = jnp.asarray(
+        rng.randint(0, bk["bank"], (bk["slots"],)), jnp.int32)
+    pads = jnp.minimum(
+        jnp.asarray(rng.randint(0, 64, (bk["slots"],)), jnp.int32),
+        positions)
+
+    def masked_dot(q, kb, vb, positions, pads):
+        kpos = jnp.arange(kb.shape[1])[None, :]
+        vis = jnp.logical_and(
+            kpos <= positions[:, None], kpos >= pads[:, None])
+        mask = jnp.where(vis, 0.0, -jnp.inf)[:, None, None, :]
+        return dot_attention(
+            q[:, None], kb, vb, causal=False, mask=mask)[:, 0]
+
+    (err,) = compare(pa.bank_attention, masked_dot, with_grads=False)(
+        None, q, kb, vb, positions, pads)
+    errs["bank_gqa"] = check("bank_gqa", err, TOL_FWD)
+
     # -- grouped matmul fwd / dx / dw vs gmm_reference -------------------
     g = sz["gmm"]
     n = g["tiles"] * g["bm"]

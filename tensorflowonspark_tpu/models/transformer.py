@@ -142,6 +142,25 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(x.dtype)
 
 
+def decode_bank_block(cfg, bank_len):
+    """Tokens per block when single-token per-slot decode over
+    contiguous banks of ``bank_len`` goes through the block-walking
+    kernel (:func:`..ops.paged_attention.bank_attention`), else None
+    (``dot_attention`` over the whole bank).  Read from what the code
+    can see, no knob: a mesh rules the kernel out (GSPMD does not
+    partition a Pallas call), and the geometry must be tile-legal —
+    ``head_dim`` a multiple of 128 and a block size that divides the
+    bank — which the tiny head sizes of CPU tests are not."""
+    if cfg.mesh is not None or cfg.kv_layout == "paged":
+        return None
+    from tensorflowonspark_tpu.ops.paged_attention import bank_block
+
+    return bank_block(
+        bank_len, cfg.head_dim,
+        jnp.int8 if cfg.cache_dtype == "int8" else cfg.jdtype,
+    )
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -160,6 +179,10 @@ class Attention(nn.Module):
         dense = lambda name, feats: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, dtype=cfg.jdtype, name=name
         )
+        out_proj = lambda o: nn.DenseGeneral(  # noqa: E731
+            cfg.embed_dim, axis=(-2, -1), use_bias=False,
+            dtype=cfg.jdtype, name="out",
+        )(o)
         if cfg.fused_qkv:
             if hkv != h:
                 raise ValueError(
@@ -181,11 +204,14 @@ class Attention(nn.Module):
         if decode:
             # KV-cache autoregressive path: keys/values append at the
             # write pointer (cache stores POST-rope keys — RoPE is
-            # absolute, so cached rotations stay valid); the query
-            # attends over the whole cache under an additive mask.
-            # Always dot attention: at s=1..P query rows the O(S²)
-            # logits the flash kernel avoids don't exist, and decode is
-            # HBM-bandwidth-bound on the cache read either way.
+            # absolute, so cached rotations stay valid).  Single-token
+            # per-slot steps read only each slot's live blocks through
+            # the block-walking decode kernel (decode_bank_block);
+            # every other decode call — prefill into the cache,
+            # multi-token spans, the static generate() batch, a mesh —
+            # attends over the whole cache under an additive mask with
+            # dot attention (never flash: at s=1..P query rows the
+            # O(S²) logits the flash kernel avoids don't exist).
             # The write index IS positions[0, 0] (rows are identical by
             # construction) — no per-layer counter to keep in sync with
             # the model-level position variable.  Cache capacity comes
@@ -207,12 +233,20 @@ class Attention(nn.Module):
             if per_slot:
                 # continuous-batching slot mode: every batch lane is an
                 # independent request with its OWN write pointer
-                # (positions[:, 0]), so appends are per-row
-                # dynamic_update_slice (vmapped -> one scatter) instead
-                # of one batch-wide slice write.
+                # (positions[:, 0]), so appends are per-row instead of
+                # one batch-wide slice write: one token a slot is ONE
+                # scatter of B rows; a longer span is a per-row
+                # dynamic_update_slice (vmapped), which XLA:TPU runs
+                # as a loop over the slots, one small copy at a time
+                # (0.22 ms a bank at 64 slots, against 0.007 for the
+                # scatter: PERF.md section 5)
                 row_i = positions[:, 0]
 
                 def _write(bank, val):
+                    if val.shape[1] == 1:
+                        return bank.at[jnp.arange(b), row_i].set(
+                            val[:, 0].astype(bank.dtype)
+                        )
                     return jax.vmap(
                         lambda bank_r, val_r, i_r: jax.lax.dynamic_update_slice(
                             bank_r, val_r.astype(bank_r.dtype),
@@ -253,6 +287,29 @@ class Attention(nn.Module):
             from tensorflowonspark_tpu.ops.attention import dot_attention
 
             if per_slot:
+                ps = (
+                    pad_start if pad_start is not None
+                    else jnp.zeros((b,), jnp.int32)
+                )
+            if (per_slot and x.shape[1] == 1
+                    and decode_bank_block(cfg, ck.value.shape[1])):
+                # one token a slot: walk the blocks of each slot's live
+                # span [pad_start, position] and nothing else.  The
+                # same visibility as the mask below — causal, window,
+                # pad region, self always — as block skips plus an
+                # in-block mask.
+                from tensorflowonspark_tpu.ops.paged_attention import (
+                    bank_attention,
+                )
+
+                out = bank_attention(
+                    q[:, 0], ck.value, cv.value, positions[:, 0], ps,
+                    window=cfg.attention_window,
+                    k_scale=cks.value if int8_cache else None,
+                    v_scale=cvs.value if int8_cache else None,
+                )
+                return out_proj(out[:, None])
+            if per_slot:
                 # per-row query positions: each slot sees its own
                 # causal horizon, window, and pad region.  Slots keep
                 # self-visibility (kpos == qpos) so a fully-masked idle
@@ -266,10 +323,6 @@ class Attention(nn.Module):
                         kpos[None, None, :]
                         > qpos_r[:, :, None] - cfg.attention_window,
                     )
-                ps = (
-                    pad_start if pad_start is not None
-                    else jnp.zeros((x.shape[0],), jnp.int32)
-                )
                 vis = jnp.logical_or(
                     jnp.logical_and(
                         vis, kpos[None, None, :] >= ps[:, None, None]
@@ -282,13 +335,7 @@ class Attention(nn.Module):
                     k_scale=cks.value if int8_cache else None,
                     v_scale=cvs.value if int8_cache else None,
                 )
-                return nn.DenseGeneral(
-                    cfg.embed_dim,
-                    axis=(-2, -1),
-                    use_bias=False,
-                    dtype=cfg.jdtype,
-                    name="out",
-                )(out)
+                return out_proj(out)
             visible = kpos[None, :] <= qpos[:, None]
             if cfg.attention_window:
                 visible = jnp.logical_and(
@@ -334,13 +381,7 @@ class Attention(nn.Module):
                 block_k=cfg.block_k,
                 window=cfg.attention_window,
             )
-        return nn.DenseGeneral(
-            cfg.embed_dim,
-            axis=(-2, -1),
-            use_bias=False,
-            dtype=cfg.jdtype,
-            name="out",
-        )(out)
+        return out_proj(out)
 
     def _paged_decode(self, x, q, k, v, positions, block_tables, hkv, d):
         """Paged-KV decode (``kv_layout="paged"``): the per-layer cache
@@ -625,8 +666,12 @@ def init_cache(model, batch_size, cache_len=None):
     """A zeroed KV cache for ``batch_size`` sequences.
 
     ``cache_len`` (default ``cfg.max_seq_len``) sizes the per-layer
-    key/value capacity; decode reads and masks the WHOLE cache every
-    step (bandwidth-bound), so size it to the actual generation length.
+    key/value capacity.  The static :func:`generate` batch, a mesh and
+    head sizes the lane does not tile read and mask the WHOLE cache
+    every step (bandwidth-bound), so size it to the actual generation
+    length; the slot decoder's single-token steps read only the blocks
+    of each slot's live span where :func:`decode_bank_block` finds a
+    block size (a bank length that 256 or 128 divides).
     Shapes come from ``jax.eval_shape`` — no parameters are
     materialized and no forward runs."""
     length = cache_len if cache_len is not None else model.cfg.max_seq_len
@@ -1247,6 +1292,28 @@ class SlotDecoder:
         else:
             self.page_pool = None
             self.tables = None
+            if mesh is not None and model.cfg.mesh is None:
+                # the model reads the mesh from its own config when it
+                # picks the decode attention (decode_bank_block): a
+                # Pallas call is not partitioned by GSPMD
+                import dataclasses as _dc
+
+                self.model = Transformer(_dc.replace(model.cfg, mesh=mesh))
+        #: what the decode chunk's flagship attention was built with:
+        #: "kernel" (block-walking, reads live blocks only) or "dot"
+        #: (masked einsums over the whole span; a speculative chunk's
+        #: verify block is a multi-token span, so always this)
+        if self._spec:
+            self._kv_block = None
+        elif self._paged:
+            self._kv_block = (
+                self._page_tokens if self.paged_impl == "kernel" else None
+            )
+        else:
+            self._kv_block = decode_bank_block(
+                self.model.cfg, self._bank_len
+            )
+        self.attn_impl = "kernel" if self._kv_block else "dot"
         self._np = np
         self._qz = qz
         self._rng = jax.random.PRNGKey(int(seed))
@@ -1736,6 +1803,13 @@ class SlotDecoder:
         paged layout ``tables`` carries the per-slot block tables (the
         pool pages are pre-allocated for the whole span, so the scan
         never allocates — one fused dispatch per chunk either way)."""
+        # a lane no request holds (evicted, never admitted) sees itself
+        # alone, whatever span its last request left behind: its
+        # output is thrown away, so its keys are not worth reading
+        pad_start = jnp.where(
+            active, state["pad_start"], jnp.int32(self.cache_len)
+        )
+
         def step(carry, key):
             cache, pos, tok, done = carry
             p = (
@@ -1746,7 +1820,7 @@ class SlotDecoder:
             )
             logits, mut = self.model.apply(
                 {"params": p, "cache": cache}, tok[:, None], decode=True,
-                mutable=["cache"], pad_start=state["pad_start"],
+                mutable=["cache"], pad_start=pad_start,
                 slot_positions=pos, block_tables=tables,
             )
             nxt = self._sample(logits[:, 0], key)
@@ -2424,6 +2498,35 @@ class SlotDecoder:
         :meth:`dispatch_chunk` / :meth:`resolve_chunk`)."""
         return self.resolve_chunk(self.dispatch_chunk())
 
+    def kv_read_tokens(self, live):
+        """``(read, bank)``: key/value positions per layer that the
+        next chunk's first decode step reads, and what the slots'
+        banks hold (slots x bank length).  ``live`` is the scheduler's
+        own record of every request in flight, ``(prompt_len,
+        generated)`` since its admit — no device pull.  Under
+        ``attn_impl == "kernel"`` a slot reads the blocks its live
+        span touches (a left-padded admit's span starts past its pad
+        region, the window cuts it from below) and a lane nobody holds
+        reads one block; under ``"dot"`` every step reads every
+        bank whole."""
+        if self._paged:
+            bank = self.num_slots * self._blocks_per_slot * self._page_tokens
+        else:
+            bank = self.num_slots * self._bank_len
+        t = self._kv_block
+        if not t:
+            return bank, bank
+        window = self.model.cfg.attention_window
+        canonical = self._paged or self._use_prefix
+        read = (self.num_slots - len(live)) * t
+        for n, gen in live:
+            first = 0 if canonical else self.bucket_len(n) - n
+            last = first + n + gen - 1  # where the step's query sits
+            if window:
+                first = max(first, last + 1 - window)
+            read += (last // t - first // t + 1) * t
+        return read, bank
+
     def reuse_stats(self):
         """Cross-request reuse counters: the prefix cache's
         cumulative stats (when attached) plus the speculative
@@ -2662,8 +2765,9 @@ def serving_builder(params, config):
         # per job.  config keys: chunk_size (decode steps between
         # admit/evict points, default 16) and max_prompt_len (sizes
         # the slot cache to bucket(max_prompt_len) + max_new instead
-        # of max_seq_len — decode re-reads the whole cache every
-        # step, so a right-sized cache is pure bandwidth savings).
+        # of max_seq_len — device memory for more slots, and where
+        # decode still reads the whole cache every step (a mesh,
+        # untiled head sizes: decode_bank_block) bandwidth too).
         # Cross-request reuse knobs (docs/serving.md "Prefix cache &
         # speculative decoding"): prefix_cache=true attaches a
         # device-resident radix prefix cache over committed KV blocks

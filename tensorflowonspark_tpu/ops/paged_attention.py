@@ -1,10 +1,12 @@
-"""Paged (block-gather) decode attention over a physical KV page pool.
+"""Block-walking decode attention: one token a slot over a physical
+KV page pool, or over contiguous per-slot banks read as one.
 
 The continuous-batching engine's decode hot loop used to read
-*contiguous per-slot banks* ``[slots, bank_len, heads, dim]``: every
-cached-prefix admit paid a physical segment copy into the admitted
-lane, and a block shared by N slots occupied N copies of HBM.  This
-kernel makes attention consume the prefix cache's block pool DIRECTLY:
+*contiguous per-slot banks* ``[slots, bank_len, heads, dim]`` whole:
+every cached-prefix admit paid a physical segment copy into the
+admitted lane, a block shared by N slots occupied N copies of HBM, and
+every step read every bank's whole length under a mask.  This kernel
+reads blocks, and only the live ones:
 
 - K/V live in ONE physical pool per layer, ``[num_pages, page_tokens,
   kv_heads, head_dim]`` (:class:`~tensorflowonspark_tpu.prefix_cache.
@@ -13,20 +15,27 @@ kernel makes attention consume the prefix cache's block pool DIRECTLY:
   ``[slots, blocks_per_slot]`` of page indices — a cached admit
   *installs indices* (host bookkeeping, zero device copies) and one
   physical page serves every table that references it;
-- the kernel is a flash-style online softmax whose k/v grid dimension
-  walks the slot's block table via scalar-prefetch index maps (the
-  same Mosaic mechanism :mod:`.gmm` uses for expert tiles): block j of
-  slot b fetches physical page ``table[b, j]`` through the BlockSpec,
-  so the gather IS the DMA schedule — no materialized contiguous copy.
+- a contiguous bank ``[slots, bank_len, ...]`` IS such a pool by a
+  free reshape (block ``j`` of slot ``b`` at page ``b * blocks + j``,
+  the identity table), so both layouts share the one kernel body;
+- the kernel is a flash-style online softmax that leaves the pools in
+  HBM and copies a slot's LIVE blocks itself — the blocks its span
+  ``[start, length)`` touches, cut by the window — through a double
+  buffer: block ``i + 1`` (or the next slot's first) is in flight
+  while block ``i`` is computed.  The table lookup IS the copy's
+  source, so the gather is the DMA schedule, and a block outside the
+  span is neither fetched nor computed.
 
-Handles GQA (grouped queries reshape per kv head), sliding-window
+Handles GQA (every query head meets every (token, kv head) row of a
+block in one matmul; other heads' columns are masked), sliding-window
 attention (whole pages behind the horizon are skipped, in-page
-positions masked), int8-KV dequant scales (logit/probability scaling,
-the same factored identities ``dot_attention`` uses), and ragged final
-pages (positions past the slot's live length masked via the prefetched
-``lengths``).
+positions masked), a per-slot first visible position (the pad region
+of a left-padded admit), int8-KV dequant scales (logit/probability
+scaling, the same factored identities ``dot_attention`` uses), and
+ragged final pages (positions past the slot's live length masked via
+the prefetched ``lengths``).
 
-Two entry points:
+Three entry points:
 
 - :func:`paged_attention` — the pallas kernel for single-token decode
   steps (``q [B, H, D]``), the bandwidth-bound hot loop.  Off-TPU it
@@ -34,6 +43,10 @@ Two entry points:
   compat` pallas shims) so CPU tier-1 exercises the real kernel path;
   tiny test shapes are legal there — hardware callers own Mosaic tile
   legality for their head/page geometry, like the gmm kernels.
+- :func:`bank_attention` — the same kernel over contiguous banks
+  (``positions``, ``pad_start``), in blocks of :func:`bank_block`
+  tokens; the per-slot decode branch of ``models/transformer.py``
+  calls it where :func:`bank_block` finds a block size.
 - :func:`paged_gather_attention` — the jnp fallback for MULTI-token
   query spans (suffix prefill at canonical positions, speculative
   verify blocks): gathers the table's pages into a transient
@@ -140,84 +153,152 @@ def _scratch(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  num_blocks, page_tokens, hkv, group, scale, window,
-                  int8_scales):
-    """One (slot, page) grid step of the online softmax.  ``rest`` is
-    ``[ks_ref, vs_ref,] o_ref, acc_ref, m_ref, l_ref``."""
+def _live_blocks(length, start, page_tokens, window):
+    """``(lo, first, count)``: the first position a slot's query can
+    see, and the blocks it can see — the query sits at ``length - 1``
+    and attends ``[start, length)``, cut to the last ``window``
+    positions.  Scalar arithmetic only: what is copied and what is
+    masked are reckoned from the one place."""
+    lo = start
+    if window:
+        lo = jnp.maximum(lo, length - window)
+    first = lo // page_tokens
+    return lo, first, (length - 1) // page_tokens - first + 1
+
+
+def _decode_kernel(tbl_ref, len_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
+                   slots, page_tokens, hkv, group, scale, window,
+                   int8_scales):
+    """The online softmax of ``slots`` slots a grid step, each over its
+    own live blocks.  ``rest`` is ``[ks_hbm, vs_hbm,] o_ref, kbuf,
+    vbuf, [ksbuf, vsbuf,] sem, par, own_ref``.
+
+    The pools stay in HBM; the kernel walks a slot's live blocks
+    itself, block ``i + 1`` copied into one half of a double buffer
+    while block ``i`` is computed from the other, and the NEXT slot's
+    first block started under this slot's last — so a dead block is
+    neither fetched nor a step of anything.  ``par`` (SMEM) carries
+    the buffer half from one grid step to the next; several slots
+    share a grid step so that going from one slot to the next costs a
+    loop iteration, not a pipeline stage with no copy in flight.
+
+    A K/V block arrives as ``[T*Hkv, D]``, one row per (token, kv
+    head) — the free view of ``[T, Hkv, D]`` (the stored layout tiles
+    its last two dims, so folding the heads into the lane dim instead
+    would copy the pool).  Every query head meets every row in ONE
+    matmul each way; the columns of another kv head are masked like
+    positions outside the span (``own_ref``: 0 on a row's own head,
+    ``NEG_INF`` elsewhere, built once), so their probabilities are
+    zero and ``p @ v`` sums a head's own rows only — no per-head
+    slicing or transposes of the block."""
+    from jax.experimental.pallas import tpu as pltpu
+
     if int8_scales:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, par,
+         own_ref) = rest
+        streams = ((k_hbm, kbuf), (v_hbm, vbuf), (ks_hbm, ksbuf),
+                   (vs_hbm, vsbuf))
     else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+        o_ref, kbuf, vbuf, sem, par, own_ref = rest
+        streams = ((k_hbm, kbuf), (v_hbm, vbuf))
+    g = pl.program_id(0)
+    total = pl.num_programs(0) * slots
     h = hkv * group
     t = page_tokens
+    n = t * hkv
+    d = q_ref.shape[-1]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def live(slot):
+        return _live_blocks(len_ref[slot], start_ref[slot], t, window)
 
-    length = len_ref[b]
-    base = j * t
-    relevant = base < length
-    if window:
-        # the query sits at position length-1; pages entirely behind
-        # the horizon (base + t <= length - window) contribute nothing
-        relevant = jnp.logical_and(relevant, base + t > length - window)
+    def copies(slot, block, half):
+        page = tbl_ref[slot, block]
+        return [
+            pltpu.make_async_copy(
+                hbm.at[page], buf.at[half], sem.at[i, half]
+            )
+            for i, (hbm, buf) in enumerate(streams)
+        ]
 
-    @pl.when(relevant)
-    def _compute():
-        q = q_ref[0]  # [H, D]
-        k = k_ref[0].astype(q.dtype)  # [T, Hkv, D] (int8 converts bare)
-        v = v_ref[0].astype(q.dtype)
-        d = q.shape[-1]
-        q3 = q.reshape(hkv, group, d)
-        kh = jnp.swapaxes(k, 0, 1)  # [Hkv, T, D]
-        logits = jax.lax.dot_general(
-            q3, kh, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [Hkv, G, T]
-        if int8_scales:
-            ks = jnp.swapaxes(ks_ref[0][:, :, 0], 0, 1)  # [Hkv, T]
-            logits = logits * ks[:, None, :]
-        logits = logits * scale
-        pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, t), 2
+    @pl.when(g == 0)
+    def _prologue():
+        col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
+        own_ref[...] = jnp.where(
+            col % hkv == row // group, 0.0, NEG_INF
         )
-        keep = pos < length
-        if window:
-            keep = jnp.logical_and(keep, pos >= length - window)
-        logits = jnp.where(keep, logits, NEG_INF)
-        lg = logits.reshape(h, t)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(lg, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(lg - m_new)  # [H, T]
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        p3 = p.reshape(hkv, group, t)
-        if int8_scales:
-            vs = jnp.swapaxes(vs_ref[0][:, :, 0], 0, 1)  # [Hkv, T]
-            p3 = p3 * vs[:, None, :]
-        vh = jnp.swapaxes(v, 0, 1)  # [Hkv, T, D]
-        pv = jax.lax.dot_general(
-            p3.astype(v.dtype), vh, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [Hkv, G, D]
-        acc_ref[...] = acc_ref[...] * alpha + pv.reshape(h, d)
+        par[0] = 0
+        for c in copies(0, live(0)[1], 0):
+            c.start()
 
-    @pl.when(j == num_blocks - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) // hkv
+
+    def slot_step(s, half0):
+        b = g * slots + s
+        q = q_ref[s]  # [H, D]
+        length = len_ref[b]
+        lo, first, count = live(b)
+
+        def block_step(i, carry):
+            m_prev, l_prev, acc = carry
+            half = (half0 + i) % 2
+
+            @pl.when(i + 1 < count)
+            def _next_block():
+                for c in copies(b, first + i + 1, 1 - half):
+                    c.start()
+
+            @pl.when(jnp.logical_and(i + 1 == count, b + 1 < total))
+            def _next_slot():
+                nxt = jnp.minimum(b + 1, total - 1)
+                for c in copies(nxt, live(nxt)[1], 1 - half):
+                    c.start()
+
+            for c in copies(b, first + i, half):
+                c.wait()
+            k = kbuf[half].astype(q.dtype)  # [T*Hkv, D]
+            v = vbuf[half].astype(q.dtype)
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [H, T*Hkv]
+            if int8_scales:
+                # q·(k*ks) == (q·k)*ks: one scale a column, [1, T*Hkv]
+                logits = logits * ksbuf[half][:, :n]
+            base = (first + i) * t
+            seen = jnp.logical_and(tok >= lo - base, tok < length - base)
+            # a select, not a sum, for the positions: what lies outside
+            # the span may be anything, NaN included
+            logits = jnp.where(seen, logits * scale, NEG_INF) + own_ref[...]
+            m_cur = jnp.max(logits, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            # every live block holds a visible position, so m_new is a
+            # real logit and the masked columns underflow to exactly 0
+            p = jnp.exp(logits - m_new)  # [H, T*Hkv]
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            if int8_scales:
+                p = p * vsbuf[half][:, :n]  # Σ p·(v*vs) == Σ (p*vs)·v
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [H, D]
+            return m_new, l_new, acc * alpha + pv
+
+        _, l_fin, acc = jax.lax.fori_loop(
+            0, count, block_step,
+            (jnp.full((h, 1), NEG_INF, jnp.float32),
+             jnp.zeros((h, 1), jnp.float32),
+             jnp.zeros((h, d), jnp.float32)),
+        )
+        o_ref[s] = (acc / l_fin).astype(o_ref.dtype)
+        return (half0 + count) % 2
+
+    par[0] = jax.lax.fori_loop(0, slots, slot_step, par[0])
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                    scale=None, window=0, k_scale_pool=None,
+                    starts=None, scale=None, window=0, k_scale_pool=None,
                     v_scale_pool=None, interpret=None):
     """Single-token decode attention over a paged KV pool.
 
@@ -229,12 +310,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         divides ``H`` (GQA).  int8 pools compose with the scale pools.
       block_tables: ``[B, NB]`` int32 page indices — slot ``b``'s
         logical block ``j`` lives in physical page
-        ``block_tables[b, j]``.  Entries past the live length must
-        still be VALID indices (the engine points idle/unused entries
-        at the reserved trash page); they are masked, not skipped.
-      lengths: ``[B]`` int32 — tokens visible to slot ``b``'s query
-        (``>= 1``; the query attends positions ``[0, lengths[b])``,
-        its own slot included).
+        ``block_tables[b, j]``.  Entries outside the live span are
+        never dereferenced (neither fetched nor computed).
+      lengths: ``[B]`` int32 — slot ``b``'s query sits at position
+        ``lengths[b] - 1`` (``>= 1``) and attends
+        ``[starts[b], lengths[b])``, its own position included.
+      starts: ``[B]`` int32 first visible position of each slot
+        (``< lengths[b]``; default 0) — the pad region of a
+        left-padded admit lies below it.  Blocks wholly below it are
+        skipped like blocks past the length.
       scale: logit scale (default ``D ** -0.5``).
       window: sliding-window width (0 = full causal) — pages fully
         behind the horizon are skipped, partial pages masked.
@@ -244,8 +328,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
       interpret: force/deny interpret mode (default: off-TPU).
     Returns ``[B, H, D]`` in ``q.dtype``.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     if interpret is None:
         interpret = compat.pallas_interpret()
     b, h, d = q.shape
@@ -265,34 +347,67 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         raise ValueError("k_scale_pool needs v_scale_pool (and vice versa)")
     group = h // hkv
     scale = scale if scale is not None else d ** -0.5
-
-    kernel = functools.partial(
-        _paged_kernel,
-        num_blocks=nb, page_tokens=t, hkv=hkv, group=group,
-        scale=scale, window=int(window), int8_scales=int8_scales,
+    if starts is None:
+        starts = jnp.zeros((b,), jnp.int32)
+    assert starts.shape == (b,), starts.shape
+    return _decode_call(
+        q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
+        jnp.asarray(lengths, jnp.int32), jnp.asarray(starts, jnp.int32),
+        k_scale_pool, v_scale_pool, scale=float(scale),
+        window=int(window), interpret=bool(interpret),
     )
-    page_map = lambda bi, j, tbl, ln: (tbl[bi, j], 0, 0, 0)  # noqa: E731
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda bi, j, tbl, ln: (bi, 0, 0)),
-        pl.BlockSpec((1, t, hkv, d), page_map),
-        pl.BlockSpec((1, t, hkv, d), page_map),
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
+def _decode_call(q, k_pool, v_pool, block_tables, lengths, starts,
+                 k_scale_pool, v_scale_pool, *, scale, window, interpret):
+    """The kernel's ``pallas_call``, under a jit of its own: a model
+    calls it once a layer with the same shapes, and is then traced and
+    lowered for Mosaic once, not once a layer (1.6 s at 16 layers)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, d = q.shape
+    p, t, hkv, _ = k_pool.shape
+    group = h // hkv
+    int8_scales = k_scale_pool is not None
+    # slots a grid step: enough that the step's fixed cost is shared
+    slots = max(g for g in (8, 4, 2, 1) if b % g == 0)
+    kernel = functools.partial(
+        _decode_kernel,
+        slots=slots, page_tokens=t, hkv=hkv, group=group,
+        scale=scale, window=window, int8_scales=int8_scales,
+    )
+    slot_map = lambda gi, tbl, ln, st: (gi, 0, 0)  # noqa: E731
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # rows = (token, kv head): merges major dims only, a free view
+    operands = [
+        q, k_pool.reshape(p, t * hkv, d), v_pool.reshape(p, t * hkv, d),
     ]
-    operands = [q, k_pool, v_pool]
+    scratch = [
+        _scratch((2, t * hkv, d), k_pool.dtype),
+        _scratch((2, t * hkv, d), v_pool.dtype),
+    ]
     if int8_scales:
-        in_specs += [
-            pl.BlockSpec((1, t, hkv, 1), page_map),
-            pl.BlockSpec((1, t, hkv, 1), page_map),
+        # one scale per row of a block, as a lane vector the logits
+        # broadcast against (this view copies the small scale pools),
+        # padded to whole lanes: a copy's slice may not split one
+        lanes = -(-t * hkv // LANE) * LANE
+        operands += [
+            jnp.pad(sp.reshape(p, 1, t * hkv),
+                    ((0, 0), (0, 0), (0, lanes - t * hkv)))
+            for sp in (k_scale_pool, v_scale_pool)
         ]
-        operands += [k_scale_pool, v_scale_pool]
+        scratch += [_scratch((2, 1, lanes), jnp.float32)] * 2
     grid_spec = _grid_spec(
-        2,
-        (b, nb),
-        in_specs,
-        pl.BlockSpec((1, h, d), lambda bi, j, tbl, ln: (bi, 0, 0)),
-        scratch_shapes=[
-            _scratch((h, d), jnp.float32),
-            _scratch((h, 1), jnp.float32),
-            _scratch((h, 1), jnp.float32),
+        3,
+        (b // slots,),
+        [pl.BlockSpec((slots, h, d), slot_map)]
+        + [hbm] * (len(operands) - 1),
+        pl.BlockSpec((slots, h, d), slot_map),
+        scratch_shapes=scratch + [
+            pltpu.SemaphoreType.DMA((len(operands) - 1, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            _scratch((h, t * hkv), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -300,11 +415,74 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            # sequential: a slot starts the next slot's first copy
+            dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(lengths, jnp.int32), *operands)
+        name="block_decode_attention",
+    )(block_tables, lengths, starts, *operands)
+
+
+#: bank block sizes, in order of preference.  A K+V block of 256
+#: tokens is 1 MB at 8 kv heads of 128 (double-buffered: 2 MB of VMEM);
+#: in the serving cell 256 read 47% of the banks and gave the shortest
+#: decode step, 128 read 42% but paid for twice the copies, 512 read
+#: 55% (PERF.md section 6, PR 27)
+BANK_BLOCKS = (256, 128)
+
+
+def bank_block(bank_len, head_dim, dtype):
+    """Tokens a copy of :func:`bank_attention` reads from a
+    contiguous bank: the first of :data:`BANK_BLOCKS` that divides
+    ``bank_len`` and is tile-legal (:func:`check_tiles`), or None —
+    the caller keeps its einsum path then."""
+    for t in BANK_BLOCKS:
+        if bank_len % t == 0:
+            try:
+                check_tiles(t, head_dim, dtype)
+            except TileLegalityError:
+                break
+            return t
+    return None
+
+
+def bank_attention(q, k_bank, v_bank, positions, pad_start, *, scale=None,
+                   window=0, k_scale=None, v_scale=None, interpret=None):
+    """Single-token decode attention over contiguous per-slot banks
+    ``[B, S, Hkv, D]``, reading only each slot's live span.
+
+    A bank is a page pool by a free reshape — block ``j`` of slot
+    ``b`` is page ``b * (S // T) + j`` — so this is
+    :func:`paged_attention` over the identity table with
+    ``lengths = positions + 1`` and ``starts = pad_start``: blocks
+    outside ``[pad_start, position]`` (and behind the window) are
+    neither fetched nor computed.  ``positions [B]`` is where each
+    slot's query sits (its K/V already written there); a slot always
+    sees its own position, so an idle lane (``pad_start`` past its
+    position) reads one block and attends itself alone.  Requires
+    :func:`bank_block` to find a block size for the bank.
+    """
+    b, s, hkv, d = k_bank.shape
+    t = bank_block(s, d, k_bank.dtype)
+    if t is None:
+        raise TileLegalityError(
+            "no block of {0} divides a bank of {1} tokens legally for "
+            "{2}".format(BANK_BLOCKS, s, jnp.dtype(k_bank.dtype).name)
+        )
+    nb = s // t
+
+    def pool(bank):
+        return None if bank is None else bank.reshape(
+            (b * nb, t) + bank.shape[2:]
+        )
+
+    return paged_attention(
+        q, pool(k_bank), pool(v_bank),
+        jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb),
+        positions + 1, starts=jnp.minimum(pad_start, positions),
+        scale=scale, window=window, k_scale_pool=pool(k_scale),
+        v_scale_pool=pool(v_scale), interpret=interpret,
+    )
 
 
 def gather_pool(pool, block_tables, span=None):

@@ -1289,6 +1289,7 @@ class ServingEngine(object):
             committed = req["out"] or []
             req["out"] = list(committed) + [first]
             req["admit_len"] = int(len(prompt))
+            req["admit_out"] = len(committed)
             self.stats["admitted"] += 1
             self._m["admitted"].inc()
             self._m_prompt_tokens.observe(float(len(prompt)))
@@ -1468,6 +1469,24 @@ class ServingEngine(object):
         )
         return toks
 
+    def _kv_read_attrs(self):
+        """What the chunk about to be dispatched reads of the KV
+        banks, for its ``engine.chunk`` span and ``stats``: ``attn``
+        (what the decoder's chunk program attends with),
+        ``kv_read_tokens`` (positions per layer its first step reads,
+        from this scheduler's own record of every request in flight)
+        and ``kv_bank_tokens`` (what the banks hold).  Nothing for a
+        decoder that does not say (tests' fakes)."""
+        reads = getattr(self.decoder, "kv_read_tokens", None)
+        if reads is None:
+            return {}
+        read, bank = reads([
+            (req["admit_len"], len(req["out"]) - req["admit_out"])
+            for req in self._slot_req.values()
+        ])
+        return {"attn": self.decoder.attn_impl,
+                "kv_read_tokens": read, "kv_bank_tokens": bank}
+
     def _run_chunk(self):
         """One decode chunk under the watchdog; returns a
         ``(tokens [B, T], valid [B])`` pair — row ``r``'s tokens are
@@ -1478,10 +1497,12 @@ class ServingEngine(object):
         decoders normalize to fully-valid rows."""
         idx = self._chunk_index
         self._chunk_index += 1
+        kv = self._kv_read_attrs()
+        self.stats.update(kv)
         t_chunk0 = time.perf_counter()
         with self._tracer.span(
             "engine.chunk", trace="engine", chunk=idx,
-            live=len(self._slot_req), slots=self.num_slots,
+            live=len(self._slot_req), slots=self.num_slots, **kv
         ) as chunk_span:
             toks = self._step_chunk(idx)
             if toks is None:

@@ -102,3 +102,46 @@ def test_gmm_float32_backward_compiles_for_v5e(mosaic):
             arg((t * bm, d), dtype), arg((e, d, f), dtype),
             arg((t,), jnp.int32), arg((t * bm, f), dtype),
         ).compile()
+
+
+@pytest.mark.parametrize("cache", ["bfloat16", "int8"])
+def test_bank_decode_kernel_compiles_for_v5e_without_copying_a_bank(
+        mosaic, cache):
+    """The serving cell's geometry (64 slots, banks of 1536, 8 kv heads
+    of 128, 4 query heads each): Mosaic must take the kernel, and the
+    view of the banks the kernel reads through must stay a bitcast —
+    a relayout would copy 200 MB a bank, a layer, a step."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.ops import paged_attention as pa
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
+        pytest.skip("no TPU AOT topology here: %s" % e)
+    dev = SingleDeviceSharding(topo.devices[0])
+    b, s, h, hkv, d = 64, 1536, 32, 8, 128
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    bank = arg((b, s, hkv, d), jnp.dtype(cache))
+    args = [arg((b, h, d), jnp.bfloat16), bank, bank,
+            arg((b,), jnp.int32), arg((b,), jnp.int32)]
+    if cache == "int8":
+        args += [arg((b, s, hkv, 1), jnp.float32)] * 2
+
+    def attend(q, k, v, positions, pad_start, ks=None, vs=None):
+        return pa.bank_attention(
+            q, k, v, positions, pad_start, window=4096,
+            k_scale=ks, v_scale=vs,
+        )
+
+    compiled = jax.jit(attend).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # temporaries: nothing the size of a bank (the int8 scales' lane
+    # view is a copy of 2 x 3 MB)
+    bank_bytes = b * s * hkv * d * jnp.dtype(cache).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < bank_bytes // 8

@@ -478,3 +478,71 @@ class TestGuards:
         with pytest.raises(ValueError, match="page pool"):
             tr.SlotDecoder(model, params, 2, 4, prefix_cache=pc,
                            kv_layout="paged")
+
+
+#: a tile-legal small shape (head_dim 128, banks of three 128-token
+#: blocks): contiguous banks whose single-token decode steps the
+#: block-walking kernel can take
+TILE_LEGAL = {
+    "vocab_size": 64, "num_layers": 2, "num_heads": 4,
+    "num_kv_heads": 2, "head_dim": 128, "embed_dim": 32, "mlp_dim": 64,
+    "max_seq_len": 384, "dtype": "float32", "attention_window": 200,
+}
+
+
+class TestBankKernelEngages:
+    """Contiguous banks read through the decode kernel where the code
+    can see it is legal, and through ``dot_attention`` elsewhere —
+    chosen from shapes, no knob — with the same tokens either way."""
+
+    @pytest.mark.parametrize("how, attn", [
+        ("plain", "kernel"), ("mesh", "dot"), ("two_token_span", "dot"),
+    ])
+    def test_engine_reports_what_it_attends_with(self, how, attn):
+        model = tr.Transformer(tr.TransformerConfig(**TILE_LEGAL))
+        params = model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
+        extra = {}
+        if how == "mesh":
+            extra["tp"] = 2
+        if how == "two_token_span":
+            # a draft of one token: the verify block is a 2-token span
+            extra.update(
+                draft_config=dict(TILE_LEGAL, num_layers=1), draft_len=1,
+                draft_params={
+                    "embedding": params["embedding"],
+                    "block_0": params["block_0"],
+                    "ln_f": params["ln_f"], "lm_head": params["lm_head"],
+                },
+            )
+        predict = tr.serving_builder(params, dict(
+            TILE_LEGAL, mode="generate", max_new_tokens=5,
+            pad_multiple=16, **extra
+        ))
+        rng = np.random.RandomState(0)
+        rows = [
+            {"prompt": rng.randint(0, 64, (n,)).astype(np.int32)}
+            for n in (3, 17, 40, 9, 150)
+        ]
+        got, stats = _run(predict, rows)
+        assert stats["attn"] == attn
+        slots, bank = 3, stats["kv_bank_tokens"]
+        if how == "two_token_span":
+            assert bank == slots * (384 + 2)  # the verify block's slack
+        else:
+            assert bank == slots * 384
+        if attn == "kernel":
+            # whole 128-token blocks of the live spans, one block for
+            # a lane nobody holds: never the banks whole here
+            assert stats["kv_read_tokens"] % 128 == 0
+            assert 0 < stats["kv_read_tokens"] < bank
+        else:
+            assert stats["kv_read_tokens"] == bank
+        for row, out in zip(rows, got):
+            ref = tr.generate(
+                model, params, jnp.asarray(row["prompt"])[None], 5
+            )
+            np.testing.assert_array_equal(
+                np.asarray(out["generated"]), np.asarray(ref)[0],
+            )
