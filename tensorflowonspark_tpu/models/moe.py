@@ -40,7 +40,10 @@ def check_drop_rate(drop_rate, capacity_factor=None, where="MoE"):
     vs CF=1.25 convergence smoke in tests/test_moe.py quantifies
     what the drops cost.  Raise
     ``capacity_factor`` (1.25 keeps drops rare on balanced routers) or
-    switch ``dispatch="dropless"`` to eliminate them.
+    switch ``dispatch="dropless"`` to eliminate them.  Only
+    :class:`MoEMLP`'s ``gather``/``einsum`` dispatches have a capacity;
+    :class:`SigmoidMoE` (``router_scoring="sigmoid"``, the serving
+    path's expert layer) routes without one and never drops.
     """
     rate = float(drop_rate)
     if rate <= DROP_RATE_WARN:
@@ -48,7 +51,8 @@ def check_drop_rate(drop_rate, capacity_factor=None, where="MoE"):
     msg = (
         "%s drop_rate %.1f%% exceeds %.0f%% (capacity_factor=%s): "
         "throughput at this setting silently drops token updates — "
-        "raise capacity_factor (e.g. 1.25) or use dispatch='dropless'; "
+        "raise capacity_factor (e.g. 1.25) or use dispatch='dropless' "
+        "(router_scoring='sigmoid' has no capacity at all); "
         "see the CF convergence smoke in tests/test_moe.py"
         % (
             where, 100.0 * rate, 100.0 * DROP_RATE_WARN,
@@ -201,6 +205,115 @@ class MoEMLP(nn.Module):
         else:
             # combine: weighted return to token order [G,D]
             y = jnp.einsum("gec,ecd->gd", combine.astype(jdtype), ye)
+        return y.reshape(b, s, d).astype(x.dtype)
+
+
+#: tokens :class:`SigmoidMoE` routes at a time (a 16k-token prompt's
+#: sorted copy of every token would be gigabytes)
+ROUTED_CHUNK = 2048
+
+
+class SigmoidMoE(nn.Module):
+    """Sigmoid-routed gated-SiLU experts with a shared expert, as ONE
+    chip's share of an expert-parallel layer.
+
+    The router scores all ``router_experts`` experts of the layer
+    (``s = sigmoid(x W_r)``), chooses the ``k`` with the largest ``s +
+    b`` (``b`` = ``router_bias``: it corrects the choice, never the
+    weight) and weighs each by ``s_e / sum of the chosen s``, times
+    ``scaling`` (:func:`..ops.moe.sigmoid_topk`).  This program holds
+    experts ``expert_first .. expert_first + num_experts - 1`` and
+    computes ``shared(x) + sum over chosen AND held e of w_e ·
+    expert_e(x)``: its own part of the result.  What the experts held
+    elsewhere would add is theirs to add (over the all-to-all that one
+    chip does not have); nothing here stands in for them.
+
+    Never a drop, at any number of tokens: the routed rows are sorted
+    by expert into row tiles (:func:`..ops.moe.share_layout`) and
+    multiplied by the grouped-matmul kernel, which skips the tiles no
+    row landed in.  Tokens go through :data:`ROUTED_CHUNK` at a time so
+    a long prompt's sorted copy stays small.
+
+    Sows ``moe_stats/held_choices``: ``[tokens, num_experts]`` int8,
+    1 where the token chose that held expert (the serving engine
+    counts assignments and experts hit from it).
+    """
+
+    router_experts: int
+    num_experts: int
+    mlp_dim: int
+    embed_dim: int
+    expert_first: int = 0
+    k: int = 8
+    scaling: float = 1.0
+    shared_experts: int = 1
+    dtype: str = "bfloat16"
+
+    @nn.compact
+    def __call__(self, x):
+        from tensorflowonspark_tpu.ops import gmm
+
+        e, held, first = self.router_experts, self.num_experts, (
+            self.expert_first)
+        if not 0 <= first <= e - held:
+            raise ValueError(
+                "held experts %d..%d are not among the router's %d" % (
+                    first, first + held - 1, e))
+        m, d = self.mlp_dim, self.embed_dim
+        jdtype = jnp.dtype(self.dtype)
+        b, s, _ = x.shape
+        g = b * s
+        xf = x.reshape(g, d)
+        router = self.param(
+            "router", nn.initializers.normal(stddev=0.02), (d, e))
+        bias = self.param("router_bias", nn.initializers.zeros, (e,))
+        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+        wi = self.param("wi", init, (held, d, m)).astype(jdtype)
+        wg = self.param("wg", init, (held, d, m)).astype(jdtype)
+        wo = self.param("wo", init, (held, m, d)).astype(jdtype)
+
+        def routed(xc):
+            # a decode step's few rows pad to the smallest row tile,
+            # a prompt's thousands to the MXU's
+            bm = 256 if xc.shape[0] >= 512 else 16
+            scores = jax.nn.sigmoid(jnp.dot(
+                xc, router.astype(xc.dtype),
+                preferred_element_type=jnp.float32))
+            experts, gates = moe_ops.sigmoid_topk(
+                scores, bias.astype(jnp.float32), self.k, self.scaling)
+            lay = moe_ops.share_layout(experts, first, held, bm=bm)
+            xs = moe_ops.dispatch_sorted(xc.astype(jdtype), lay)
+
+            def mm(a, w):
+                return gmm.gmm_call(
+                    a, w, lay.tile_expert, bm=bm,
+                    live_tiles=lay.live_tiles)
+
+            ys = mm(nn.silu(mm(xs, wg)) * mm(xs, wi), wo)
+            y = moe_ops.combine_share(ys, lay, gates, out_dtype=x.dtype)
+            chose = jnp.any(
+                jnp.logical_and(
+                    lay.local[..., None],
+                    (experts - first)[..., None] == jnp.arange(held)),
+                axis=1,
+            )
+            return y, chose.astype(jnp.int8)
+
+        with jax.named_scope("moe"):
+            c = ROUTED_CHUNK
+            if g > c and g % c == 0:
+                y, chose = jax.lax.map(routed, xf.reshape(g // c, c, d))
+                y, chose = y.reshape(g, d), chose.reshape(g, held)
+            else:
+                y, chose = routed(xf)
+            self.sow("moe_stats", "held_choices", chose)
+            if self.shared_experts:
+                dense = lambda name, feats: nn.Dense(  # noqa: E731
+                    feats, use_bias=False, dtype=jdtype, name=name)
+                width = m * self.shared_experts
+                gate = nn.silu(dense("shared_wg", width)(xf))
+                y = y + dense("shared_wo", d)(
+                    gate * dense("shared_wi", width)(xf))
         return y.reshape(b, s, d).astype(x.dtype)
 
 

@@ -109,14 +109,79 @@ class TransformerConfig:
     capacity_factor: float = 1.25
     #: "gather" (index dispatch, no permutation matmuls) | "einsum"
     expert_dispatch: str = "gather"
+    #: RMSNorm epsilon of every norm in the model
+    rms_norm_eps: float = 1e-6
+    #: RoPE base, and whether a rotated pair is (2i, 2i+1)
+    #: (interleaved) or (i, i + D/2) (split halves)
+    rope_theta: float = 10000.0
+    rope_interleave: bool = False
+    # -- per-layer pattern --------------------------------------------
+    #: FFN of each layer, "dense" | "sparse" (empty: every layer dense,
+    #: or every layer the softmax MoE above when num_experts > 0)
+    mlp_layer_types: tuple = ()
+    #: sparse index of each latent-attention layer: "full" (owns an
+    #: indexer and selects), "shared" (attends over the selection of
+    #: the nearest "full" layer before it) or "" (dense); empty: all ""
+    indexer_types: tuple = ()
+    # -- latent attention (models/mla.py) ------------------------------
+    #: "gqa" (Attention above) | "mla" (low-rank queries, a compressed
+    #: key/value latent and one shared rotary key a token; the decode
+    #: cache holds the latent row, not per-head keys and values)
+    attention_kind: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    #: keys a query attends to on indexed layers (all while fewer)
+    index_topk: int = 0
+    # -- sigmoid-routed experts (models/moe.py SigmoidMoE) -------------
+    #: "softmax" (MoEMLP) | "sigmoid" (scores sigmoid(x W_r); top-k of
+    #: score + a selection-only bias; weights normalised over the
+    #: chosen, times routed_scaling; a shared expert; never a drop)
+    router_scoring: str = "softmax"
+    #: experts the router scores (0 = num_experts).  num_experts is how
+    #: many THIS program holds, ids expert_first .. expert_first +
+    #: num_experts - 1: it routes over all and computes its own part
+    router_experts: int = 0
+    expert_first: int = 0
+    shared_experts: int = 0
+    routed_scaling: float = 1.0
+    #: width of one routed (and one shared) expert (0 = mlp_dim)
+    moe_mlp_dim: int = 0
+
+    def __post_init__(self):
+        # a config read from JSON brings lists; flax hashes the config
+        for name in ("mlp_layer_types", "indexer_types"):
+            val = tuple(getattr(self, name) or ())
+            object.__setattr__(self, name, val)
+            if val and len(val) != self.num_layers:
+                raise ValueError(
+                    "{0} names {1} layers, num_layers is {2}".format(
+                        name, len(val), self.num_layers))
 
     @property
     def jdtype(self):
         return jnp.dtype(self.dtype)
 
+    def ffn_kind(self, layer):
+        """"dense", "moe" (softmax, MoEMLP) or "sigmoid_moe" of layer
+        ``layer``."""
+        sparse = (
+            self.mlp_layer_types[layer] == "sparse"
+            if self.mlp_layer_types else self.num_experts > 0
+        )
+        if not sparse:
+            return "dense"
+        return "sigmoid_moe" if self.router_scoring == "sigmoid" else "moe"
 
-def rope(x, positions, max_wavelength=10000.0):
-    """Rotary position embedding on ``[B, S, H, D]`` (D even)."""
+
+def rope(x, positions, max_wavelength=10000.0, interleave=False):
+    """Rotary position embedding on ``[B, S, H, D]`` (D even): pair
+    ``i`` is ``(i, i + D/2)``, or ``(2i, 2i + 1)`` with
+    ``interleave``."""
     d = x.shape[-1]
     freq = max_wavelength ** (
         -jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2)
@@ -124,7 +189,14 @@ def rope(x, positions, max_wavelength=10000.0):
     angles = positions[..., None].astype(jnp.float32) * freq  # [B,S,D/2]
     angles = angles[:, :, None, :]  # [B,S,1,D/2]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    if interleave:
+        x1, x2 = x32[..., 0::2], x32[..., 1::2]
+        out = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).reshape(x.shape)
+        return out.astype(x.dtype)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
@@ -151,7 +223,10 @@ def decode_bank_block(cfg, bank_len):
     partition a Pallas call), and the geometry must be tile-legal —
     ``head_dim`` a multiple of 128 and a block size that divides the
     bank — which the tiny head sizes of CPU tests are not."""
-    if cfg.mesh is not None or cfg.kv_layout == "paged":
+    if (cfg.mesh is not None or cfg.kv_layout == "paged"
+            or cfg.attention_kind == "mla"):
+        # a latent row (one 576-wide row a token, every head's key) is
+        # not the kernel's [Hkv, D] block: models/mla.py reads it
         return None
     from tensorflowonspark_tpu.ops.paged_attention import bank_block
 
@@ -195,8 +270,8 @@ class Attention(nn.Module):
             q = dense("q", (h, d))(x)
             k = dense("k", (hkv, d))(x)
             v = dense("v", (hkv, d))(x)
-        q = rope(q, positions)
-        k = rope(k, positions)
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_interleave)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_interleave)
         if decode and cfg.kv_layout == "paged":
             return self._paged_decode(
                 x, q, k, v, positions, block_tables, hkv, d
@@ -496,18 +571,55 @@ class MLP(nn.Module):
 
 class Block(nn.Module):
     cfg: TransformerConfig
+    #: index of this layer in the model's per-layer pattern
+    #: (``mlp_layer_types``, ``indexer_types``)
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, positions, decode=False, pad_start=None,
-                 per_slot=False, block_tables=None):
+                 per_slot=False, block_tables=None, sel=None):
+        """One pre-norm layer.  Under ``attention_kind="mla"`` the
+        layer takes and returns the sparse selection ``sel`` beside
+        ``x`` (a "shared" layer attends over the set the last "full"
+        layer chose): ``(x, sel)``; otherwise ``x`` alone."""
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(
-            RMSNorm(name="ln1")(x), positions, decode=decode,
-            pad_start=pad_start, per_slot=per_slot,
-            block_tables=block_tables,
-        )
-        h = RMSNorm(name="ln2")(x)
-        if cfg.num_experts > 0:
+        norm = lambda name: RMSNorm(  # noqa: E731
+            eps=cfg.rms_norm_eps, name=name)
+        if cfg.attention_kind == "mla":
+            from tensorflowonspark_tpu.models.mla import MLAttention
+
+            att, sel = MLAttention(
+                cfg,
+                indexer=(cfg.indexer_types[self.layer]
+                         if cfg.indexer_types else ""),
+                name="attn",
+            )(norm("ln1")(x), positions, decode=decode,
+              pad_start=pad_start, per_slot=per_slot, sel=sel)
+            x = x + att
+        else:
+            x = x + Attention(cfg, name="attn")(
+                norm("ln1")(x), positions, decode=decode,
+                pad_start=pad_start, per_slot=per_slot,
+                block_tables=block_tables,
+            )
+        h = norm("ln2")(x)
+        kind = cfg.ffn_kind(self.layer)
+        if kind == "sigmoid_moe":
+            from tensorflowonspark_tpu.models.moe import SigmoidMoE
+
+            ff = SigmoidMoE(
+                router_experts=cfg.router_experts or cfg.num_experts,
+                expert_first=cfg.expert_first,
+                num_experts=cfg.num_experts,
+                mlp_dim=cfg.moe_mlp_dim or cfg.mlp_dim,
+                embed_dim=cfg.embed_dim,
+                k=cfg.expert_k,
+                scaling=cfg.routed_scaling,
+                shared_experts=cfg.shared_experts,
+                dtype=cfg.dtype,
+                name="moe",
+            )(h)
+        elif kind == "moe":
             from tensorflowonspark_tpu.models.moe import MoEMLP
 
             axes = set(getattr(cfg.mesh, "axis_names", ()) or ())
@@ -535,7 +647,8 @@ class Block(nn.Module):
             )(h)
         else:
             ff = MLP(cfg, name="mlp")(h)
-        return x + ff
+        x = x + ff
+        return (x, sel) if cfg.attention_kind == "mla" else x
 
 
 class Transformer(nn.Module):
@@ -545,7 +658,11 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, decode=False, pad_start=None,
-                 slot_positions=None, block_tables=None):
+                 slot_positions=None, block_tables=None,
+                 last_only=False):
+        """``last_only``: logits of the last position alone,
+        ``[B, 1, vocab]`` (a prefill samples from nothing else; the
+        head over a 16k-token prompt is a gigabyte of logits)."""
         cfg = self.cfg
         if pad_start is not None and not decode:
             raise ValueError(
@@ -593,6 +710,7 @@ class Transformer(nn.Module):
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1]), tokens.shape
             )
+        mla = cfg.attention_kind == "mla"
         if cfg.remat and cfg.remat_policy not in ("block", "dots"):
             raise ValueError(
                 "remat_policy must be 'block' or 'dots', got %r"
@@ -608,16 +726,23 @@ class Transformer(nn.Module):
                 else None
             )
             block = nn.remat(Block, static_argnums=(), policy=policy)
+            sel = None
             for i in range(cfg.num_layers):
-                x = block(cfg, name="block_%d" % i)(x, positions)
+                out = block(cfg, layer=i, name="block_%d" % i)(
+                    x, positions, sel=sel)
+                x, sel = out if mla else (out, None)
         else:
+            sel = None
             for i in range(cfg.num_layers):
-                x = Block(cfg, name="block_%d" % i)(
+                out = Block(cfg, layer=i, name="block_%d" % i)(
                     x, positions, decode, pad_start=pad_start,
                     per_slot=slot_positions is not None,
-                    block_tables=block_tables,
+                    block_tables=block_tables, sel=sel,
                 )
-        x = RMSNorm(name="ln_f")(x)
+                x, sel = out if mla else (out, None)
+        if last_only:
+            x = x[:, -1:]
+        x = RMSNorm(eps=cfg.rms_norm_eps, name="ln_f")(x)
         # tied output head would shard awkwardly under TP; a separate
         # vocab projection keeps the ``vocab`` logical axis clean
         logits = nn.Dense(
@@ -691,9 +816,10 @@ def init_cache(model, batch_size, cache_len=None):
         )
 
     def _zero(x):
-        if x.ndim == 4:  # [B, max_seq, H, D] key/value banks
-            b, _, h, d = x.shape
-            return jnp.zeros((b, length, h, d), x.dtype)
+        # [B, max_seq, ...] banks: keys and values [.., H, D], latent
+        # rows and index keys [.., width]
+        if x.ndim >= 3:
+            return jnp.zeros((x.shape[0], length) + x.shape[2:], x.dtype)
         return jnp.zeros(x.shape, x.dtype)
 
     return jax.tree.map(_zero, shapes["cache"])
@@ -899,6 +1025,11 @@ def generate_speculative(model, params, prompt, max_new_tokens,
     b, p = prompt.shape
     k = int(draft_len)
     total = p + max_new_tokens
+    if model.cfg.attention_kind == "mla":
+        raise ValueError(
+            "speculative decoding verifies a multi-token block against "
+            "the cache; latent banks (attention_kind='mla') take a "
+            "span only as a prefill from the start")
     if k < 1:
         raise ValueError("draft_len must be >= 1")
     if ngram < 1:
@@ -1218,6 +1349,26 @@ class SlotDecoder:
                 "was asked for 'contiguous'; pass kv_layout='paged'"
             )
         self._paged = self.kv_layout == "paged"
+        #: latent attention: the banks are latent rows and index keys
+        #: (models/mla.py).  Built for the contiguous layout and whole
+        #: prefills; what is left says so here, by name
+        self._latent = model.cfg.attention_kind == "mla"
+        if self._latent and (
+                self._paged or prefix_cache is not None
+                or draft_model is not None or mesh is not None):
+            raise ValueError(
+                "attention_kind='mla' serves from contiguous latent "
+                "banks on one device: paged latent pages, prefix reuse "
+                "(a suffix prefill over cached latent rows), "
+                "draft-model speculation and a TP mesh are not built")
+        #: sparse layers whose routing the chunk program counts
+        self._moe_layers = sum(
+            model.cfg.ffn_kind(i) == "sigmoid_moe"
+            for i in range(model.cfg.num_layers))
+        #: MoE counts of the last resolved chunk (None without such
+        #: layers): assignments, those to held experts, and held
+        #: experts hit, summed over the chunk's steps and layers
+        self.last_chunk_counts = None
         self.model = model
         self.num_slots = int(num_slots)
         self.max_new_tokens = int(max_new_tokens)
@@ -1272,6 +1423,10 @@ class SlotDecoder:
         self._bank_len = self.cache_len + (
             self.draft_len + 1 if self._spec else 0
         )
+        if self._latent:
+            # whole blocks for the latent decode kernel; the admission
+            # bound stays cache_len
+            self._bank_len = -(-self._bank_len // 128) * 128
         if self._paged:
             if paged_impl not in ("kernel", "gather"):
                 raise ValueError(
@@ -1300,11 +1455,18 @@ class SlotDecoder:
 
                 self.model = Transformer(_dc.replace(model.cfg, mesh=mesh))
         #: what the decode chunk's flagship attention was built with:
-        #: "kernel" (block-walking, reads live blocks only) or "dot"
+        #: "kernel" (block-walking, reads live blocks only), "dot"
         #: (masked einsums over the whole span; a speculative chunk's
-        #: verify block is a multi-token span, so always this)
+        #: verify block is a multi-token span, so always this) or
+        #: "latent" (absorbed MLA under the index's selection, through
+        #: the latent decode kernel over each slot's live blocks where
+        #: ``_kv_block`` is set, else over the whole bank)
         if self._spec:
             self._kv_block = None
+        elif self._latent:
+            from tensorflowonspark_tpu.models.mla import decode_block
+
+            self._kv_block = decode_block(self.model.cfg, self._bank_len)
         elif self._paged:
             self._kv_block = (
                 self._page_tokens if self.paged_impl == "kernel" else None
@@ -1313,7 +1475,9 @@ class SlotDecoder:
             self._kv_block = decode_bank_block(
                 self.model.cfg, self._bank_len
             )
-        self.attn_impl = "kernel" if self._kv_block else "dot"
+        self.attn_impl = (
+            "latent" if self._latent
+            else "kernel" if self._kv_block else "dot")
         self._np = np
         self._qz = qz
         self._rng = jax.random.PRNGKey(int(seed))
@@ -1545,10 +1709,12 @@ class SlotDecoder:
 
     @staticmethod
     def _lane_of(cache, slot):
-        """Slice lane ``slot`` out of every 4-dim cache bank (the
-        shared position counter resets to 0 — slot mode ignores it)."""
+        """Slice lane ``slot`` out of every cache bank — ``[B, L, H,
+        Dx]`` keys and values, ``[B, L, width]`` latent rows and index
+        keys — (the shared position counter resets to 0: slot mode
+        ignores it)."""
         def _lane(leaf):
-            if getattr(leaf, "ndim", 0) == 4:  # [B, L, H, Dx] banks
+            if getattr(leaf, "ndim", 0) >= 3:  # [B, L, ...] banks
                 return jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=0)
             return jnp.zeros((), jnp.int32)
 
@@ -1557,7 +1723,7 @@ class SlotDecoder:
     @staticmethod
     def _merge_lane(cache, lane, slot):
         def _merge(full, lane_leaf):
-            if getattr(full, "ndim", 0) == 4:
+            if getattr(full, "ndim", 0) >= 3:
                 return jax.lax.dynamic_update_slice_in_dim(
                     full, lane_leaf.astype(full.dtype), slot, axis=0
                 )
@@ -1622,7 +1788,7 @@ class SlotDecoder:
         lane = self._lane_of(cache, slot)
         logits, mut = self.model.apply(
             {"params": params, "cache": lane}, tokens, decode=True,
-            mutable=["cache"], pad_start=pad,
+            mutable=["cache"], pad_start=pad, last_only=True,
         )
         cache = self._merge_lane(cache, mut["cache"], slot)
         if self._spec:
@@ -1630,6 +1796,7 @@ class SlotDecoder:
             _, dmut = self.draft_model.apply(
                 {"params": dparams, "cache": dlane}, tokens,
                 decode=True, mutable=["cache"], pad_start=pad,
+                last_only=True,
             )
             dcache = self._merge_lane(dcache, dmut["cache"], slot)
         first = self._sample(logits[:, -1], key)[0]
@@ -1761,7 +1928,7 @@ class SlotDecoder:
         }
 
     def _install_segment_impl(self, cache, slot, segment):
-        """Write a cached-prefix segment (per-bank ``[L_seg, H, Dx]``
+        """Write a cached-prefix segment (per-bank ``[L_seg, ...]``
         leaves, flattened bank order) into lane ``slot`` at positions
         ``[0, L_seg)`` — prefix blocks always sit at canonical
         offset 0.  One dispatch per admit hit."""
@@ -1769,11 +1936,11 @@ class SlotDecoder:
         it = iter(segment)
         out = []
         for leaf in flat:
-            if getattr(leaf, "ndim", 0) == 4:
+            if getattr(leaf, "ndim", 0) >= 3:
                 seg = next(it)
                 out.append(jax.lax.dynamic_update_slice(
                     leaf, seg[None].astype(leaf.dtype),
-                    (slot, 0, 0, 0),
+                    (slot,) + (0,) * (leaf.ndim - 1),
                 ))
             else:
                 out.append(leaf)
@@ -1787,7 +1954,7 @@ class SlotDecoder:
         flat, _ = jax.tree_util.tree_flatten(cache)
         out = []
         for leaf in flat:
-            if getattr(leaf, "ndim", 0) == 4:
+            if getattr(leaf, "ndim", 0) >= 3:
                 lane = jax.lax.dynamic_slice_in_dim(
                     leaf, slot, 1, axis=0
                 )[0]
@@ -1810,6 +1977,9 @@ class SlotDecoder:
             active, state["pad_start"], jnp.int32(self.cache_len)
         )
 
+        count_moe = self._moe_layers > 0
+        cfg = self.model.cfg
+
         def step(carry, key):
             cache, pos, tok, done = carry
             p = (
@@ -1820,9 +1990,22 @@ class SlotDecoder:
             )
             logits, mut = self.model.apply(
                 {"params": p, "cache": cache}, tok[:, None], decode=True,
-                mutable=["cache"], pad_start=pad_start,
+                mutable=["cache", "moe_stats"] if count_moe else ["cache"],
+                pad_start=pad_start,
                 slot_positions=pos, block_tables=tables,
             )
+            counts = None
+            if count_moe:
+                # what the rows requests hold asked of the experts held
+                # here: [layers, slots, held] -> three integers
+                chose = jnp.stack([
+                    c for c in jax.tree.leaves(mut["moe_stats"])
+                ]).astype(jnp.int32) * active[None, :, None]
+                counts = jnp.stack([
+                    jnp.sum(active) * (cfg.expert_k * self._moe_layers),
+                    jnp.sum(chose),
+                    jnp.sum(jnp.any(chose > 0, axis=1)),
+                ]).astype(jnp.int32)
             nxt = self._sample(logits[:, 0], key)
             if self.eos_id is not None:
                 nxt = jnp.where(done, jnp.int32(self.eos_id), nxt)
@@ -1833,16 +2016,20 @@ class SlotDecoder:
             pos = jnp.where(
                 active, jnp.minimum(pos + 1, self.cache_len - 1), pos
             )
-            return (mut["cache"], pos, nxt, done), nxt
+            return (mut["cache"], pos, nxt, done), (nxt, counts)
 
-        (cache, positions, last_tok, done), toks = jax.lax.scan(
+        (cache, positions, last_tok, done), (toks, counts) = jax.lax.scan(
             step,
             (cache, state["positions"], state["last_tok"], state["done"]),
             keys,
         )
         state = dict(state, positions=positions, last_tok=last_tok,
                      done=done)
-        return cache, state, jnp.swapaxes(toks, 0, 1)
+        toks = jnp.swapaxes(toks, 0, 1)
+        if count_moe:
+            # the three integers ride back beside the tokens
+            return cache, state, (toks, jnp.sum(counts, axis=0))
+        return cache, state, toks
 
     def _chunk_spec_impl(self, params, dparams, cache, dcache, state,
                          active, tables, keys):
@@ -2490,7 +2677,14 @@ class SlotDecoder:
             # tfoslint: disable=TFOS002(same sanctioned sync point as the line above)
             self.spec_proposed += int(np.asarray(prop).sum())
             return toks, valid
-        toks = np.asarray(pending)
+        if self._moe_layers:
+            # one pull for both: the copies start together
+            toks, counts = jax.device_get(pending)
+            self.last_chunk_counts = dict(zip(
+                ("moe_assignments", "moe_local_assignments",
+                 "moe_experts_hit"), (int(c) for c in counts)))
+        else:
+            toks = np.asarray(pending)
         return toks, np.full((toks.shape[0],), toks.shape[1], np.int32)
 
     def step_chunk(self):
@@ -2515,6 +2709,7 @@ class SlotDecoder:
             bank = self.num_slots * self._bank_len
         t = self._kv_block
         if not t:
+            # every bank whole under a mask
             return bank, bank
         window = self.model.cfg.attention_window
         canonical = self._paged or self._use_prefix
@@ -2526,6 +2721,22 @@ class SlotDecoder:
                 first = max(first, last + 1 - window)
             read += (last // t - first // t + 1) * t
         return read, bank
+
+    def attn_read_tokens(self, live):
+        """``(read, context)`` summed over the layers: the positions
+        the next chunk's first decode step reads — key/value or latent
+        rows on every layer, and the index keys of the layers that own
+        a sparse index — and the positions that are live (every
+        request's prompt and answer so far, a layer; what dense
+        attention over exactly the live keys would read).  ``live`` as
+        for :meth:`kv_read_tokens`: no device pull."""
+        cfg = self.model.cfg
+        read, bank = self.kv_read_tokens(live)
+        # the index scores every position of its bank
+        index_layers = sum(t == "full" for t in cfg.indexer_types)
+        context = sum(n + gen for n, gen in live)
+        return (read * cfg.num_layers + bank * index_layers,
+                context * cfg.num_layers)
 
     def reuse_stats(self):
         """Cross-request reuse counters: the prefix cache's

@@ -76,6 +76,14 @@ def _gmm_kernel(te_ref, x_ref, w_ref, y_ref):
     ).astype(y_ref.dtype)
 
 
+def _gmm_live_kernel(te_ref, live_ref, x_ref, w_ref, y_ref):
+    # tiles past the live ones hold no routed row: no product, and the
+    # index maps keep them on the last live tile's blocks (no copy)
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _():
+        _gmm_kernel(te_ref, x_ref, w_ref, y_ref)
+
+
 def _pick_bf(bm, d, f, bf=None, itemsize=2):
     """Pick a legal f-stripe width.
 
@@ -106,12 +114,16 @@ def _pick_bf(bm, d, f, bf=None, itemsize=2):
     return best if best else f
 
 
-def gmm_call(x, w, tile_expert, *, bm=256, bf=None, interpret=None):
+def gmm_call(x, w, tile_expert, *, bm=256, bf=None, interpret=None,
+             live_tiles=None):
     """Raw forward: ``y[N, F]`` for sorted ``x[N, D]``, ``w[E, D, F]``.
 
     ``N`` must be ``T*bm`` with ``tile_expert`` of shape ``[T]`` int32;
     differentiate through :func:`grouped_matmul` instead (this primal
-    has no registered gradient).
+    has no registered gradient).  With ``live_tiles`` (``[1]`` int32,
+    ``ops.moe.share_layout``) only the first so many row tiles are
+    multiplied; the rows of the others are left unwritten and must
+    never be read.
     """
     if interpret is None:
         interpret = compat.pallas_interpret()
@@ -123,22 +135,34 @@ def gmm_call(x, w, tile_expert, *, bm=256, bf=None, interpret=None):
     assert tile_expert.shape == (t,), (tile_expert.shape, t)
     bf = _pick_bf(bm, d, f, bf, itemsize=x.dtype.itemsize)
     assert f % bf == 0, (f, bf)
+    # the scalars the index maps see: tile_expert, and live_tiles when
+    # only the first so many row tiles hold rows (the others stay on
+    # the last live tile's blocks: nothing is copied for them)
+    scalars = (tile_expert,) if live_tiles is None else (
+        tile_expert, live_tiles)
+
+    def row(ti, s):
+        if len(s) == 1:
+            return ti
+        return jnp.maximum(jnp.minimum(ti, s[1][0] - 1), 0)
+
     grid_spec = _grid_spec(
-        1,
+        len(scalars),
         (f // bf, t),
         [
-            pl.BlockSpec((bm, d), lambda fi, ti, te: (ti, 0)),
-            pl.BlockSpec((1, d, bf), lambda fi, ti, te: (te[ti], 0, fi)),
+            pl.BlockSpec((bm, d), lambda fi, ti, *s: (row(ti, s), 0)),
+            pl.BlockSpec((1, d, bf), lambda fi, ti, *s: (s[0][ti], 0, fi)),
         ],
-        pl.BlockSpec((bm, bf), lambda fi, ti, te: (ti, fi)),
+        pl.BlockSpec((bm, bf), lambda fi, ti, *s: (row(ti, s), fi)),
     )
     return pl.pallas_call(
-        _gmm_kernel,
+        _gmm_kernel if live_tiles is None else _gmm_live_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, f), x.dtype),
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(tile_expert, x, w)
+        name="grouped_matmul",
+    )(*scalars, x, w)
 
 
 def _gmm_dxt_kernel(te_ref, dy_ref, w_ref, dx_ref):

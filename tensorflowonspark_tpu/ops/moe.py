@@ -285,6 +285,99 @@ def combine_sorted(ys, layout, gates, out_dtype=None):
     return y if out_dtype is None else y.astype(out_dtype)
 
 
+def sigmoid_topk(scores, bias, k, scaling=1.0):
+    """Bias-corrected top-k over sigmoid scores (``noaux_tc``): the
+    ``k`` experts with the largest ``scores + bias`` are chosen, ties
+    to the lower id; ``bias`` moves the choice and never the weight,
+    which is the chosen expert's own score over the sum of the chosen
+    scores, times ``scaling``.  ``scores [G, E]`` float32 in (0, 1).
+    Returns ``(experts [G, k] i32, gates [G, k] f32)``."""
+    _, experts = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    gates = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
+    return experts.astype(jnp.int32), gates
+
+
+class ShareLayout(NamedTuple):
+    """:class:`DroplessLayout` over the experts one chip HOLDS of a
+    layer whose router scores more: only the (token, choice) pairs
+    routed to a held expert take a row."""
+
+    #: [NP] i32: slot -> source token row (sentinel G = the zero row)
+    slot_token: jnp.ndarray
+    #: [G, k] i32: (token, choice) -> slot; NP (no row) where the
+    #: choice is an expert held elsewhere
+    dest: jnp.ndarray
+    #: [T] i32: row tile -> owning held expert (0-based among the
+    #: held); tiles past ``live_tiles`` repeat the last live tile's
+    tile_expert: jnp.ndarray
+    #: [1] i32: row tiles that hold a routed row
+    live_tiles: jnp.ndarray
+    #: [G, k] bool: the choice is an expert held here
+    local: jnp.ndarray
+
+
+def share_layout(experts, first, held, bm=256):
+    """The sorted, tile-aligned layout of ``experts [G, k]`` over the
+    held experts ``first .. first + held - 1``.  Static size ``NP =
+    round_up(G*k, bm) + held*bm`` holds every split — all choices
+    local included, so nothing is ever dropped; what is routed
+    elsewhere takes no row, and the row tiles past ``live_tiles`` hold
+    nothing (the grouped matmul skips them)."""
+    g, k = experts.shape
+    n = g * k
+    ef = experts.reshape(-1).astype(jnp.int32)
+    local = jnp.logical_and(ef >= first, ef < first + held)
+    el = jnp.where(local, ef - first, held)  # elsewhere sorts last
+    counts = jnp.bincount(el, length=held + 1)[:held]
+    padded = ((counts + bm - 1) // bm) * bm
+    zero = jnp.zeros((1,), jnp.int32)
+    ends = jnp.cumsum(padded).astype(jnp.int32)
+    starts = jnp.concatenate([zero, ends])          # [held + 1]
+    unaligned = jnp.concatenate(
+        [zero, jnp.cumsum(counts).astype(jnp.int32)])
+    np_rows = ((n + bm - 1) // bm) * bm + held * bm
+    order = jnp.argsort(el, stable=True)
+    sorted_e = el[order]
+    slot_sorted = jnp.where(
+        sorted_e < held,
+        starts[sorted_e] + jnp.arange(n, dtype=jnp.int32)
+        - unaligned[sorted_e],
+        np_rows,
+    )
+    dest_flat = jnp.zeros((n,), jnp.int32).at[order].set(slot_sorted)
+    t = np_rows // bm
+    live_tiles = ends[-1:] // bm
+    tile = jnp.arange(t, dtype=jnp.int32)
+    tile_expert = jnp.clip(
+        jnp.searchsorted(ends, tile * bm, side="right"), 0, held - 1
+    ).astype(jnp.int32)
+    last = tile_expert[jnp.maximum(live_tiles[0] - 1, 0)]
+    tile_expert = jnp.where(tile < live_tiles[0], tile_expert, last)
+    token_ids = jnp.repeat(jnp.arange(g, dtype=jnp.int32), k)
+    slot_token = jnp.full((np_rows,), g, jnp.int32).at[dest_flat].set(
+        token_ids, mode="drop")
+    return ShareLayout(
+        slot_token=slot_token, dest=dest_flat.reshape(g, k),
+        tile_expert=tile_expert, live_tiles=live_tiles,
+        local=local.reshape(g, k),
+    )
+
+
+def combine_share(ys, layout, gates, out_dtype=None):
+    """``y[g] = sum over the LOCAL choices k of gates[g, k] ·
+    ys[dest[g, k]]``: this chip's part of the routed sum."""
+    rows = jnp.take(ys, layout.dest, axis=0, mode="fill", fill_value=0)
+    # weights and their sum in float32: a gate rounded to the rows'
+    # bf16 is a 0.4% error on every routed term
+    y = jnp.sum(
+        jnp.where(layout.local[..., None],
+                  rows.astype(jnp.float32) * gates[..., None], 0.0),
+        axis=1,
+    )
+    return y.astype(ys.dtype if out_dtype is None else out_dtype)
+
+
 def expert_capacity(num_tokens, num_experts, capacity_factor=1.25, k=2):
     """Standard capacity formula: ``ceil(k * G / E * factor)``, rounded
     up to a multiple of 8 (TPU sublane alignment)."""

@@ -714,6 +714,12 @@ class ServingEngine(object):
                 "swap_rollbacks", "drained",
             )
         }
+        self._m_chunk = {
+            name: reg.counter("serving." + name)
+            for name in ("moe_assignments", "moe_local_assignments",
+                         "moe_experts_hit", "attn_read_tokens",
+                         "attn_context_tokens")
+        }
         self._m_gen = reg.gauge("serving.weight_generation")
         self._m_gen.set(self.stats["weight_generation"])
         # live re-planner sensors (ISSUE 18): admitted prompt lengths
@@ -1250,6 +1256,11 @@ class ServingEngine(object):
                 else:
                     with self._tracer.span("prefill", trace=rid) as sp:
                         first = self.decoder.admit(slot, prompt)
+                        sp.set("prompt_tokens", int(len(prompt)))
+                        bucket_of = getattr(
+                            self.decoder, "bucket_len", None)
+                        if bucket_of is not None:
+                            sp.set("bucket", int(bucket_of(len(prompt))))
                         cached = int(getattr(
                             self.decoder, "last_admit_cached_tokens", 0
                         ))
@@ -1474,18 +1485,26 @@ class ServingEngine(object):
         banks, for its ``engine.chunk`` span and ``stats``: ``attn``
         (what the decoder's chunk program attends with),
         ``kv_read_tokens`` (positions per layer its first step reads,
-        from this scheduler's own record of every request in flight)
-        and ``kv_bank_tokens`` (what the banks hold).  Nothing for a
-        decoder that does not say (tests' fakes)."""
+        from this scheduler's own record of every request in flight),
+        ``kv_bank_tokens`` (what the banks hold) and, summed over the
+        layers, ``attn_read_tokens`` against ``attn_context_tokens``
+        (positions read, index keys included, against positions live).
+        Nothing for a decoder that does not say (tests' fakes)."""
         reads = getattr(self.decoder, "kv_read_tokens", None)
         if reads is None:
             return {}
-        read, bank = reads([
+        live = [
             (req["admit_len"], len(req["out"]) - req["admit_out"])
             for req in self._slot_req.values()
-        ])
-        return {"attn": self.decoder.attn_impl,
-                "kv_read_tokens": read, "kv_bank_tokens": bank}
+        ]
+        read, bank = reads(live)
+        attrs = {"attn": self.decoder.attn_impl,
+                 "kv_read_tokens": read, "kv_bank_tokens": bank}
+        over_layers = getattr(self.decoder, "attn_read_tokens", None)
+        if over_layers is not None:
+            attrs["attn_read_tokens"], attrs["attn_context_tokens"] = (
+                over_layers(live))
+        return attrs
 
     def _run_chunk(self):
         """One decode chunk under the watchdog; returns a
@@ -1509,6 +1528,13 @@ class ServingEngine(object):
                 return None
             self.stats["chunks"] += 1
             self._m["chunks"].inc()
+            # the expert counts the chunk program returned with its
+            # tokens (decoders with sigmoid-routed layers only)
+            moe = getattr(self.decoder, "last_chunk_counts", None) or {}
+            for name, n in moe.items():
+                chunk_span.set(name, n)
+            for name, counter in self._m_chunk.items():
+                counter.inc(moe.get(name, kv.get(name, 0)))
             if self._profile is not None:
                 self._profile.step()
         dur = time.perf_counter() - t_chunk0

@@ -78,7 +78,21 @@ METRICS = tuple(
        ("serving.swaps", "weight swaps installed"),
        ("serving.swap_commits", "probation windows closed clean"),
        ("serving.swap_rollbacks", "swaps rolled back inside the window"),
-       ("serving.drained", "requests returned as typed drained records"))
+       ("serving.drained", "requests returned as typed drained records"),
+       ("serving.moe_assignments",
+        "(token, expert) choices of the decode steps' live rows over "
+        "the sigmoid-routed layers"),
+       ("serving.moe_local_assignments",
+        "of those, choices of an expert this program holds"),
+       ("serving.moe_experts_hit",
+        "held experts chosen by at least one live row, summed over "
+        "decode steps and layers"),
+       ("serving.attn_read_tokens",
+        "positions a chunk's first decode step reads, summed over "
+        "layers (index keys included)"),
+       ("serving.attn_context_tokens",
+        "live positions (prompt + answer so far) of the requests in "
+        "flight at a chunk, summed over layers"))
     + _m(_H, "ServingEngine",
          ("serving.request_latency_sec",
           "submit→emit latency, BOTH schedules (the authoritative "

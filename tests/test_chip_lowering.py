@@ -145,3 +145,96 @@ def test_bank_decode_kernel_compiles_for_v5e_without_copying_a_bank(
     # view is a copy of 2 x 3 MB)
     bank_bytes = b * s * hkv * d * jnp.dtype(cache).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < bank_bytes // 8
+
+
+@pytest.mark.parametrize("rows,bm", [(17 * 8, 16), (1024 * 8, 256)])
+def test_share_grouped_matmul_compiles_for_v5e_at_both_row_tiles(
+        mosaic, rows, bm):
+    """The routed experts of the latent-attention cell at published
+    widths (16 held experts, 6144 x 2048): a decode step's 136
+    assignments in row tiles of 16, a prompt chunk's 8192 in tiles of
+    256 — Mosaic must take the live-tile kernel at both."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
+        pytest.skip("no TPU AOT topology here: %s" % e)
+    dev = SingleDeviceSharding(topo.devices[0])
+    held, d, f = 16, 6144, 2048
+    t = -(-rows // bm) + held
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    def experts(x, wi, wg, wo, te, live):
+        def mm(a, w):
+            return gmm.gmm_call(a, w, te, bm=bm, live_tiles=live)
+
+        return mm(jax.nn.silu(mm(x, wg)) * mm(x, wi), wo)
+
+    w_in = arg((held, d, f), jnp.bfloat16)
+    compiled = jax.jit(experts).lower(
+        arg((t * bm, d), jnp.bfloat16), w_in, w_in,
+        arg((held, f, d), jnp.bfloat16), arg((t,), jnp.int32),
+        arg((1,), jnp.int32),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_latent_decode_step_compiles_for_v5e_without_copying_a_bank(mosaic):
+    """One absorbed decode step of latent attention at the cell's
+    geometry (17 slots, banks of 20480 positions, 64 heads, rows of
+    512 + 64 padded to 640 lanes, an index of 32 heads x 128): Mosaic
+    must take the latent decode kernel, and the row append, the index,
+    the selection and the kernel's view must leave the bank where it
+    is — a relayout or a slice down to the 512 latent columns is a
+    copy of 446 MB a layer, a step."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import mla
+    from tensorflowonspark_tpu.models import transformer as tr
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
+        pytest.skip("no TPU AOT topology here: %s" % e)
+    dev = SingleDeviceSharding(topo.devices[0])
+    slots, length = 17, 20480
+    cfg = tr.TransformerConfig(
+        num_heads=64, embed_dim=6144, max_seq_len=length,
+        attention_kind="mla", q_lora_rank=2048, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        index_n_heads=32, index_head_dim=128, index_topk=2048,
+        rope_theta=8e6, rope_interleave=True, rms_norm_eps=1e-5,
+    )
+    layer = mla.MLAttention(cfg, indexer="full")
+    x = jnp.zeros((slots, 1, cfg.embed_dim), jnp.bfloat16)
+    pos = jnp.zeros((slots, 1), jnp.int32)
+    shapes = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), x, pos, decode=True))
+    assert shapes["cache"]["latent"].shape == (slots, length, 640)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.dtype == jnp.float32 else a.dtype,
+            sharding=dev), tree)
+
+    def step(params, cache, x, pos, pad):
+        (out, sel), mut = layer.apply(
+            {"params": params, "cache": cache}, x, pos, decode=True,
+            pad_start=pad, per_slot=True, mutable=["cache"])
+        return out, sel, mut["cache"]
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(shapes["params"]), on_chip(shapes["cache"]),
+        on_chip(x), on_chip(pos),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=dev),
+    ).compile()
+    assert "latent_decode_attention" in compiled.as_text()
+    bank_bytes = slots * length * 640 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < bank_bytes // 2
