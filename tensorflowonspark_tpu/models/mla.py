@@ -30,9 +30,10 @@ The attention of ``TransformerConfig(attention_kind="mla")``:
   as ``sel``: a boolean ``[B, queries, keys]``.
 
 Selection is exact (:func:`topk_mask`).  The decode step reads the
-rows of each slot's live span once, under that mask, through
-``ops/latent_attention.py`` (docs/serving.md "Latent and index
-banks").
+rows of each slot's live span once, under that mask, and a prefill's
+span attends block by block under it with its scores in VMEM, both
+through ``ops/latent_attention.py`` (docs/serving.md "Latent and
+index banks"); :func:`decode_block` and :func:`span_blocks` say when.
 """
 
 import flax.linen as nn
@@ -70,6 +71,24 @@ def decode_block(cfg, bank_len):
     from tensorflowonspark_tpu.ops.latent_attention import block_rows
 
     return block_rows(bank_len, bank_width(cfg))
+
+
+def span_blocks(cfg, decode, span):
+    """``(queries, keys)`` a grid step when a span's attention over its
+    own tokens goes through the span kernel
+    (:func:`..ops.latent_attention.latent_span_attention`), else None
+    (:func:`span_attention`'s einsums a block of queries).  The twin
+    of :func:`decode_block`, from what the code can see: the module is
+    filling a cache with more than one token (``decode``: a prefill,
+    through which no gradient is ever taken — the kernel is forward
+    only), no mesh (GSPMD does not partition a Pallas call), and a
+    span that the kernel's blocks divide (a multiple of 128: the slot
+    decoder's prompt buckets of a long-prompt mix are)."""
+    if not decode or span <= 1 or cfg.mesh is not None:
+        return None
+    from tensorflowonspark_tpu.ops import latent_attention
+
+    return latent_attention.span_blocks(span)
 
 
 def topk_mask(scores, visible, k):
@@ -126,8 +145,45 @@ def index_scores(q_i, k_i, w_i):
     return jnp.sum(nn.relu(dots) * w_i[..., None], axis=2)
 
 
+def _query_block(pos, pad_start, b, start, sub, k1):
+    """The ``sub`` queries from row ``start`` of a span against its
+    first ``k1`` keys: ``cut`` (takes their rows of a ``[B, S, ...]``
+    tensor), their positions, and the keys they may see ``[B, sub,
+    k1]``."""
+    def cut(t):
+        return jax.lax.dynamic_slice_in_dim(t, start, sub, axis=1)
+
+    qpos = jax.lax.dynamic_slice_in_dim(pos, start, sub)
+    vis = jnp.broadcast_to(
+        _visible(qpos[None], pos[:k1], pad_start), (b, sub, k1))
+    return cut, qpos, vis
+
+
+def _selection(cut, c_q, qpos, vis, k1, index, sel, topk):
+    """The keys ``[B, Q, k1]`` that a block of queries attends over,
+    of the ``vis`` ible ones: the index's ``topk``, else the block's
+    rows of ``sel``, else all of them.  ``cut`` takes the block's rows
+    of a ``[B, S, ...]`` tensor."""
+    if index is not None:
+        index_queries, k_i, w_i = index
+        with jax.named_scope("dsa.index"):
+            scores = index_scores(
+                index_queries(cut(c_q), qpos), k_i[:, :k1], cut(w_i))
+        with jax.named_scope("dsa.select"):
+            return topk_mask(scores, vis, topk)
+    if sel is not None:
+        return cut(sel)[:, :, :k1]
+    return vis
+
+
+def _query_blocks(s):
+    """``(super-block, sub-block)`` of a span of ``s`` queries."""
+    sup = Q_SUPER if s % Q_SUPER == 0 else s
+    return sup, Q_SUB if sup % Q_SUB == 0 else sup
+
+
 def span_attention(c_q, queries, c_kv, keys, k_r, pos, pad_start, scale,
-                   index=None, sel=None, topk=0):
+                   index=None, sel=None, topk=0, blocks=None):
     """Non-absorbed attention of a span over its own tokens, queries in
     blocks: ``c_q [B, S, rq]`` the query latent, ``queries(c_q_block,
     pos_block)`` -> ``(q_nope [B, Q, H, dn], q_rope [B, Q, H, dr])``
@@ -142,39 +198,27 @@ def span_attention(c_q, queries, c_kv, keys, k_r, pos, pad_start, scale,
     ascending).
     ``index`` = ``(index_queries, k_i, w_i)`` selects ``topk`` keys a
     query; else ``sel`` ``[B, S, S]`` is the selection to attend over;
-    else every visible key.  Returns the context ``[B, S, H, dv]`` and
-    the selection ``[B, S, S]`` it attended over."""
+    else every visible key.  ``blocks`` (:func:`span_blocks`) sends
+    the products and the softmax through the span kernel instead: the
+    same selection, made first, then every head over keys expanded
+    once (:func:`_kernel_span_attention`).  Returns the context ``[B,
+    S, H, dv]`` and the selection ``[B, S, S]`` it attended over."""
+    if blocks is not None:
+        return _kernel_span_attention(
+            c_q, queries, c_kv, keys, k_r, pos, pad_start, scale, index,
+            sel, topk, blocks)
     b, s = c_q.shape[:2]
-    sup = Q_SUPER if s % Q_SUPER == 0 else s
-    sub = Q_SUB if sup % Q_SUB == 0 else sup
+    sup, sub = _query_blocks(s)
     ctxs, masks = [], []
     for q0 in range(0, s, sup):
         k1 = q0 + sup  # no key after the super-block's last query
-        kpos = pos[:k1]
         k_nope, v = keys(c_kv[:, :k1])
 
-        def one(i, q0=q0, k1=k1, kpos=kpos, k_nope=k_nope, v=v):
-            start = q0 + i * sub
-
-            def cut(t):
-                return jax.lax.dynamic_slice_in_dim(t, start, sub, axis=1)
-
-            qpos = jax.lax.dynamic_slice_in_dim(pos, start, sub)
-            vis = jnp.broadcast_to(
-                _visible(qpos[None], kpos, pad_start), (b, sub, k1))
+        def one(i, q0=q0, k1=k1, k_nope=k_nope, v=v):
+            cut, qpos, vis = _query_block(
+                pos, pad_start, b, q0 + i * sub, sub, k1)
             q_nope, q_rope = queries(cut(c_q), qpos)
-            if index is not None:
-                index_queries, k_i, w_i = index
-                with jax.named_scope("dsa.index"):
-                    scores = index_scores(
-                        index_queries(cut(c_q), qpos), k_i[:, :k1],
-                        cut(w_i))
-                with jax.named_scope("dsa.select"):
-                    mask = topk_mask(scores, vis, topk)
-            elif sel is not None:
-                mask = cut(sel)[:, :, :k1]
-            else:
-                mask = vis
+            mask = _selection(cut, c_q, qpos, vis, k1, index, sel, topk)
             logits = jnp.einsum(
                 "bqhd,bhkd->bhqk", q_nope, k_nope,
                 preferred_element_type=jnp.float32,
@@ -198,6 +242,55 @@ def span_attention(c_q, queries, c_kv, keys, k_r, pos, pad_start, scale,
             (b, sup) + ctx.shape[3:]))
         masks.append(jnp.moveaxis(mask, 0, 1).reshape(b, sup, s))
     return jnp.concatenate(ctxs, axis=1), jnp.concatenate(masks, axis=1)
+
+
+def _kernel_span_attention(c_q, queries, c_kv, keys, k_r, pos, pad_start,
+                           scale, index, sel, topk, blocks):
+    """:func:`span_attention` through the span kernel.  The selection
+    comes first and is what the einsum form computes: the index's
+    scores and the exact top-k a sub-block of queries over the keys up
+    to its super-block's end, in XLA (a ``"shared"`` or index-less
+    layer has nothing to compute).  Then ONE kernel call for the span:
+    queries of every head ``[B, H, S, dn + dr]``, keys expanded once
+    with the rotary key written beside every head's (one MXU product
+    contracting 256 where ``k_nope`` and ``k_r`` apart are two, 192
+    and 64 deep, at the cost of ``S x H x dr`` more key bytes), the
+    selection as int8.  Key blocks after a query block, or wholly in
+    the pad region, are skipped inside the kernel."""
+    from tensorflowonspark_tpu.ops import latent_attention
+
+    b, s = c_q.shape[:2]
+    if index is None:
+        mask = sel if sel is not None else jnp.broadcast_to(
+            _visible(pos[None], pos, pad_start), (b, s, s))
+    else:
+        sup, sub = _query_blocks(s)
+        masks = []
+        for q0 in range(0, s, sup):
+            k1 = q0 + sup
+
+            def one(i, q0=q0, k1=k1):
+                cut, qpos, vis = _query_block(
+                    pos, pad_start, b, q0 + i * sub, sub, k1)
+                return jnp.pad(
+                    _selection(cut, c_q, qpos, vis, k1, index, None, topk),
+                    ((0, 0), (0, 0), (0, s - k1)))
+
+            masks.append(jnp.moveaxis(
+                jax.lax.map(one, jnp.arange(sup // sub)), 0, 1,
+            ).reshape(b, sup, s))
+        mask = jnp.concatenate(masks, axis=1)
+    q = jnp.swapaxes(jnp.concatenate(queries(c_q, pos), axis=-1), 1, 2)
+    k_nope, v = keys(c_kv)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(
+            k_r[:, None], k_nope.shape[:3] + k_r.shape[-1:])], axis=-1)
+    # the first key outside the pad region, as a row of the span
+    first = jnp.zeros((b,), jnp.int32) if pad_start is None else jnp.clip(
+        pad_start - pos[0], 0, s - 1).astype(jnp.int32)
+    ctx = latent_attention.latent_span_attention(
+        q, k, v, mask.astype(jnp.int8), first, scale=scale, blocks=blocks)
+    return jnp.swapaxes(ctx, 1, 2), mask
 
 
 class MLAttention(nn.Module):
@@ -328,6 +421,7 @@ class MLAttention(nn.Module):
                     positions[0], pad_start, scale, index=index,
                     sel=sel if self.indexer == "shared" else None,
                     topk=cfg.index_topk,
+                    blocks=span_blocks(cfg, decode, s),
                 )
             sel = mask if self.indexer else None
         out = nn.DenseGeneral(

@@ -2164,6 +2164,23 @@ class SlotDecoder:
         return max(int(prompt_len), min(b, self.cache_len
                                         - self.max_new_tokens))
 
+    def prefill_attn(self, bucket):
+        """What the prefill program of a ``bucket``-token prompt
+        attends with, for the engine's ``prefill`` span (the decode
+        chunk's is ``attn_impl``): ``"latent_span_kernel"`` where a
+        latent span goes through the span kernel and ``"einsum"``
+        where it keeps its einsums (``mla.span_blocks`` decides, the
+        function the model itself asks); over K/V banks a span is
+        always ``"dot"`` (masked dot attention over the bank), over
+        pages ``"gather"``."""
+        if self._latent:
+            from tensorflowonspark_tpu.models.mla import span_blocks
+
+            return ("latent_span_kernel"
+                    if span_blocks(self.model.cfg, True, int(bucket))
+                    else "einsum")
+        return "gather" if self._paged else "dot"
+
     def _suffix_bucket(self, suffix_len, kpref):
         """Suffix-prefill bucket for a cached-prefix admit: round the
         uncached tail up to ``pad_multiple``, capped so the bucketed

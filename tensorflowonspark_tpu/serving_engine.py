@@ -1260,7 +1260,12 @@ class ServingEngine(object):
                         bucket_of = getattr(
                             self.decoder, "bucket_len", None)
                         if bucket_of is not None:
-                            sp.set("bucket", int(bucket_of(len(prompt))))
+                            bucket = int(bucket_of(len(prompt)))
+                            sp.set("bucket", bucket)
+                            attn_of = getattr(
+                                self.decoder, "prefill_attn", None)
+                            if attn_of is not None:
+                                sp.set("attn", attn_of(bucket))
                         cached = int(getattr(
                             self.decoder, "last_admit_cached_tokens", 0
                         ))

@@ -238,3 +238,66 @@ def test_latent_decode_step_compiles_for_v5e_without_copying_a_bank(mosaic):
     assert "latent_decode_attention" in compiled.as_text()
     bank_bytes = slots * length * 640 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < bank_bytes // 2
+
+
+def test_latent_prefill_layer_compiles_for_v5e_with_its_scores_in_vmem(
+        mosaic):
+    """One "full" latent-attention layer prefilling the cell's longest
+    bucket (16384 tokens, 64 heads of 192 + 64 / 256, an index of 32
+    heads x 128 picking 2048) into a lane of its banks: the span's
+    attention must be the span kernel, no float32 ``[64, queries,
+    keys]`` score tensor may be left in the program (the einsum form
+    holds one of 128 x 16384 a block of queries), and the plan's
+    temporaries stay under the 3.45 GB the whole prefill program of
+    that bucket took with the einsums (PERF.md section 4)."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import mla
+    from tensorflowonspark_tpu.models import transformer as tr
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
+        pytest.skip("no TPU AOT topology here: %s" % e)
+    dev = SingleDeviceSharding(topo.devices[0])
+    span, length = 16384, 20480
+    cfg = tr.TransformerConfig(
+        num_heads=64, embed_dim=6144, max_seq_len=length,
+        attention_kind="mla", q_lora_rank=2048, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        index_n_heads=32, index_head_dim=128, index_topk=2048,
+        rope_theta=8e6, rope_interleave=True, rms_norm_eps=1e-5,
+    )
+    assert mla.span_blocks(cfg, True, span) == (2048, 512)
+    layer = mla.MLAttention(cfg, indexer="full")
+    x = jnp.zeros((1, span, cfg.embed_dim), jnp.bfloat16)
+    pos = jnp.zeros((1, span), jnp.int32)
+    shapes = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), x[:, :1], pos[:, :1], decode=True))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.dtype == jnp.float32 else a.dtype,
+            sharding=dev), tree)
+
+    def prefill(params, cache, x, pos, pad):
+        (out, sel), mut = layer.apply(
+            {"params": params, "cache": cache}, x, pos, decode=True,
+            pad_start=pad, mutable=["cache"])
+        return out, sel, mut["cache"]
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        on_chip(shapes["params"]), on_chip(shapes["cache"]),
+        on_chip(x), on_chip(pos),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=dev),
+    ).compile()
+    text = compiled.as_text()
+    assert "latent_span_attention" in text
+    scores = [m for m in re.findall(r"f32\[(?:1,)?64,\d+,(\d+)\]", text)
+              if int(m) >= 2048]
+    assert scores == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.45e9

@@ -25,13 +25,21 @@ from tensorflowonspark_tpu.utils import compile_cache
 where = compile_cache.ensure_compile_cache()
 jax.config.update = real_update
 import jax.numpy as jnp
-jax.jit(lambda x: jnp.tanh(x * %(salt)s) @ x)(jnp.ones((8, 8))).block_until_ready()
+def tfos_cache_probe(x):
+    return jnp.tanh(x * %(salt)s) @ x
+jax.jit(tfos_cache_probe)(jnp.ones((8, 8))).block_until_ready()
 d = jax.config.jax_compilation_cache_dir
 print(json.dumps({
     "where": where, "config_dir": d, "updates": calls,
-    "entries": sorted(f for f in os.listdir(d) if f.endswith("-cache")),
+    "entries": sorted(f for f in os.listdir(d)
+                      if f.startswith("jit_tfos_cache_probe-")
+                      and f.endswith("-cache")),
 }))
 """
+#: ``entries`` are the probe program's own, by its name: the fixed
+#: directory is shared with every test worker (``conftest.py`` calls
+#: ``ensure_compile_cache``), and whatever another worker compiles
+#: between two children lands there too
 
 
 def _child(tmp_path, salt, **env_extra):

@@ -25,6 +25,7 @@ from benchmarks.reference import glm_dsa_moe as ref
 from benchmarks.runners import serve_mla_moe as runner
 from tensorflowonspark_tpu.models import mla, moe
 from tensorflowonspark_tpu.models import transformer as tr
+from tensorflowonspark_tpu.ops import latent_attention as la
 from tensorflowonspark_tpu.ops import moe as moe_ops
 
 TOL = 2e-5
@@ -134,6 +135,22 @@ def test_full_forward_is_the_reference_s():
     assert float(jnp.max(jnp.abs(got - want))) < TOL
 
 
+@pytest.fixture
+def span_kernel_calls(monkeypatch):
+    """The span kernel's blocks cut to test size (a prefill of 16
+    tokens is then 2 x 2 blocks of 8, or one of 16), and the block
+    sizes of every call of the kernel."""
+    monkeypatch.setattr(la, "SPAN_BLOCKS", ((16, 16), (8, 8)))
+    calls, kernel = [], la.latent_span_attention
+
+    def counted(*args, **kw):
+        calls.append(kw["blocks"])
+        return kernel(*args, **kw)
+
+    monkeypatch.setattr(la, "latent_span_attention", counted)
+    return calls
+
+
 @pytest.mark.parametrize("prompt_len,total,pad", [
     (6, 11, 0),    # every context under index_topk: all keys selected
     (20, 30, 4),   # over it, behind a pad region
@@ -144,6 +161,25 @@ def test_prefill_then_decode_is_the_reference_s_full_forward(
     cfg, model, params = build()
     tokens = tokens_of(total, seed=prompt_len)
     got = step_logits(model, params, tokens, prompt_len, pad)
+    want = reference(cfg, params, tokens)[prompt_len - 1:]
+    assert float(jnp.max(jnp.abs(got - want))) < TOL
+
+
+@pytest.mark.parametrize("prompt_len,total,pad,blocks", [
+    (6, 11, 2, (8, 8)),      # one block, all keys selected
+    (20, 30, 4, (8, 8)),     # 3 x 3 blocks, the pad region inside one
+    (9, 18, 7, (16, 16)),    # one block of 16, crossing index_topk
+    (23, 31, 9, (16, 16)),   # 2 x 2 blocks, a whole key block of pad
+])
+def test_a_prefill_through_the_span_kernel_then_decode_is_the_reference_s(
+        prompt_len, total, pad, blocks, span_kernel_calls):
+    # the same comparison with the prefill's attention through the span
+    # kernel on all 4 layers (2 "full", 2 "shared"), the decode steps
+    # reading the rows it banked
+    cfg, model, params = build()
+    tokens = tokens_of(total, seed=prompt_len)
+    got = step_logits(model, params, tokens, prompt_len, pad)
+    assert span_kernel_calls == [blocks] * 4
     want = reference(cfg, params, tokens)[prompt_len - 1:]
     assert float(jnp.max(jnp.abs(got - want))) < TOL
 
@@ -164,6 +200,115 @@ def test_a_span_longer_than_a_super_block_is_the_reference_s(monkeypatch):
     got = step_logits(model, params, tokens, 27, pad=5)  # a bucket of 32
     want = reference(cfg, params, tokens)[26:]
     assert float(jnp.max(jnp.abs(got - want))) < TOL
+
+
+@pytest.mark.parametrize("indexer,span,pad,supers,blocks", [
+    # a "full" layer over 48 keys with index_topk 12: the selection bites
+    ("full", 48, 0, None, (16, 16)),
+    # a "shared" layer attends over the set it is fed
+    ("shared", 48, 0, None, (16, 16)),
+    # no index: every visible key
+    ("", 48, 0, None, (16, 16)),
+    # a left pad region that holds a whole key block and cuts the next
+    ("full", 48, 21, None, (16, 16)),
+    ("shared", 40, 19, None, (8, 8)),
+    # 4 super-blocks of 16 queries in sub-blocks of 8: the selection is
+    # made a super-block at a time, the keys expanded once
+    ("full", 64, 5, (16, 8), (16, 16)),
+    # a span the blocks do not divide keeps the einsums: same answer
+    ("full", 44, 3, None, None),
+], ids=["full", "shared", "no-index", "pad-full", "pad-shared",
+        "super-blocks", "undivided"])
+def test_the_span_kernel_is_the_einsum_form(
+        indexer, span, pad, supers, blocks, span_kernel_calls, monkeypatch):
+    # one layer filling its banks (decode=True: the span kernel where
+    # the blocks divide the span) against the same layer in a full
+    # forward (the einsums): the selection bit for bit, the output to
+    # float32 rounding
+    if supers:
+        monkeypatch.setattr(mla, "Q_SUPER", supers[0])
+        monkeypatch.setattr(mla, "Q_SUB", supers[1])
+    _, model, params = build()
+    assert mla.span_blocks(model.cfg, True, span) == blocks
+    assert mla.span_blocks(model.cfg, False, span) is None
+    x = jax.random.normal(jax.random.PRNGKey(span), (1, span, 64))
+    pos, pads = jnp.arange(span)[None], jnp.asarray([pad])
+    fed = None
+    if indexer == "shared":
+        _, fed = mla.MLAttention(model.cfg, indexer="full").apply(
+            {"params": params["block_0"]["attn"]}, x, pos, pad_start=pads)
+    layer = mla.MLAttention(model.cfg, indexer=indexer)
+    weights_ = {"params": params[
+        "block_0" if indexer == "full" else "block_1"]["attn"]}
+    want, want_sel = layer.apply(weights_, x, pos, pad_start=pads, sel=fed)
+    assert span_kernel_calls == []
+    (got, got_sel), banked = layer.apply(
+        weights_, x, pos, decode=True, pad_start=pads, sel=fed,
+        mutable=["cache"])
+    assert span_kernel_calls == ([blocks] if blocks else [])
+    if indexer:
+        assert got_sel.dtype == jnp.bool_
+        assert bool(jnp.all(got_sel == want_sel))
+        if indexer == "full":  # and it bites: 12 of up to 48 - pad keys
+            assert int(got_sel[0, -1].sum()) == 12
+    else:
+        assert got_sel is None and want_sel is None
+    assert float(jnp.max(jnp.abs(got - want))) < TOL
+    assert banked["cache"]["latent"].shape == (1, 128, 128)
+
+
+def test_the_span_kernel_in_bfloat16_is_within_rounding_of_float32():
+    # the kernel as the chip runs it — bfloat16 operands, float32
+    # scores and sums, probabilities rounded for the second product —
+    # at its real block size (a span of 384 is 3 x 3 blocks of 128),
+    # against float32 einsums over the same bfloat16 values
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    b, h, s, d, dv = 2, 2, 384, 32, 16
+    q, key = (jax.random.normal(k[i], (b, h, s, d)).astype(jnp.bfloat16)
+              for i in (0, 1))
+    v = jax.random.normal(k[2], (b, h, s, dv)).astype(jnp.bfloat16)
+    pad = jnp.asarray([0, 150])
+    at = jnp.arange(s)
+    itself = at[:, None] == at[None]
+    mask = ((at[None] <= at[:, None]) & (at[None] >= pad[:, None, None]) & (
+        jax.random.uniform(k[3], (b, s, s)) < 0.4)) | itself
+    assert la.span_blocks(s) == (128, 128)
+    got = la.latent_span_attention(
+        q, key, v, mask.astype(jnp.int8), pad, scale=0.2)
+    assert got.dtype == jnp.bfloat16 and got.shape == (b, h, s, dv)
+    f32 = jnp.float32
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(f32), key.astype(f32))
+    probs = jax.nn.softmax(
+        jnp.where(mask[:, None], logits * 0.2, -jnp.inf), axis=-1)
+    want = jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(f32))
+    # a context of size ~1 rounded to 8 bits, its probabilities too
+    assert float(jnp.max(jnp.abs(got.astype(f32) - want))) < 2e-2
+    assert la.span_blocks(16384) == la.span_blocks(10240) == (2048, 512)
+    assert la.span_blocks(1024 * 13) == (1024, 512)
+    assert la.span_blocks(2048 + 128) == (128, 128)
+    assert la.span_blocks(100) is None
+    with pytest.raises(ValueError, match="do not divide a span"):
+        la.latent_span_attention(
+            q[:, :, :100], key[:, :, :100], v[:, :, :100],
+            mask[:, :100, :100].astype(jnp.int8), pad, scale=0.2)
+
+
+def test_a_dropped_selection_shows_through_the_span_kernel(
+        span_kernel_calls, monkeypatch):
+    # the benchmark's planted fault (every query attends to the most
+    # recent index_topk keys) replaces mla.topk_mask: the kernel path
+    # calls the selection by that module-level name, so the fault
+    # reaches a prefill through the kernel as it reaches the einsums
+    from benchmarks.tests import faults_glm_dsa_moe
+
+    cfg, model, params = build()
+    tokens = tokens_of(30, seed=20)
+    want = reference(cfg, params, tokens)[19:]
+    monkeypatch.setattr(mla, "topk_mask", mla.topk_mask)  # restored after
+    faults_glm_dsa_moe.plant("recent_keys_only")
+    got = step_logits(model, params, tokens, 20, pad=4)
+    assert span_kernel_calls == [(8, 8)] * 4
+    assert float(jnp.max(jnp.abs(got - want))) > 1e-2
 
 
 def test_absorbed_decode_is_the_non_absorbed_span_form():

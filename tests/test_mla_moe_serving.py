@@ -89,6 +89,43 @@ def test_slots_at_mixed_positions_serve_the_reference_s_best_tokens():
     assert 0 < counts["moe_experts_hit"] <= 4 * 3 * 4
 
 
+@pytest.mark.parametrize("pad_multiple,lengths,forms", [
+    # buckets of 128 and 256: 1 x 1 and 2 x 2 blocks of the span kernel
+    (128, (100, 130), ["latent_span_kernel", "latent_span_kernel"]),
+    # a bucket of 136 (no block divides it) beside one of 128
+    (8, (131, 122), ["einsum", "latent_span_kernel"]),
+])
+def test_prompts_admitted_through_the_span_kernel_serve_the_reference_s_best(
+        pad_multiple, lengths, forms):
+    # the serving path with the prefill's attention as the span kernel
+    # where the bucket allows: the engine's span says which it was, and
+    # the tokens sit on the reference's best either way
+    from tensorflowonspark_tpu import serving, serving_engine, telemetry
+
+    cfg, model, params = build(seed=5, max_position_embeddings=512)
+
+    class Plan:
+        answer_len = np.array([6])
+        prompt_len = np.array([256])
+
+    predict = tr.serving_builder(params, dict(
+        runner.program_config(cfg, Plan), pad_multiple=pad_multiple,
+        chunk_size=4))
+    prompts = [tokens_of(n, seed=n) for n in lengths]
+    tracer = telemetry.get_tracer()
+    tracer.clear()
+    outs = list(serving.predict_rows(
+        predict, [{"prompt": p} for p in prompts], {"prompt": "tokens"},
+        batch_size=2, schedule="continuous", on_error="raise"))
+    prefills = {s["attrs"]["prompt_tokens"]: s["attrs"]["attn"]
+                for s in tracer.spans() if s["name"] == "prefill"}
+    assert [prefills[n] for n in lengths] == forms
+    samples = [(p, np.asarray(o["generated"])) for p, o in zip(prompts, outs)]
+    gaps = runner.served_gaps(cfg, 5, samples, "float32", row_multiple=64)
+    assert gaps["tokens_compared"] == 12
+    assert gaps["served_gap_max"] < 1e-4
+
+
 def test_one_continuous_predict_rows_job_end_to_end_with_its_counters():
     from tensorflowonspark_tpu import serving, serving_engine, telemetry
 
@@ -128,6 +165,8 @@ def test_one_continuous_predict_rows_job_end_to_end_with_its_counters():
     prefills = [s["attrs"] for s in spans if s["name"] == "prefill"]
     assert sorted(p["prompt_tokens"] for p in prefills) == [7, 16, 22, 30]
     assert sorted(p["bucket"] for p in prefills) == [8, 16, 24, 32]
+    # no block of the span kernel divides these buckets
+    assert {p["attn"] for p in prefills} == {"einsum"}
     counters = telemetry.get_registry().snapshot()["counters"]
     for name in ("moe_assignments", "moe_local_assignments",
                  "moe_experts_hit", "attn_read_tokens",

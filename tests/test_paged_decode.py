@@ -525,8 +525,18 @@ class TestBankKernelEngages:
             {"prompt": rng.randint(0, 64, (n,)).astype(np.int32)}
             for n in (3, 17, 40, 9, 150)
         ]
+        from tensorflowonspark_tpu import telemetry
+
+        tracer = telemetry.get_tracer()
+        tracer.clear()
         got, stats = _run(predict, rows)
         assert stats["attn"] == attn
+        # a prompt's span into K/V banks is masked dot attention
+        # whatever the decode step reads with
+        prefills = [s["attrs"] for s in tracer.spans()
+                    if s["name"] == "prefill"]
+        assert len(prefills) == 5
+        assert {p["attn"] for p in prefills} == {"dot"}
         slots, bank = 3, stats["kv_bank_tokens"]
         if how == "two_token_span":
             assert bank == slots * (384 + 2)  # the verify block's slack
