@@ -7,7 +7,7 @@ the official-models ``TimeHistory`` ``exp_per_second``, reference:
 examples/resnet/common.py:175-246).  Synthetic-input mode mirrors
 ``common.py:315-363``.
 
-Single-node it is the same workload as ``bench.py``; under
+Single-node it trains on the host's own chips; under
 ``--cluster_size N`` it runs through the cluster API with one mesh per
 node (DP over each node's chips, the multi-host axis via
 ``jax.distributed``).

@@ -17,7 +17,7 @@ through ``serving.predict_rows``:
 - ``--quantize int8`` composes weight-only int8 + the int8 KV cache
   with GQA (``--num_kv_heads``) and sliding-window attention
   (``--attention_window``) — the full decode-efficiency stack in one
-  serving config (measured: ``python bench.py serving_generate``);
+  serving config (its rate is not measured on the chip);
 - ``--schedule continuous`` runs the same requests through the
   slot-level in-flight scheduler instead of static batches: finished
   rows are evicted and waiting prompts admitted into the freed

@@ -20,7 +20,7 @@ Dispatch policies (:data:`DISPATCH_POLICIES`, pluggable by callable):
   ``imbalance`` ahead of the least loaded) it falls back to
   least-loaded (an ``affinity_spill``);
 - ``weighted_rr`` — deterministic smooth weighted round-robin;
-- ``random`` — seeded uniform pick (the bench's affinity baseline).
+- ``random`` — seeded uniform pick (what ``prefix_affinity`` is compared with).
 
 **Replica death** re-dispatches committed-token-safe: the dead
 replica's wreckage (finished-but-unemitted rows, per-request committed

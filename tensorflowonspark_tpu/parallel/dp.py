@@ -240,7 +240,7 @@ class SyncTrainer(object):
         (give it :meth:`batch_sharding`) so batch N+1's host→HBM DMA
         overlaps batch N's compute.  When per-step *dispatch* dominates
         (small/fast models), prefer :meth:`multi_step`, which amortizes
-        it K× — the structure bench.py uses."""
+        it K×."""
         return self._step_fn(state, device_batch, rng)
 
     def batch_sharding(self):

@@ -147,7 +147,7 @@ def send_msg(sock, header, tensors=None, codec=None):
     tensor ships as the codec's encoded parts and the per-tensor meta
     gains the codec header ``recv_msg`` decodes by.  Returns the total
     bytes laid on the wire (header + payloads) — the wire-traffic
-    accounting the wire tests and bench rows use.
+    accounting the wire tests use.
     """
     tensors = tensors or {}
     entries = []
@@ -955,9 +955,9 @@ class PSClient(object):
         ``handle.result()`` blocks for the replies and unshards.  The
         pipelined :class:`AsyncTrainer` uses this to overlap the round
         trip with the next gradient computation without an extra relay
-        thread (each hop in the wakeup chain costs a context switch —
-        measured on the bench model, a pool-thread relay ate the whole
-        overlap win).
+        thread (each hop in the wakeup chain costs a context switch,
+        and a pool-thread relay would sit between the shard workers and
+        the caller).
 
         ``header_extra`` merges extra JSON-able fields into every
         shard's push header — the hierarchical plane stamps its

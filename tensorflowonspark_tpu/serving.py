@@ -309,8 +309,8 @@ def predict_rows(
         docs/serving.md).
       stats: optional dict the continuous scheduler fills with
         per-request latency accounting (``latency_sec`` in input
-        order, plus admitted/evicted and robustness counters) — the
-        serving bench's p50/p99 source.  Cross-request reuse counters
+        order, plus admitted/evicted and robustness counters).
+        Cross-request reuse counters
         land here too: ``prefix_hits`` / ``prefix_tokens_saved`` /
         ``evictions`` / ``pressure_evictions`` when the export enables
         the prefix cache, and ``spec_accepted`` / ``spec_proposed`` /

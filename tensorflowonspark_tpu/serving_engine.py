@@ -99,8 +99,8 @@ def latency_summary(since=None):
 
     **This histogram is the authoritative percentile source** (docs/
     serving.md "Latency accounting").  Consumers that also keep raw
-    per-request lists (``stats["latency_sec"]``; the bench's
-    ``TFOS_TELEMETRY=0`` fallback) interpolate differently — a raw
+    per-request lists (``stats["latency_sec"]``, all there is under
+    ``TFOS_TELEMETRY=0``) interpolate differently — a raw
     list nearest-rank percentile vs the histogram's within-bucket
     linear interpolation — so the two agree only to the geometric
     bucket width (ratio 1.25, ~±12%; parity-tested at that tolerance

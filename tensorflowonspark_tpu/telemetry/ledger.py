@@ -188,8 +188,8 @@ class UsageLedger(object):
         self._mirror = {}  # (field, tenant) -> registry Counter
         #: tri-state override: None follows the registry's enabled
         #: flag (the TFOS_TELEMETRY story); True/False pins the
-        #: ledger independently (the bench isolates the ledger's own
-        #: increment this way)
+        #: ledger independently (so the ledger's own increment can
+        #: be isolated)
         self.enabled_override = None
 
     # -- enable story ---------------------------------------------------

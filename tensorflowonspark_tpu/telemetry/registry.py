@@ -480,8 +480,8 @@ def enabled():
 
 
 def set_enabled(flag):
-    """Flip the default registry AND tracer (tests, the bench's
-    instrumented-vs-disabled window).  Note: objects that cached a
+    """Flip the default registry AND tracer (tests, an
+    instrumented-vs-disabled comparison).  Note: objects that cached a
     NULL metric while disabled keep the null — set the flag before
     constructing the surfaces you want measured."""
     reg = get_registry()

@@ -1,8 +1,8 @@
 """Where this program keeps JAX's persistent compilation cache.
 
 One rule, applied by every process that can own a chip (the cluster's
-compute process, the serving CLI, ``bench.py``, ``chip_smoke.py``, the
-test session):
+compute process, the serving CLI, ``chip_smoke.py``, the benchmark's
+runners, the test session):
 
 - ``JAX_COMPILATION_CACHE_DIR`` set in the environment — by the
   operator, the CI job or the machine image — means the cache is

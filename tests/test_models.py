@@ -188,7 +188,7 @@ class TestTransformer:
         assert int(rounds) < 24  # strictly fewer forwards than tokens
 
     def test_ragged_generate_matches_per_row(self):
-        # ragged multi-request batching (VERDICT r4 #8): left-padded
+        # ragged multi-request batching: left-padded
         # rows with pad_start must generate exactly what each row's
         # unpadded prompt generates alone (greedy; RoPE scores depend
         # only on position differences, so physical-slot positions

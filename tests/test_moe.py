@@ -344,7 +344,7 @@ class TestDropless:
 
 
 class TestCapacityHonesty:
-    """The drop-rate honesty guard (VERDICT r5 weak #2): throughput
+    """The drop-rate honesty guard: throughput
     numbers taken at a capacity factor that drops >2% of token updates
     must say so, and the quality cost must be quantified somewhere a
     reader can check — the CF=1.0 vs CF=1.25 convergence smoke below."""
@@ -364,8 +364,7 @@ class TestCapacityHonesty:
                 0.121, capacity_factor=1.0, where="bench MoE"
             )
         assert msg is not None
-        # the annotation a bench row attaches must name the rate, the
-        # knob, and the fixes
+        # the annotation must name the rate, the knob, and the fixes
         assert "12.1%" in msg and "capacity_factor" in msg
         assert "dropless" in msg and "bench MoE" in msg
         assert any("drop_rate" in r.message for r in caplog.records)
@@ -408,8 +407,7 @@ class TestCapacityHonesty:
             params, opt_state, l = step(params, opt_state)
             last = float(l)
             first = last if first is None else first
-        # drop-rate telemetry on the trained router (the bench.py moe
-        # row reads the same sow)
+        # drop-rate telemetry on the trained router
         _, stats = model.apply(
             {"params": params}, tokens, mutable=["moe_stats"]
         )

@@ -465,7 +465,7 @@ class TestPipelineTrainer:
 
 
 class TestSchedules:
-    """Scheduled-ops trace tests (VERDICT r1 #9): 1F1B's activation
+    """Scheduled-ops trace tests: 1F1B's activation
     stash is O(P) where GPipe's is O(M), and the interleaved schedule
     has measurably fewer idle ticks; single-slot handoff buffers never
     overrun."""

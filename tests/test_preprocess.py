@@ -153,10 +153,13 @@ def test_prefetch_preprocess_skips_host_count():
         assert np.asarray(batch).dtype == np.float32
 
 
-def test_prefetch_host_prefetch_preserves_order_and_values():
+@pytest.mark.parametrize("host_prefetch", [False, True])
+def test_prefetch_host_prefetch_preserves_order_and_values(host_prefetch):
+    # the synchronous path and the background-thread path hand the
+    # consumer the same batches in the same order
     batches = [np.full((2, 2), i, np.uint8) for i in range(8)]
     out = list(prefetch_to_device(
-        iter(batches), size=2, host_prefetch=True
+        iter(batches), size=2, host_prefetch=host_prefetch
     ))
     assert len(out) == 8
     for i, b in enumerate(out):

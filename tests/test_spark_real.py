@@ -299,7 +299,7 @@ def test_estimator_fit_then_transform_on_spark(sc, tmp_path):
     model.setOutputMapping({"prediction": "pred"})
     model.engine = SparkEngine(sc)
     out = model.transform(test_df)
-    # native-DataFrame contract (VERDICT r4 'Missing' #1): a TYPED
+    # native-DataFrame contract: a TYPED
     # DataFrame evaluated lazily on the executors, schema derived from
     # the predictor (reference: TFModel.scala:294-335)
     assert hasattr(out, "schema"), "transform must return a DataFrame"
