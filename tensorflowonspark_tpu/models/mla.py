@@ -6,15 +6,18 @@ The attention of ``TransformerConfig(attention_kind="mla")``:
   head are one row: ``c_kv = RMSNorm(x W_kva[:, :r])`` (``r`` =
   ``kv_lora_rank``) beside one rotary key ``k_r = RoPE(x W_kva[:, r:])``
   shared by all heads; ``[k_nope | v] = c_kv W_kvb`` per head.  Queries
-  are low-rank too: ``q = RMSNorm(x W_qa) W_qb``, split into a part
-  that meets ``k_nope`` and a rotary part that meets ``k_r``.  The
+  are low-rank too: ``q = RMSNorm(x W_qa) W_qb`` — or, with
+  ``q_lora_rank == 0``, one projection ``q = x W_q`` — split into a
+  part that meets ``k_nope`` and a rotary part that meets ``k_r``.  The
   decode cache holds the ``r + rope`` wide row a token — the latent
   bank ``[slots, L, r + rope]``, its rows padded out to whole lanes
   (:func:`bank_width`) — never per-head keys and values.
 - **Two forms of one product.**  A span of tokens (training, prefill)
   expands ``k_nope`` and ``v`` once and attends per head (the
-  *non-absorbed* form).  A one-token decode step folds ``W_kvb`` into
-  the query and the output instead (*absorbed*): the score is
+  *non-absorbed* form; a training span with no index takes it through
+  the flash kernels, :func:`flash_span`).  A one-token decode step
+  folds ``W_kvb`` into the query and the output instead (*absorbed*):
+  the score is
   ``[q_nope W_k | q_rope] · [c_kv | k_r]`` straight against the bank,
   the context ``p · c_kv`` is expanded by ``W_v`` afterwards.
 - **The index** (layers of kind ``"full"``).  A small scorer,
@@ -89,6 +92,28 @@ def span_blocks(cfg, decode, span):
     from tensorflowonspark_tpu.ops import latent_attention
 
     return latent_attention.span_blocks(span)
+
+
+def flash_span(cfg, indexer, decode, pad_start, span):
+    """Whether a span's attention goes through the flash kernels
+    (``ops/flash_attention.py``: forward AND backward, the scores never
+    leave VMEM) as plain causal attention over ``nope + rope`` wide
+    queries and keys and ``v_head_dim`` wide values.  From what the
+    code can see, as :func:`span_blocks`: the configuration asks for
+    flash, the span is no cache fill (training or a plain forward:
+    every row runs positions 0.. with no pad region, which is what the
+    kernels' causal mask assumes), the layer attends over every
+    visible key (no index of its own, none shared), no mesh (GSPMD
+    does not partition a Pallas call, and the sharded wrapper splits
+    heads that the one rotary key is shared by) and a span the blocks
+    divide.  Otherwise :func:`span_attention`'s einsum form."""
+    if (cfg.attention_impl != "flash" or decode or indexer
+            or pad_start is not None or cfg.mesh is not None):
+        return False
+    from tensorflowonspark_tpu.ops.flash_attention import flash_supported
+
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    return flash_supported(scale, span, cfg.block_q, cfg.block_k)
 
 
 def topk_mask(scores, visible, k):
@@ -335,10 +360,23 @@ class MLAttention(nn.Module):
 
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
 
+        if self.indexer == "full" and not cfg.q_lora_rank:
+            raise ValueError(
+                "a layer that owns an index takes the index's queries "
+                "from the query latent: q_lora_rank must be set")
         with jax.named_scope("mla"):
-            c_q = norm("q_norm")(dense("q_a", cfg.q_lora_rank)(x))
-            w_qb = self.param(
-                "q_b", init, (cfg.q_lora_rank, h, dn + dr)).astype(dt)
+            if cfg.q_lora_rank:
+                c_q = norm("q_norm")(dense("q_a", cfg.q_lora_rank)(x))
+                w_qb = self.param(
+                    "q_b", init, (cfg.q_lora_rank, h, dn + dr)).astype(dt)
+            else:
+                # no query latent (the published ``q_lora_rank: null``):
+                # ONE projection ``q = x W_q``, no ``q_a``, no
+                # ``q_norm``; what follows reads ``x`` where it read
+                # the latent
+                c_q = x
+                w_qb = self.param(
+                    "q", init, (cfg.embed_dim, h, dn + dr)).astype(dt)
 
             def queries(c_q, pos):
                 # ``pos [S]`` (one row of positions for every batch
@@ -416,19 +454,46 @@ class MLAttention(nn.Module):
                         jnp.einsum("bsc,chd->bhsd", c_kv, w_kvb[..., dn:]),
                     )
 
-                ctx, mask = span_attention(
-                    c_q, queries, c_kv, keys, k_r,
-                    positions[0], pad_start, scale, index=index,
-                    sel=sel if self.indexer == "shared" else None,
-                    topk=cfg.index_topk,
-                    blocks=span_blocks(cfg, decode, s),
-                )
+                if flash_span(cfg, self.indexer, decode, pad_start, s):
+                    ctx, mask = self._flash_span(
+                        c_q, w_qb, c_kv, w_kvb, k_r, rot, scale), None
+                else:
+                    ctx, mask = span_attention(
+                        c_q, queries, c_kv, keys, k_r,
+                        positions[0], pad_start, scale, index=index,
+                        sel=sel if self.indexer == "shared" else None,
+                        topk=cfg.index_topk,
+                        blocks=span_blocks(cfg, decode, s),
+                    )
             sel = mask if self.indexer else None
         out = nn.DenseGeneral(
             cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=dt,
             name="out",
         )(ctx)
         return out, sel
+
+    def _flash_span(self, c_q, w_qb, c_kv, w_kvb, k_r, rot, scale):
+        """The non-absorbed form of a training span as plain causal
+        attention through the flash kernels, which have a backward:
+        ``q = [q_nope | RoPE(q_rope)]`` and ``k = [k_nope | k_r]`` (the
+        one rotary key written beside every head's) ``nope + rope``
+        wide, ``v`` ``v_head_dim`` wide, token-major as the kernels
+        take them.  Returns the context ``[B, S, H, dv]``."""
+        from tensorflowonspark_tpu.ops.flash_attention import (
+            flash_attention,
+        )
+
+        cfg = self.cfg
+        dn = cfg.qk_nope_head_dim
+        q = jnp.einsum("bsr,rhd->bshd", c_q, w_qb)
+        q = jnp.concatenate([q[..., :dn], rot(q[..., dn:])], axis=-1)
+        kv = jnp.einsum("bsc,chd->bshd", c_kv, w_kvb)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(
+                k_r[:, :, None], kv.shape[:3] + k_r.shape[-1:])], axis=-1)
+        return flash_attention(
+            q, k, kv[..., dn:], causal=True, scale=scale,
+            block_q=cfg.block_q, block_k=cfg.block_k)
 
     def _decode_step(self, bank, ibank, row, index, q_nope, q_rope, w_kvb,
                      pos, pad_start, sel, scale):

@@ -211,6 +211,10 @@ class MoEMLP(nn.Module):
 #: tokens :class:`SigmoidMoE` routes at a time (a 16k-token prompt's
 #: sorted copy of every token would be gigabytes)
 ROUTED_CHUNK = 2048
+#: what a training span of :class:`SigmoidMoE` sows beside
+#: ``held_choices``, and :func:`sigmoid_moe_loss_fn` hands out of the
+#: step as ``moe_<name>``, summed over the sparse layers
+MOE_STEP_COUNTS = ("local_assignments", "experts_hit", "rows_multiplied")
 
 
 class SigmoidMoE(nn.Module):
@@ -237,6 +241,19 @@ class SigmoidMoE(nn.Module):
     Sows ``moe_stats/held_choices``: ``[tokens, num_experts]`` int8,
     1 where the token chose that held expert (the serving engine
     counts assignments and experts hit from it).
+
+    ``differentiable`` (a training span: ``Block`` passes ``not
+    decode``) sends the three products through
+    :func:`..ops.gmm.grouped_matmul_live`, whose ``dx`` and ``dw`` skip
+    the dead tiles as the forward does, and sows three more integers,
+    each summed over the routing passes (:data:`MOE_STEP_COUNTS`):
+    ``local_assignments`` (routed rows that landed on held experts),
+    ``experts_hit`` (held experts a pass reached) and
+    ``rows_multiplied`` (live tiles x tile rows).  The gate's gradient
+    reaches ``router`` through the sigmoid and the normalisation over
+    the chosen ``k``; ``router_bias`` enters the choice alone and gets
+    none.  The serving path (``differentiable=False``) is the raw
+    forward kernel, as it was.
     """
 
     router_experts: int
@@ -250,7 +267,7 @@ class SigmoidMoE(nn.Module):
     dtype: str = "bfloat16"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, differentiable=False):
         from tensorflowonspark_tpu.ops import gmm
 
         e, held, first = self.router_experts, self.num_experts, (
@@ -272,6 +289,11 @@ class SigmoidMoE(nn.Module):
         wg = self.param("wg", init, (held, d, m)).astype(jdtype)
         wo = self.param("wo", init, (held, m, d)).astype(jdtype)
 
+        # the correction bias moves the choice and never the weight:
+        # it takes no gradient (stopped where it enters the choice; on
+        # the serving path there is none to stop)
+        choice_bias = jax.lax.stop_gradient(bias) if differentiable else bias
+
         def routed(xc):
             # a decode step's few rows pad to the smallest row tile,
             # a prompt's thousands to the MXU's
@@ -280,11 +302,15 @@ class SigmoidMoE(nn.Module):
                 xc, router.astype(xc.dtype),
                 preferred_element_type=jnp.float32))
             experts, gates = moe_ops.sigmoid_topk(
-                scores, bias.astype(jnp.float32), self.k, self.scaling)
+                scores, choice_bias.astype(jnp.float32), self.k,
+                self.scaling)
             lay = moe_ops.share_layout(experts, first, held, bm=bm)
             xs = moe_ops.dispatch_sorted(xc.astype(jdtype), lay)
 
             def mm(a, w):
+                if differentiable:
+                    return gmm.grouped_matmul_live(
+                        a, w, lay.tile_expert, lay.live_tiles, bm)
                 return gmm.gmm_call(
                     a, w, lay.tile_expert, bm=bm,
                     live_tiles=lay.live_tiles)
@@ -297,16 +323,34 @@ class SigmoidMoE(nn.Module):
                     (experts - first)[..., None] == jnp.arange(held)),
                 axis=1,
             )
-            return y, chose.astype(jnp.int8)
+            if not differentiable:
+                return y, chose.astype(jnp.int8)
+            counts = jnp.stack([
+                jnp.sum(lay.local.astype(jnp.int32)),
+                jnp.sum(jnp.any(chose, axis=0).astype(jnp.int32)),
+                lay.live_tiles[0] * bm,
+            ])
+            return y, chose.astype(jnp.int8), counts
 
         with jax.named_scope("moe"):
             c = ROUTED_CHUNK
             if g > c and g % c == 0:
-                y, chose = jax.lax.map(routed, xf.reshape(g // c, c, d))
-                y, chose = y.reshape(g, d), chose.reshape(g, held)
+                # a training span keeps a pass's tokens alone for the
+                # backward and routes the pass again there: the sorted
+                # copies and the gathered rows of every pass at once
+                # are gigabytes (static sizes, mostly dead tiles)
+                out = jax.lax.map(
+                    jax.checkpoint(routed) if differentiable else routed,
+                    xf.reshape(g // c, c, d))
+                y, chose = out[0].reshape(g, d), out[1].reshape(g, held)
+                counts = out[2].sum(0) if differentiable else None
             else:
-                y, chose = routed(xf)
+                y, chose, *rest = routed(xf)
+                counts = rest[0] if rest else None
             self.sow("moe_stats", "held_choices", chose)
+            if differentiable:
+                for name, count in zip(MOE_STEP_COUNTS, counts):
+                    self.sow("moe_stats", name, count)
             if self.shared_experts:
                 dense = lambda name, feats: nn.Dense(  # noqa: E731
                     feats, use_bias=False, dtype=jdtype, name=name)
@@ -326,6 +370,14 @@ MOE_LOGICAL_AXES_RULES = (
 )
 
 
+def _next_token_ce(logits, tokens):
+    """Mean cross-entropy of ``logits[:, t]`` against ``tokens[:, t +
+    1]``."""
+    logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
+    nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
+    return jnp.mean(nll)
+
+
 def moe_loss_fn(model, aux_weight=0.01):
     """Next-token CE + weighted MoE load-balance aux losses.
 
@@ -338,11 +390,7 @@ def moe_loss_fn(model, aux_weight=0.01):
         logits, variables = model.apply(
             {"params": params}, tokens, mutable=["losses"]
         )
-        targets = tokens[:, 1:]
-        logits = logits[:, :-1]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        ce = jnp.mean(nll)
+        ce = _next_token_ce(logits, tokens)
         aux_leaves = jax.tree.leaves(variables.get("losses", {}))
         aux = (
             sum(jnp.sum(a) for a in aux_leaves)
@@ -351,3 +399,51 @@ def moe_loss_fn(model, aux_weight=0.01):
         return ce + aux_weight * aux, {"ce": ce, "moe_aux": aux}
 
     return _loss
+
+
+def sigmoid_moe_loss_fn(model):
+    """Next-token cross-entropy for a model whose sparse layers are
+    :class:`SigmoidMoE` shares (they sow ``moe_stats``), over the
+    vocabulary rows the model holds.  Same contract as
+    ``transformer.loss_fn`` (batch = dict(tokens)), for a trainer built
+    with ``has_aux=True``: the aux is the step's integer counts
+    ``moe_local_assignments``, ``moe_experts_hit`` and
+    ``moe_rows_multiplied`` (:data:`MOE_STEP_COUNTS`), each summed over
+    the sparse layers.  No balance loss: the correction bias's update
+    and the sequence-wise balance loss are training procedure that the
+    program does not run (ROADMAP M3)."""
+    from flax import traverse_util
+
+    def _loss(params, batch, rng):
+        tokens = batch["tokens"]
+        logits, variables = model.apply(
+            {"params": params}, tokens, mutable=["moe_stats"]
+        )
+        sown = traverse_util.flatten_dict(variables.get("moe_stats", {}))
+        aux = {
+            "moe_" + name: sum(
+                jnp.sum(jnp.stack(v)) for path, v in sown.items()
+                if path[-1] == name)
+            for name in MOE_STEP_COUNTS
+        }
+        return _next_token_ce(logits, tokens), aux
+
+    return _loss
+
+
+def leave_router_bias(optimizer):
+    """``optimizer`` on every leaf but the routers' correction bias
+    (``.../router_bias``), which gets a zero update — no step, no
+    weight decay, no moments kept: the bias moves by its own balance
+    rule or not at all (:func:`..ops.moe.sigmoid_topk`), never by the
+    loss.  An optax mask for the call site that builds the trainer."""
+    import optax
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "frozen" if str(
+                getattr(path[-1], "key", path[-1])) == "router_bias"
+            else "trained", params)
+
+    return optax.multi_transform(
+        {"trained": optimizer, "frozen": optax.set_to_zero()}, labels)

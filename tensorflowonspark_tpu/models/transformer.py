@@ -618,7 +618,7 @@ class Block(nn.Module):
                 shared_experts=cfg.shared_experts,
                 dtype=cfg.dtype,
                 name="moe",
-            )(h)
+            )(h, differentiable=not decode)
         elif kind == "moe":
             from tensorflowonspark_tpu.models.moe import MoEMLP
 

@@ -375,7 +375,7 @@ def _fwd_core(qt, kt, vt, scale, causal, block_q, block_k, out_dtype=None,
     ``H % Hkv == 0`` — the kv BlockSpec index maps divide the q-head
     grid index by the group size, so each kv head's blocks stream to
     its whole query group with no repeated-kv materialization."""
-    b, h, s, d = qt.shape
+    (b, h, s, d), dv = qt.shape, vt.shape[-1]  # v's own head size
     g = h // kt.shape[1]
     bq, bk = _block_sizes(s, block_q, block_k)
     # windowed: stream only the band of kv blocks the horizon can
@@ -406,20 +406,20 @@ def _fwd_core(qt, kt, vt, scale, causal, block_q, block_k, out_dtype=None,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bk, d), _kv_idx),
-            pl.BlockSpec((1, 1, bk, d), _kv_idx),
+            pl.BlockSpec((1, 1, bk, dv), _kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), out_dtype or qt.dtype),
+            jax.ShapeDtypeStruct((b, h, s, dv), out_dtype or qt.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
         scratch_shapes=[
             _scratch((bq, 1), jnp.float32),  # running max
             _scratch((bq, 1), jnp.float32),  # running normalizer
-            _scratch((bq, d), jnp.float32),  # output accumulator
+            _scratch((bq, dv), jnp.float32),  # output accumulator
         ],
         interpret=compat.pallas_interpret(),
         compiler_params=_compiler_params(),
@@ -459,7 +459,7 @@ def _bwd_core(scale, causal, block_q, block_k, qt, kt, vt, dot_, lse,
     forward; dk/dv are computed PER QUERY HEAD (the q-head grid dim is
     parallel, so different group members must not write one kv block)
     and group-summed outside the kernel."""
-    b, h, s, d = qt.shape
+    (b, h, s, d), dvh = qt.shape, vt.shape[-1]  # v, dout, dv: dvh wide
     hkv = kt.shape[1]
     g = h // hkv
     bq, bk = _block_sizes(s, block_q, block_k)
@@ -488,8 +488,8 @@ def _bwd_core(scale, causal, block_q, block_k, qt, kt, vt, dot_, lse,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bk, d), _kv_idx),
-            pl.BlockSpec((1, 1, bk, d), _kv_idx),
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, dvh), _kv_idx),
+            pl.BlockSpec((1, 1, bq, dvh), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
         ],
@@ -524,16 +524,16 @@ def _bwd_core(scale, causal, block_q, block_k, qt, kt, vt, dot_, lse,
                 lambda bi, hi, kj, qi, g=g: (bi, hi // g, kj, 0),
             ),
             pl.BlockSpec(
-                (1, 1, bk, d),
+                (1, 1, bk, dvh),
                 lambda bi, hi, kj, qi, g=g: (bi, hi // g, kj, 0),
             ),
-            pl.BlockSpec((1, 1, bq, d), _q_idx),
+            pl.BlockSpec((1, 1, bq, dvh), _q_idx),
             pl.BlockSpec((1, 1, bq, 1), _q_idx),
             pl.BlockSpec((1, 1, bq, 1), _q_idx),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda bi, hi, kj, qi: (bi, hi, kj, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, kj, qi: (bi, hi, kj, 0)),
+            pl.BlockSpec((1, 1, bk, dvh), lambda bi, hi, kj, qi: (bi, hi, kj, 0)),
         ],
         out_shape=[
             # per-q-head partials stay f32 when they will be
@@ -543,12 +543,12 @@ def _bwd_core(scale, causal, block_q, block_k, qt, kt, vt, dot_, lse,
                 (b, h, s, d), jnp.float32 if g > 1 else kt.dtype
             ),
             jax.ShapeDtypeStruct(
-                (b, h, s, d), jnp.float32 if g > 1 else vt.dtype
+                (b, h, s, dvh), jnp.float32 if g > 1 else vt.dtype
             ),
         ],
         scratch_shapes=[
             _scratch((bk, d), jnp.float32),
-            _scratch((bk, d), jnp.float32),
+            _scratch((bk, dvh), jnp.float32),
         ],
         interpret=compat.pallas_interpret(),
         compiler_params=_compiler_params(),
@@ -557,7 +557,7 @@ def _bwd_core(scale, causal, block_q, block_k, qt, kt, vt, dot_, lse,
     if g > 1:
         # per-q-head f32 contributions -> kv heads, ONE final downcast
         dk = dk.reshape(b, hkv, g, s, d).sum(2).astype(kt.dtype)
-        dv = dv.reshape(b, hkv, g, s, d).sum(2).astype(vt.dtype)
+        dv = dv.reshape(b, hkv, g, s, dvh).sum(2).astype(vt.dtype)
     return dq, dk, dv
 
 
@@ -577,7 +577,7 @@ _flash.defvjp(_flash_fwd, _bwd)
 def flash_attention(q, k, v, causal=True, scale=None, block_q=1024,
                     block_k=1024, window=0):
     """Flash attention on ``[B, S, H, D]`` tensors (self-attention:
-    q/k/v share the sequence length).
+    q/k/v share the sequence length; ``v`` may be ``[B, S, Hkv, Dv]``).
 
     Grouped-query attention: k/v may carry ``Hkv`` heads with
     ``H % Hkv == 0`` (each kv head serves ``H/Hkv`` query heads) — the
@@ -594,7 +594,7 @@ def flash_attention(q, k, v, causal=True, scale=None, block_q=1024,
     1024x1024 default blocks measured fastest on v5e at S=2048 (+9%
     over 512x512; 2048-wide blocks overflow VMEM).
     """
-    if k.shape != v.shape:
+    if k.shape[:3] != v.shape[:3]:  # v's head size is its own
         raise ValueError(
             "k/v must match, got {0} {1}".format(k.shape, v.shape)
         )

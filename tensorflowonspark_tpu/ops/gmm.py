@@ -203,12 +203,33 @@ def _pick_bd(bm, d, f, bd, itemsize=2):
     return best
 
 
-def gmm_dxt_call(dy, w, tile_expert, *, bm=256, bd=None, interpret=None):
+def _gmm_dxt_live_kernel(te_ref, live_ref, dy_ref, w_ref, dx_ref):
+    # as _gmm_live_kernel: a dead tile is neither multiplied nor copied
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _():
+        _gmm_dxt_kernel(te_ref, dy_ref, w_ref, dx_ref)
+
+
+def _live_row(ti, scalars):
+    """Row-tile index a block map fetches for grid step ``ti``: itself,
+    or — where the scalars carry ``live_tiles`` — the last live tile's
+    for every dead one, so that nothing is copied for them (as
+    ``gmm_call``'s ``row``, which stays where it is: the serving
+    cell's kernel text holds its lines)."""
+    if len(scalars) == 1:
+        return ti
+    return jnp.maximum(jnp.minimum(ti, scalars[1][0] - 1), 0)
+
+
+def gmm_dxt_call(dy, w, tile_expert, *, bm=256, bd=None, interpret=None,
+                 live_tiles=None):
     """``dx[N, D] = dy[N, F] @ w[te].T`` reading ``w[E, D, F]`` in its
     STORED layout — the backward's input gradient without materializing
     ``swapaxes(w, 1, 2)`` (a full transposed weight copy in HBM every
     step; ADVICE r4 #4).  Returns None when no resident block exists
-    for this ``f`` (then the caller takes the transposed-copy path)."""
+    for this ``f`` (then the caller takes the transposed-copy path).
+    With ``live_tiles`` the rows of the dead tiles are left unwritten,
+    as :func:`gmm_call` leaves them."""
     if interpret is None:
         interpret = compat.pallas_interpret()
     n, f = dy.shape
@@ -220,25 +241,30 @@ def gmm_dxt_call(dy, w, tile_expert, *, bm=256, bd=None, interpret=None):
     bd = _pick_bd(bm, d, f, bd, itemsize=dy.dtype.itemsize)
     if not bd:
         return None
+    scalars = (tile_expert,) if live_tiles is None else (
+        tile_expert, live_tiles)
     grid_spec = _grid_spec(
-        1,
+        len(scalars),
         (d // bd, t),
         [
-            pl.BlockSpec((bm, f), lambda di, ti, te: (ti, 0)),
-            pl.BlockSpec((1, bd, f), lambda di, ti, te: (te[ti], di, 0)),
+            pl.BlockSpec(
+                (bm, f), lambda di, ti, *s: (_live_row(ti, s), 0)),
+            pl.BlockSpec(
+                (1, bd, f), lambda di, ti, *s: (s[0][ti], di, 0)),
         ],
-        pl.BlockSpec((bm, bd), lambda di, ti, te: (ti, di)),
+        pl.BlockSpec((bm, bd), lambda di, ti, *s: (_live_row(ti, s), di)),
     )
     return pl.pallas_call(
-        _gmm_dxt_kernel,
+        _gmm_dxt_kernel if live_tiles is None else _gmm_dxt_live_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, d), dy.dtype),
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(tile_expert, dy, w)
+        name="grouped_matmul_dx",
+    )(*scalars, dy, w)
 
 
-def _tgmm_kernel(te_ref, x_ref, dy_ref, dw_ref, acc_ref):
+def _tgmm_kernel(te_ref, x_ref, dy_ref, dw_ref, acc_ref, live_ref=None):
     ti = pl.program_id(2)
     nt = pl.num_programs(2)
     prev = jnp.maximum(ti - 1, 0)
@@ -248,9 +274,18 @@ def _tgmm_kernel(te_ref, x_ref, dy_ref, dw_ref, acc_ref):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...].T, dy_ref[...], preferred_element_type=jnp.float32
-    )
+    def _accumulate():
+        acc_ref[...] += jnp.dot(
+            x_ref[...].T, dy_ref[...], preferred_element_type=jnp.float32
+        )
+
+    if live_ref is None:
+        _accumulate()
+    else:
+        # the dead tiles repeat the last live tile's expert
+        # (``ops.moe.share_layout``): they add nothing to its sum, and
+        # the last of them flushes it
+        pl.when(ti < live_ref[0])(_accumulate)
     nxt = jnp.minimum(ti + 1, nt - 1)
     last = jnp.logical_or(ti == nt - 1, te_ref[nxt] != te_ref[ti])
 
@@ -259,8 +294,12 @@ def _tgmm_kernel(te_ref, x_ref, dy_ref, dw_ref, acc_ref):
         dw_ref[...] = acc_ref[...][None].astype(dw_ref.dtype)
 
 
+def _tgmm_live_kernel(te_ref, live_ref, x_ref, dy_ref, dw_ref, acc_ref):
+    _tgmm_kernel(te_ref, x_ref, dy_ref, dw_ref, acc_ref, live_ref)
+
+
 def tgmm_call(x, dy, tile_expert, num_experts, *, bm=256, bd=None,
-              bf=None, interpret=None):
+              bf=None, interpret=None, live_tiles=None):
     """``dw[E, D, F] = segment-sum over row tiles of x[t].T @ dy[t]``.
 
     The per-expert sum accumulates in an f32 VMEM scratch and flushes
@@ -270,7 +309,10 @@ def tgmm_call(x, dy, tile_expert, num_experts, *, bm=256, bd=None,
     a full-``D`` f32 accumulator at MoE widths exceeds the 16MB
     scoped-VMEM budget.  An expert that owns no row tile this batch
     never has its output block visited (uninitialized memory), so
-    absent experts are zeroed explicitly after the kernel.
+    absent experts are zeroed explicitly after the kernel.  With
+    ``live_tiles`` only the first so many row tiles are read and
+    multiplied (what the others hold is never looked at), and an expert
+    with no LIVE tile is absent.
     """
     if interpret is None:
         interpret = compat.pallas_interpret()
@@ -303,29 +345,37 @@ def tgmm_call(x, dy, tile_expert, num_experts, *, bm=256, bd=None,
             bf //= 2
     assert d % bd == 0, (d, bd)
     assert f % bf == 0, (f, bf)
+    scalars = (tile_expert,) if live_tiles is None else (
+        tile_expert, live_tiles)
     grid_spec = _grid_spec(
-        1,
+        len(scalars),
         (d // bd, f // bf, t),
         [
-            pl.BlockSpec((bm, bd), lambda di, fi, ti, te: (ti, di)),
-            pl.BlockSpec((bm, bf), lambda di, fi, ti, te: (ti, fi)),
+            pl.BlockSpec(
+                (bm, bd), lambda di, fi, ti, *s: (_live_row(ti, s), di)),
+            pl.BlockSpec(
+                (bm, bf), lambda di, fi, ti, *s: (_live_row(ti, s), fi)),
         ],
         pl.BlockSpec(
-            (1, bd, bf), lambda di, fi, ti, te: (te[ti], di, fi)
+            (1, bd, bf), lambda di, fi, ti, *s: (s[0][ti], di, fi)
         ),
         scratch_shapes=[pltpu.VMEM((bd, bf), jnp.float32)],
     )
     dw = pl.pallas_call(
-        _tgmm_kernel,
+        _tgmm_kernel if live_tiles is None else _tgmm_live_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_experts, d, f), x.dtype),
         compiler_params=_compiler_params(ndim=3),
         interpret=interpret,
-    )(tile_expert, x, dy)
+        name="grouped_matmul_dw",
+    )(*scalars, x, dy)
     # zero the rows of experts that own no tile this batch (their output
     # block was never visited and holds uninitialized memory)
+    owners = tile_expert if live_tiles is None else jnp.where(
+        jnp.arange(t) < live_tiles[0], tile_expert, num_experts)
     present = (
-        jnp.zeros((num_experts,), jnp.bool_).at[tile_expert].set(True)
+        jnp.zeros((num_experts,), jnp.bool_).at[owners].set(
+            True, mode="drop")
     )
     return jnp.where(present[:, None, None], dw, 0.0)
 
@@ -359,6 +409,40 @@ def _grouped_matmul_bwd(bm, bf, res, dy):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul_live(x, w, tile_expert, live_tiles, bm=256):
+    """:func:`grouped_matmul` over the first ``live_tiles`` row tiles
+    alone (``ops.moe.share_layout``: a chip's share of a layer sizes
+    its layout for every row landing here, and about ``held /
+    router_experts`` of them do).  Forward, ``dx`` and ``dw`` each skip
+    the dead tiles — no product, no copy — so their rows of ``y`` and
+    ``dx`` are never written and must never be read (the layout's
+    gathers never do), and what ``x`` and ``dy`` hold there is never
+    looked at; ``dw`` of an expert with no live tile is zero."""
+    return gmm_call(x, w, tile_expert, bm=bm, live_tiles=live_tiles)
+
+
+def _grouped_matmul_live_fwd(x, w, tile_expert, live_tiles, bm):
+    y = gmm_call(x, w, tile_expert, bm=bm, live_tiles=live_tiles)
+    return y, (x, w, tile_expert, live_tiles)
+
+
+def _grouped_matmul_live_bwd(bm, res, dy):
+    x, w, tile_expert, live_tiles = res
+    dx = gmm_dxt_call(dy, w, tile_expert, bm=bm, live_tiles=live_tiles)
+    if dx is None:
+        dx = gmm_call(dy, jnp.swapaxes(w, 1, 2), tile_expert, bm=bm,
+                      live_tiles=live_tiles)
+    dw = tgmm_call(
+        x, dy, tile_expert, w.shape[0], bm=bm, live_tiles=live_tiles
+    ).astype(w.dtype)
+    return dx, dw, None, None
+
+
+grouped_matmul_live.defvjp(
+    _grouped_matmul_live_fwd, _grouped_matmul_live_bwd)
 
 
 def gmm_reference(x, w, tile_expert, bm=256):

@@ -291,7 +291,11 @@ def sigmoid_topk(scores, bias, k, scaling=1.0):
     to the lower id; ``bias`` moves the choice and never the weight,
     which is the chosen expert's own score over the sum of the chosen
     scores, times ``scaling``.  ``scores [G, E]`` float32 in (0, 1).
-    Returns ``(experts [G, k] i32, gates [G, k] f32)``."""
+    Returns ``(experts [G, k] i32, gates [G, k] f32)``.  The gates
+    carry the gradient to ``scores`` (through the normalisation over
+    the chosen ``k``); ``bias`` enters the choice alone — integers, so
+    no gradient comes back through it, and a caller that trains stops
+    it outright (``models.moe.SigmoidMoE``)."""
     _, experts = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     gates = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling

@@ -422,8 +422,16 @@ class SyncTrainer(object):
                 # where a caller reads the step's metrics it waits for
                 # the step: the span separates that wait from the
                 # host's own part of the step
-                with tracer.span("train.callback", trace=trace_id):
+                with tracer.span("train.callback", trace=trace_id) as sp:
                     metrics_callback(steps, metrics)
+                    # a sigmoid-routed model's integer counts of the
+                    # step (``moe.sigmoid_moe_loss_fn``'s aux), read
+                    # after the callback has waited for the step;
+                    # absent for a model that sows nothing
+                    counts = {k: v for k, v in metrics.items()
+                              if k.startswith("moe_")}
+                    for key, value in jax.device_get(counts).items():
+                        sp.set(key, int(value))
             if (
                 checkpointer is not None
                 and checkpoint_every

@@ -1,0 +1,379 @@
+"""Latent attention and sigmoid-routed experts WITH A GRADIENT: the
+program's training span (``MLAttention`` with no query latent through
+the flash kernels, ``SigmoidMoE`` through the grouped matmul that skips
+dead tiles forward and backward) against the plain reference
+(``benchmarks/reference/mla_moe_train.py``) at small widths on seeded
+weights.
+
+Tolerances.  In float32 both sides sum in float32 and differ by the
+order of their sums (blocked scores, sorted expert rows): a leaf's
+gradient moves by a few 1e-6 of its largest entry, held to 5e-5; a
+token routed to ONE other expert moves expert leaves by 1e-2 and more.
+In bfloat16 the program rounds every activation to 8 bits of mantissa
+(2^-9 = 0.2% a value) and at these widths (64 columns, 128 tokens: a
+leaf's norm sums a few thousand rounded terms) a leaf's gradient NORM
+reads 0.5-3% off the float32 reference's; the band is 8% and the loss
+1%.  Pallas kernels run interpreted here, at their smallest blocks.
+"""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from benchmarks import compare
+from benchmarks import weights_mla_moe_train as weights
+from benchmarks.reference import mla_moe_train as ref
+from benchmarks.runners import train_mla_moe as runner
+from benchmarks.tests import faults_mla_moe_train as faults
+from tensorflowonspark_tpu.models import mla, moe
+from tensorflowonspark_tpu.models import transformer as tr
+from tensorflowonspark_tpu.ops import flash_attention as fa
+from tensorflowonspark_tpu.ops import gmm
+from tensorflowonspark_tpu.ops import moe as moe_ops
+
+TOL = 5e-5
+BF16_NORM_BAND = 0.08
+BF16_LOSS_BAND = 0.01
+
+#: a key-for-key miniature of the published configuration: 1 dense +
+#: 2 sparse layers, this chip experts 4-7 of 16
+TINY = dict(
+    hidden_size=64, num_attention_heads=4, q_lora_rank=None,
+    kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, intermediate_size=96, moe_intermediate_size=32,
+    n_shared_experts=2, num_experts_per_tok=3, n_routed_experts=4,
+    expert_share={"first": 4, "held": 4, "of": 16}, vocab_size=128,
+    num_hidden_layers=3, first_k_dense_replace=1, rope_theta=50000,
+    rms_norm_eps=1e-5, routed_scaling_factor=2.446,
+    scoring_func="sigmoid", dtype="float32",
+    program={"attention_impl": "flash", "block_q": 32, "block_k": 32,
+             "remat": False, "rope_interleave": True},
+)
+#: the cell rematerialises every block: so does the float32 case
+REMAT = dict(TINY, program=dict(TINY["program"], remat=True))
+ROWS, SEQ = 2, 64
+
+
+@pytest.fixture(autouse=True)
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def program_grads(cfg, params, tokens):
+    model = runner.program_model(cfg, SEQ)
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        moe.sigmoid_moe_loss_fn(model), has_aux=True))(
+            params, {"tokens": tokens}, None)
+    return float(loss), {k: int(v) for k, v in aux.items()}, grads
+
+
+@pytest.fixture(scope="module")
+def case():
+    """Seeded weights and rows, the reference's loss, gradient and
+    count, and the program's in float32."""
+    with jax.default_matmul_precision("highest"):
+        params = weights.make_params(TINY, 2 ** 31 + 3, jnp.float32)
+        tokens = np.stack([
+            np.random.default_rng([5, r]).integers(1, 128, SEQ)
+            for r in range(ROWS)]).astype(np.int32)
+        want = ref.loss_and_grads(params, tokens, TINY)
+        got = program_grads(REMAT, params, jnp.asarray(tokens))
+    return params, tokens, want, got
+
+
+def leaf_gaps(got, want):
+    """``{leaf: largest difference over the reference's largest
+    entry}``."""
+    return {
+        k: float(jnp.max(jnp.abs(g - w)) / (jnp.max(jnp.abs(w)) + 1e-12))
+        for (k, g), (_, w) in zip(compare.leaf_paths(got),
+                                  compare.leaf_paths(want))
+    }
+
+
+# -- (a), (b): the model against the reference --------------------------
+
+
+def test_loss_and_every_leaf_s_gradient_are_the_reference_s(case):
+    params, _, (loss, grads, local), (got_loss, aux, got) = case
+    assert jax.tree.structure(got) == jax.tree.structure(grads)
+    assert abs(got_loss - loss) < TOL * loss
+    gaps = leaf_gaps(got, grads)
+    bias = [k for k in gaps if k.endswith("router_bias")]
+    assert len(bias) == 2 and len(gaps) == len(jax.tree.leaves(grads))
+    worst = max((v, k) for k, v in gaps.items() if k not in bias)
+    assert worst[0] < TOL, worst
+    # no assignment dropped: the program's count IS the reference's
+    assert aux["moe_local_assignments"] == local > 0
+    assert aux["moe_rows_multiplied"] >= aux["moe_local_assignments"]
+    assert 0 < aux["moe_experts_hit"] <= 2 * 4
+
+
+def test_in_bfloat16_every_leaf_s_norm_is_within_rounding(case):
+    params, tokens, (loss, grads, _), _ = case
+    got_loss, _, got = program_grads(
+        dict(TINY, dtype="bfloat16"), params, jnp.asarray(tokens))
+    assert abs(got_loss - loss) < BF16_LOSS_BAND * loss
+    want = {k: v for k, v in compare.leaf_norms(grads).items()
+            if not k.endswith("router_bias")}
+    worst, leaf = compare.worst_leaf_gap(compare.leaf_norms(got), want)
+    assert 1e-4 < worst < BF16_NORM_BAND, (worst, leaf)
+
+
+# -- (c): the correction bias ------------------------------------------
+
+
+def test_the_router_s_bias_takes_no_gradient_and_no_step(case):
+    params, tokens, (_, ref_grads, _), (_, _, grads) = case
+    for i in (1, 2):
+        name = "block_%d" % i
+        assert not np.any(np.asarray(grads[name]["moe"]["router_bias"]))
+        assert not np.any(np.asarray(ref_grads[name]["moe"]["router_bias"]))
+        # the gate's gradient does reach the router
+        assert float(jnp.max(jnp.abs(grads[name]["moe"]["router"]))) > 1e-6
+    opt = moe.leave_router_bias(optax.adamw(1e-2, weight_decay=0.1))
+    state, p = opt.init(params), params
+    for _ in range(3):
+        # any gradient will do: even one that pushes the bias
+        g = jax.tree.map(jnp.ones_like, p)
+        updates, state = opt.update(g, state, p)
+        p = optax.apply_updates(p, updates)
+    for i in (1, 2):
+        was, now = (t["block_%d" % i]["moe"] for t in (params, p))
+        np.testing.assert_array_equal(
+            np.asarray(now["router_bias"]), np.asarray(was["router_bias"]))
+        assert float(jnp.max(jnp.abs(now["router"] - was["router"]))) > 1e-3
+
+
+# -- (d): dead tiles ----------------------------------------------------
+
+
+def test_the_backward_skips_dead_tiles_and_is_the_skip_nothing_backward():
+    rng, bm = np.random.default_rng(0), 8
+    g, k, held, first, d, f = 64, 3, 4, 4, 32, 48
+    scores = jnp.asarray(rng.uniform(size=(g, 16)), jnp.float32)
+    scores = scores.at[:, 5].set(-1.0)   # held expert 1: never chosen
+    experts, gates = moe_ops.sigmoid_topk(scores, jnp.zeros((16,)), k, 2.0)
+    lay = moe_ops.share_layout(experts, first, held, bm=bm)
+    tiles, live = lay.tile_expert.shape[0], int(lay.live_tiles[0])
+    assert 0 < live < tiles // 2
+    x = jnp.asarray(rng.normal(size=(g, d)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(held, d, f)), jnp.float32)
+    weigh = jnp.cos(jnp.arange(g * f, dtype=jnp.float32)).reshape(g, f)
+
+    def loss(mm, x, w):
+        ys = mm(moe_ops.dispatch_sorted(x, lay), w)
+        return jnp.sum(moe_ops.combine_share(ys, lay, gates) * weigh)
+
+    def skipping(xs, w):
+        return gmm.grouped_matmul_live(
+            xs, w, lay.tile_expert, lay.live_tiles, bm)
+
+    def nothing_skipped(xs, w):
+        return gmm.grouped_matmul(xs, w, lay.tile_expert, bm)
+
+    got = jax.grad(lambda x, w: loss(skipping, x, w), argnums=(0, 1))(x, w)
+    want = jax.grad(
+        lambda x, w: loss(nothing_skipped, x, w), argnums=(0, 1))(x, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+    assert not np.any(np.asarray(got[1][1]))       # no row: dw == 0
+    assert all(np.any(np.asarray(got[1][e])) for e in (0, 2, 3))
+    # what a dead tile holds is never looked at: poison it
+    dead = jnp.arange(tiles * bm) >= live * bm
+    xs = jnp.where(dead[:, None], jnp.nan, moe_ops.dispatch_sorted(x, lay))
+    dy = jnp.where(dead[:, None], jnp.nan, jnp.ones((tiles * bm, f)))
+    dw = gmm.tgmm_call(
+        xs, dy, lay.tile_expert, held, bm=bm, live_tiles=lay.live_tiles)
+    dx = gmm.gmm_dxt_call(
+        dy, w, lay.tile_expert, bm=bm, live_tiles=lay.live_tiles)
+    assert np.all(np.isfinite(np.asarray(dw)))
+    assert np.all(np.isfinite(np.asarray(dx)[: live * bm]))
+
+
+# -- (e): flash attention with a value head of its own ------------------
+
+
+def _einsum_attention(q, k, v, scale):
+    s = q.shape[1]
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    seen = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    probs = jax.nn.softmax(jnp.where(seen, logits, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+@pytest.mark.parametrize("dqk,dv,seq,block", [
+    (24, 16, 64, 32),      # the tiny model's head sizes
+    (192, 128, 256, 128),  # the published pair: whole lanes
+])
+def test_flash_with_its_own_value_head_is_the_einsum_form(
+        dqk, dv, seq, block):
+    ks = jax.random.split(jax.random.PRNGKey(dqk), 4)
+    q = jax.random.normal(ks[0], (1, seq, 2, dqk))
+    k = jax.random.normal(ks[1], (1, seq, 2, dqk))
+    v = jax.random.normal(ks[2], (1, seq, 2, dv))
+    cot = jax.random.normal(ks[3], (1, seq, 2, dv))
+    scale = dqk ** -0.5
+
+    def flash(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, scale=scale, block_q=block, block_k=block)
+
+    out, vjp = jax.vjp(flash, q, k, v)
+    want, want_vjp = jax.vjp(
+        lambda q, k, v: _einsum_attention(q, k, v, scale), q, k, v)
+    assert out.shape == (1, seq, 2, dv)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(want), rtol=0, atol=2e-5)
+    for got, ref_, like in zip(vjp(cot), want_vjp(cot), (q, k, v)):
+        assert got.shape == like.shape
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(ref_), rtol=0, atol=1e-4)
+
+
+#: sha256 of the traced program (forward and the three gradient
+#: kernels, their grids and block maps) of ``flash_attention`` at EQUAL
+#: head sizes, recorded from the parent commit (e82dcf5): the same
+#: characters are the same arithmetic, so the cells that train through
+#: these kernels at one head size get bit-equal numbers
+PARENT_FLASH_PROGRAMS = {
+    (0, 2): "6b501788adb5661d339b7cab95fd0c43db0af3b5100b4b52e53e8a3a0039622c",
+    (160, 1): "61d2666efe89d70944437e2c7f4ac0bfdc5795b741ee96b8e438d7dfab537ff1",
+}
+
+
+@pytest.mark.parametrize("window,hkv", sorted(PARENT_FLASH_PROGRAMS))
+def test_flash_at_equal_head_sizes_is_the_parent_s_program(window, hkv):
+    q = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, hkv, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128,
+            window=window).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k))
+    text = re.sub(r" at [^\s\]]+:\d+", "", text)   # no source positions
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        PARENT_FLASH_PROGRAMS[window, hkv])
+
+
+def test_flash_still_refuses_keys_and_values_of_different_spans():
+    q = jnp.zeros((1, 64, 2, 16))
+    with pytest.raises(ValueError, match="k/v must match"):
+        fa.flash_attention(q, q, jnp.zeros((1, 32, 2, 16)))
+
+
+# -- (f): the shares add up ---------------------------------------------
+
+
+def test_eight_shares_and_the_shared_experts_once_are_the_uncut_layer():
+    """Values AND input gradients: what the absent chips would add is
+    exactly the other shares' routed parts.  ONE traced program serves
+    the eight shares: share ``j`` is the layer with experts ``2j, 2j +
+    1`` rotated to the front of the router (``expert_first=0``); the
+    shares by ``expert_first`` itself are ``tests/test_mla_moe.py``'s
+    (forward) and the model's above (experts 4-7)."""
+    uncut = dict(TINY, n_routed_experts=16,
+                 expert_share={"first": 0, "held": 16, "of": 16})
+    whole = weights.block_params(
+        uncut, weights.seed_key(9), 2, jnp.float32)["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(2), (96, 64))
+    cot = jax.random.normal(jax.random.PRNGKey(3), (96, 64))
+    layer = moe.SigmoidMoE(
+        router_experts=16, num_experts=2, mlp_dim=32, embed_dim=64,
+        expert_first=0, k=3, scaling=2.446, shared_experts=2,
+        dtype="float32")
+
+    @jax.jit
+    def share(p, first):
+        held = dict(p, router=jnp.roll(p["router"], -first, axis=1),
+                    router_bias=jnp.roll(p["router_bias"], -first), **{
+            k: jax.lax.dynamic_slice_in_dim(p[k], first, 2)
+            for k in ("wi", "wg", "wo")})
+        y, vjp = jax.vjp(lambda x: layer.apply(
+            {"params": held}, x[None], differentiable=True)[0], x)
+        return y, vjp(cot)[0]
+
+    want, want_vjp = jax.vjp(
+        lambda x: ref.sparse_ffn(x, whole, uncut, "f32")[0], x)
+    once, once_vjp = jax.vjp(lambda x: ref.gated(
+        x, whole["shared_wi"]["kernel"], whole["shared_wg"]["kernel"],
+        whole["shared_wo"]["kernel"], "f32"), x)
+    total, dx = once, once_vjp(cot)[0]
+    for first in range(0, 16, 2):
+        part, dpart = share(whole, first)
+        total = total + (part - once)
+        dx = dx + (dpart - once_vjp(cot)[0])
+    assert float(jnp.max(jnp.abs(total - want))) < TOL
+    assert float(jnp.max(jnp.abs(dx - want_vjp(cot)[0]))) < TOL
+    assert float(jnp.max(jnp.abs(want - once))) > 1e-2
+
+
+# -- (g): no query latent ----------------------------------------------
+
+
+def test_without_a_query_latent_the_layer_builds_one_projection():
+    model = runner.program_model(TINY, SEQ)
+    shapes = jax.eval_shape(
+        lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, SEQ), jnp.int32)))
+    attn = shapes["params"]["block_1"]["attn"]
+    assert set(attn) == {"q", "kv_a", "kv_norm", "kv_b", "out"}
+    assert attn["q"].shape == (64, 4, 16 + 8)
+    assert mla.flash_span(model.cfg, "", False, None, SEQ)
+    assert not mla.flash_span(model.cfg, "", True, None, SEQ)
+    assert not mla.flash_span(model.cfg, "shared", False, None, SEQ)
+
+
+def test_an_index_layer_without_a_query_latent_is_refused():
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        runner.program_model(TINY, SEQ).cfg,
+        indexer_types=("full", "shared", "shared"), index_n_heads=2,
+        index_head_dim=16, index_topk=8)
+    with pytest.raises(ValueError, match="q_lora_rank must be set"):
+        tr.Transformer(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, SEQ), jnp.int32))
+
+
+# -- (h): planted faults ------------------------------------------------
+
+
+def _checks(got_loss, got_grads, want):
+    loss, grads, local = want
+    norms = compare.leaf_norms(grads)
+    return runner.checks_of(
+        [got_loss], compare.leaf_norms(got_grads), norms, [local], 0.0,
+        {"losses": [loss], "grad_norms": norms, "change_norms": norms,
+         "local_assignments": [local]},
+        [runner.LOSS_LIMIT, runner.GRAD_LIMIT, 1.0, 0.0])[0]
+
+
+def test_the_comparison_holds_for_the_program_as_it_is(case):
+    _, _, want, (got_loss, _, got) = case
+    checks = _checks(got_loss, got, want)
+    assert all(c["value"] <= c["limit"] for c in checks.values()), checks
+
+
+@pytest.mark.parametrize("fault", sorted(faults.FAULTS))
+def test_a_planted_fault_fails_the_comparison(case, fault, monkeypatch):
+    params, tokens, want, _ = case
+    # the faults patch these in place: put them back afterwards
+    monkeypatch.setattr(
+        moe, "sigmoid_moe_loss_fn", moe.sigmoid_moe_loss_fn)
+    monkeypatch.setattr(gmm, "gmm_dxt_call", gmm.gmm_dxt_call)
+    monkeypatch.setattr(gmm, "tgmm_call", gmm.tgmm_call)
+    faults.plant(fault)
+    got_loss, _, got = program_grads(TINY, params, jnp.asarray(tokens))
+    checks = _checks(got_loss, got, want)
+    assert checks["grad_norm_gap_worst_leaf"]["value"] > (
+        checks["grad_norm_gap_worst_leaf"]["limit"]), checks
