@@ -17,6 +17,7 @@ import logging
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from tensorflowonspark_tpu.ops import moe as moe_ops
 
@@ -208,13 +209,35 @@ class MoEMLP(nn.Module):
         return y.reshape(b, s, d).astype(x.dtype)
 
 
-#: tokens :class:`SigmoidMoE` routes at a time (a 16k-token prompt's
-#: sorted copy of every token would be gigabytes)
-ROUTED_CHUNK = 2048
+#: rows from which :class:`SigmoidMoE` takes a call for a span (a
+#: prompt, a training batch: row tiles of the MXU's 256, the sorted
+#: rows gone through a chunk at a time); fewer are a decode step's
+#: handful, one pass over tiles of 16
+SPAN_ROWS = 512
+#: most float32 bytes of the sorted rows a span moves and multiplies at
+#: a time (:func:`..ops.moe.share_span`).  A chunk is sized for the rows
+#: a balanced router sends here — ``tokens x k x held / experts`` and
+#: two row tiles a held expert, one for the rounding and one for a load
+#: that leans here: ONE chunk a layer then (a second costs ~1% of a v5e
+#: step, and seeds that met one now and then read that much apart) —
+#: and no larger than this.  Adding a chunk's rows to the tokens' costs
+#: a pass over the whole output besides the rows, so few large chunks
+#: beat many small ones (a v5e step of 16384 tokens x 2048: 663 ms at
+#: 4096 rows, 643 at 8192, 639 at 16384)
+SPAN_CHUNK_BYTES = 256 * 2 ** 20
+#: names (``jax.ad_checkpoint.checkpoint_name``) of a span's routing
+#: that a rematerialised block keeps for its backward
+#: (``Transformer``'s ``remat_policy="block"`` saves these and nothing
+#: else): the chosen experts and the sorted rows' pairs, 4 bytes a
+#: pair each (393 + 401 KB a Moonlight layer).  The backward then
+#: starts from the saved routing: no second top-k, no second sort — a
+#: fifth of what the span adds to a v5e step's executable
+SPAN_SAVED = ("moe_experts", "moe_pairs")
 #: what a training span of :class:`SigmoidMoE` sows beside
 #: ``held_choices``, and :func:`sigmoid_moe_loss_fn` hands out of the
 #: step as ``moe_<name>``, summed over the sparse layers
-MOE_STEP_COUNTS = ("local_assignments", "experts_hit", "rows_multiplied")
+MOE_STEP_COUNTS = ("local_assignments", "experts_hit", "rows_multiplied",
+                   "rows_moved")
 
 
 class SigmoidMoE(nn.Module):
@@ -232,28 +255,49 @@ class SigmoidMoE(nn.Module):
     elsewhere would add is theirs to add (over the all-to-all that one
     chip does not have); nothing here stands in for them.
 
-    Never a drop, at any number of tokens: the routed rows are sorted
-    by expert into row tiles (:func:`..ops.moe.share_layout`) and
-    multiplied by the grouped-matmul kernel, which skips the tiles no
-    row landed in.  Tokens go through :data:`ROUTED_CHUNK` at a time so
-    a long prompt's sorted copy stays small.
+    Never a drop, at any number of tokens, and no capacity.  A SPAN
+    (:data:`SPAN_ROWS` rows or more: a prompt, a training batch) is
+    routed ONCE: one layout over all of its ``(token, choice)`` pairs
+    (:func:`..ops.moe.span_layout`: one sort, each held expert's run
+    rounded up to row tiles of 256 once), of which only 1-D integers
+    are sized for every pair landing here, then a loop over the LIVE
+    prefix of the sorted rows a chunk at a time
+    (:func:`..ops.moe.share_span`, :data:`SPAN_CHUNK_BYTES`): the
+    chunk's rows of ``x`` gathered, the three grouped products on its
+    tiles, the gate applied a row in float32, the result added to the
+    tokens' rows.  The trip count is read from the layout, so the
+    routed part's device time follows the rows that landed on the held
+    experts and not ``tokens x k``; any span length goes through.  A
+    span reads its chosen scores by a masked sum over the experts
+    (``sigmoid_topk(masked_pick=True)``: the gathered numbers to the
+    bit), and names the chosen experts and the sorted rows' pairs
+    (:data:`SPAN_SAVED`) for a rematerialised block to keep.  A decode
+    step's handful of rows takes one pass sized for every choice
+    landing here, over row tiles of 16
+    (:func:`..ops.moe.share_layout`).
 
     Sows ``moe_stats/held_choices``: ``[tokens, num_experts]`` int8,
     1 where the token chose that held expert (the serving engine
     counts assignments and experts hit from it).
 
     ``differentiable`` (a training span: ``Block`` passes ``not
-    decode``) sends the three products through
-    :func:`..ops.gmm.grouped_matmul_live`, whose ``dx`` and ``dw`` skip
-    the dead tiles as the forward does, and sows three more integers,
-    each summed over the routing passes (:data:`MOE_STEP_COUNTS`):
-    ``local_assignments`` (routed rows that landed on held experts),
-    ``experts_hit`` (held experts a pass reached) and
-    ``rows_multiplied`` (live tiles x tile rows).  The gate's gradient
+    decode``) sows four more integers (:data:`MOE_STEP_COUNTS`), a
+    "pass" being one chunk of sorted rows: ``local_assignments``
+    (routed rows that landed on held experts), ``experts_hit`` (held
+    experts a chunk's tiles belong to, summed over the chunks: one
+    whose run straddles a chunk's edge is read twice and counts
+    twice), ``rows_multiplied`` (live tiles x tile rows) and
+    ``rows_moved`` (rows the chunks' gathers carried: chunks run x
+    chunk rows).  A span's backward is the same loop
+    (``share_span``'s own rule: a chunk's products are made again,
+    ``dw`` summed over the chunks in float32); the decode-sized pass
+    under a gradient sends its products through
+    :func:`..ops.gmm.grouped_matmul_live`, whose ``dx`` and ``dw``
+    skip the dead tiles as the forward does.  The gate's gradient
     reaches ``router`` through the sigmoid and the normalisation over
     the chosen ``k``; ``router_bias`` enters the choice alone and gets
-    none.  The serving path (``differentiable=False``) is the raw
-    forward kernel, as it was.
+    none.  The serving path's decode step (``differentiable=False``)
+    is the raw forward kernel, as it was.
     """
 
     router_experts: int
@@ -294,16 +338,27 @@ class SigmoidMoE(nn.Module):
         # the serving path there is none to stop)
         choice_bias = jax.lax.stop_gradient(bias) if differentiable else bias
 
-        def routed(xc):
-            # a decode step's few rows pad to the smallest row tile,
-            # a prompt's thousands to the MXU's
-            bm = 256 if xc.shape[0] >= 512 else 16
+        def route(xc, span=False):
             scores = jax.nn.sigmoid(jnp.dot(
                 xc, router.astype(xc.dtype),
                 preferred_element_type=jnp.float32))
-            experts, gates = moe_ops.sigmoid_topk(
+            return moe_ops.sigmoid_topk(
                 scores, choice_bias.astype(jnp.float32), self.k,
-                self.scaling)
+                self.scaling, masked_pick=span)
+
+        def chosen(experts, local):
+            return jnp.any(
+                jnp.logical_and(
+                    local[..., None],
+                    (experts - first)[..., None] == jnp.arange(held)),
+                axis=1,
+            )
+
+        def routed(xc):
+            # a decode step's handful of rows: one pass, sized for
+            # every choice landing here, over the smallest row tile
+            bm = 16
+            experts, gates = route(xc)
             lay = moe_ops.share_layout(experts, first, held, bm=bm)
             xs = moe_ops.dispatch_sorted(xc.astype(jdtype), lay)
 
@@ -317,40 +372,43 @@ class SigmoidMoE(nn.Module):
 
             ys = mm(nn.silu(mm(xs, wg)) * mm(xs, wi), wo)
             y = moe_ops.combine_share(ys, lay, gates, out_dtype=x.dtype)
-            chose = jnp.any(
-                jnp.logical_and(
-                    lay.local[..., None],
-                    (experts - first)[..., None] == jnp.arange(held)),
-                axis=1,
-            )
-            if not differentiable:
-                return y, chose.astype(jnp.int8)
-            counts = jnp.stack([
+            chose = chosen(experts, lay.local)
+            return y, chose, differentiable and (
                 jnp.sum(lay.local.astype(jnp.int32)),
                 jnp.sum(jnp.any(chose, axis=0).astype(jnp.int32)),
-                lay.live_tiles[0] * bm,
-            ])
-            return y, chose.astype(jnp.int8), counts
+                lay.live_tiles[0] * bm, xs.shape[0])
+
+        def span(xc):
+            # routed once, moved and multiplied a chunk of the sorted
+            # rows at a time: as many chunks as hold a row that landed
+            # here, whatever the span's length
+            bm = 256
+            n = xc.shape[0] * self.k
+            # a chunk: a balanced router's rows for the held experts
+            # and two tiles each, under the byte budget
+            rows = min(-(-n * held // e // bm) * bm + 2 * held * bm,
+                       max(SPAN_CHUNK_BYTES // (4 * d) // bm, 1) * bm)
+            experts, gates = route(xc, span=True)
+            experts = checkpoint_name(experts, SPAN_SAVED[0])
+            lay = moe_ops.span_layout(experts, first, held, bm, rows)
+            lay = lay._replace(
+                pairs=checkpoint_name(lay.pairs, SPAN_SAVED[1]))
+            y = moe_ops.share_span(
+                xc.astype(jdtype), gates, (wi, wg, wo), lay, bm, rows)
+            chose = chosen(experts, lay.local)
+            if not differentiable:
+                return y.astype(x.dtype), chose, None
+            chunks, hit = moe_ops.span_chunks(lay, bm, rows)
+            return y.astype(x.dtype), chose, (
+                jnp.sum(lay.local.astype(jnp.int32)), hit,
+                lay.live_tiles[0] * bm, chunks * rows)
 
         with jax.named_scope("moe"):
-            c = ROUTED_CHUNK
-            if g > c and g % c == 0:
-                # a training span keeps a pass's tokens alone for the
-                # backward and routes the pass again there: the sorted
-                # copies and the gathered rows of every pass at once
-                # are gigabytes (static sizes, mostly dead tiles)
-                out = jax.lax.map(
-                    jax.checkpoint(routed) if differentiable else routed,
-                    xf.reshape(g // c, c, d))
-                y, chose = out[0].reshape(g, d), out[1].reshape(g, held)
-                counts = out[2].sum(0) if differentiable else None
-            else:
-                y, chose, *rest = routed(xf)
-                counts = rest[0] if rest else None
-            self.sow("moe_stats", "held_choices", chose)
+            y, chose, counts = (span if g >= SPAN_ROWS else routed)(xf)
+            self.sow("moe_stats", "held_choices", chose.astype(jnp.int8))
             if differentiable:
                 for name, count in zip(MOE_STEP_COUNTS, counts):
-                    self.sow("moe_stats", name, count)
+                    self.sow("moe_stats", name, jnp.asarray(count))
             if self.shared_experts:
                 dense = lambda name, feats: nn.Dense(  # noqa: E731
                     feats, use_bias=False, dtype=jdtype, name=name)

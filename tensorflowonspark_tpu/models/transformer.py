@@ -720,10 +720,16 @@ class Transformer(nn.Module):
             # remat is a training trade (recompute in backward); decode
             # has no backward, and the wrapped call must not see the
             # python-bool flag (jax.checkpoint would try to trace it)
+            # "block" keeps the block's input and, of a sigmoid-routed
+            # span, its routing's integers (a block that names nothing
+            # saves nothing, as under no policy at all)
+            from tensorflowonspark_tpu.models.moe import SPAN_SAVED
+
             policy = (
                 jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                 if cfg.remat_policy == "dots"
-                else None
+                else jax.checkpoint_policies.save_only_these_names(
+                    *SPAN_SAVED)
             )
             block = nn.remat(Block, static_argnums=(), policy=policy)
             sel = None

@@ -16,6 +16,7 @@ Switch behavior); the auxiliary load-balancing loss pushes the router
 toward uniform load so drops stay rare.
 """
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -285,7 +286,7 @@ def combine_sorted(ys, layout, gates, out_dtype=None):
     return y if out_dtype is None else y.astype(out_dtype)
 
 
-def sigmoid_topk(scores, bias, k, scaling=1.0):
+def sigmoid_topk(scores, bias, k, scaling=1.0, masked_pick=False):
     """Bias-corrected top-k over sigmoid scores (``noaux_tc``): the
     ``k`` experts with the largest ``scores + bias`` are chosen, ties
     to the lower id; ``bias`` moves the choice and never the weight,
@@ -295,9 +296,21 @@ def sigmoid_topk(scores, bias, k, scaling=1.0):
     carry the gradient to ``scores`` (through the normalisation over
     the chosen ``k``); ``bias`` enters the choice alone — integers, so
     no gradient comes back through it, and a caller that trains stops
-    it outright (``models.moe.SigmoidMoE``)."""
+    it outright (``models.moe.SigmoidMoE``).
+
+    ``masked_pick`` reads the chosen scores as a sum over the experts
+    under the choice's mask — the same numbers to the bit — where the
+    default gathers them: over a span's thousands of rows the gather
+    and the scatter-add that is its transpose are each 1.5 MB of a
+    v5e program and milliseconds of a step, the masked sum one fused
+    pass either way."""
     _, experts = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
-    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    if masked_pick:
+        chosen = jnp.sum(
+            jnp.where(experts[..., None] == jnp.arange(scores.shape[-1]),
+                      scores[:, None, :], 0.0), axis=-1)
+    else:
+        chosen = jnp.take_along_axis(scores, experts, axis=-1)
     gates = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
     return experts.astype(jnp.int32), gates
 
@@ -380,6 +393,220 @@ def combine_share(ys, layout, gates, out_dtype=None):
         axis=1,
     )
     return y.astype(ys.dtype if out_dtype is None else out_dtype)
+
+
+class SpanLayout(NamedTuple):
+    """:class:`ShareLayout` of a whole span for :func:`share_span`:
+    every ``(token, choice)`` pair that landed on a held expert in ONE
+    sorted, tile-aligned order — by expert and then by token, each
+    expert's run rounded up to whole row tiles — as 1-D integers: no
+    array of rows is built for it."""
+
+    #: [NP] i32: sorted row -> its pair ``token * k + choice``
+    #: (sentinel ``G * k``: a pad row, or a row of a tile past the live
+    #: ones); NP is a whole number of chunks
+    pairs: jnp.ndarray
+    #: [NP // bm] i32: row tile -> owning held expert, as
+    #: :class:`ShareLayout` has it
+    tile_expert: jnp.ndarray
+    #: [1] i32: row tiles that hold a routed row (a prefix)
+    live_tiles: jnp.ndarray
+    #: [G, k] bool: the choice is an expert held here
+    local: jnp.ndarray
+
+
+def span_layout(experts, first, held, bm, chunk_rows):
+    """:func:`share_layout` over ALL of a span's pairs at once: one
+    stable sort by held expert (elsewhere last), each held expert's run
+    rounded up to whole row tiles ONCE, and every sorted row's pair read
+    from the sorted order here, once a layer (a loop that worked its
+    chunk's pairs out for itself was traced, lowered and run three
+    times a layer).  Only 1-D integers are sized for every pair landing
+    here."""
+    g, k = experts.shape
+    n = g * k
+    ef = experts.reshape(-1).astype(jnp.int32)
+    local = jnp.logical_and(ef >= first, ef < first + held)
+    el = jnp.where(local, ef - first, held)
+    counts = jnp.sum(
+        el[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None],
+        axis=1, dtype=jnp.int32)
+    padded = ((counts + bm - 1) // bm) * bm
+    ends = jnp.cumsum(padded).astype(jnp.int32)
+    np_rows = ((n + bm - 1) // bm) * bm + held * bm
+    np_rows = -(-np_rows // chunk_rows) * chunk_rows
+    # a tile's expert: the runs that end at or before its first row
+    # (:func:`share_layout`'s search as one comparison: a prefill program
+    # a bucket and a layer each trace and lower this, and the search's
+    # scan was the dearest line of it); the tiles past the live ones
+    # repeat the last live tile's expert, as there
+    live_tiles = ends[-1:] // bm
+    tile = jnp.arange(np_rows // bm, dtype=jnp.int32)
+    tile_expert = jnp.minimum(
+        jnp.sum(ends[None, :] <= tile[:, None] * bm, axis=1,
+                dtype=jnp.int32), held - 1)
+    tile_expert = jnp.where(
+        tile < live_tiles[0], tile_expert,
+        tile_expert[jnp.maximum(live_tiles[0] - 1, 0)])
+    order = jnp.argsort(el, stable=True).astype(jnp.int32)
+    # sorted row s of expert e's run is pair order[unaligned[e] + s -
+    # starts[e]] while that offset is under counts[e]; a dead tile
+    # repeats the last live expert's id and lies past its run, pads
+    # included: its offsets are over the count
+    e = jnp.repeat(tile_expert, bm)
+    unaligned = (jnp.cumsum(counts) - counts).astype(jnp.int32)
+    r = jnp.arange(np_rows, dtype=jnp.int32) - (ends - padded)[e]
+    pairs = jnp.where(
+        r < counts[e], order[jnp.minimum(unaligned[e] + r, n - 1)], n)
+    return SpanLayout(pairs=pairs, tile_expert=tile_expert,
+                      live_tiles=live_tiles, local=local.reshape(g, k))
+
+
+def span_chunks(layout, bm, chunk_rows):
+    """``(chunks, experts_hit)`` of a :class:`SpanLayout`: the chunks
+    of ``chunk_rows`` sorted rows that hold a live tile (the trip count
+    of :func:`share_span`'s loops), and the held experts a chunk's live
+    tiles belong to, summed over the chunks — an expert whose run
+    straddles a chunk's edge is read by both and counts twice."""
+    per = chunk_rows // bm
+    live = layout.live_tiles[0]
+    tile = jnp.arange(layout.tile_expert.shape[0], dtype=jnp.int32)
+    owner = jnp.where(tile < live, layout.tile_expert, -1).reshape(-1, per)
+    # a live prefix's owners never fall: a chunk's experts are its
+    # first live tile's and one more wherever the owner changes
+    fresh = jnp.concatenate(
+        [owner[:, :1] >= 0,
+         jnp.logical_and(owner[:, 1:] >= 0, owner[:, 1:] != owner[:, :-1])],
+        axis=1)
+    return _live_chunks(layout, bm, chunk_rows), jnp.sum(
+        fresh.astype(jnp.int32))
+
+
+def _live_chunks(layout, bm, chunk_rows):
+    per = chunk_rows // bm
+    return (layout.live_tiles[0] + per - 1) // per
+
+
+def _act(hg, hi):
+    return jax.nn.silu(hg) * hi
+
+
+def _span_chunk(c, x, gates, weights, layout, bm, chunk_rows):
+    """Chunk ``c`` of the sorted rows: its pairs, tokens and gates, its
+    rows of ``x``, and the three products over its live tiles.  Pad
+    rows read zeros and weigh nothing; the rows of the tiles past the
+    live ones are never written (``gmm``'s live kernels) and never
+    read back: their token is the sentinel, which every scatter drops
+    and every weight masks."""
+    from tensorflowonspark_tpu.ops import gmm
+
+    wi, wg, wo = weights
+    per = chunk_rows // bm
+    pair = jax.lax.dynamic_slice(
+        layout.pairs, (c * chunk_rows,), (chunk_rows,))
+    te = jax.lax.dynamic_slice(layout.tile_expert, (c * per,), (per,))
+    live = jnp.clip(layout.live_tiles - c * per, 0, per)
+    tok = pair // layout.local.shape[1]
+    gate = jnp.take(gates.reshape(-1), pair, mode="fill", fill_value=0)
+    xs = jnp.take(x, tok, axis=0, mode="fill", fill_value=0)
+    mm = lambda a, w: gmm.gmm_call(  # noqa: E731
+        a, w, te, bm=bm, live_tiles=live)
+    hi, hg = mm(xs, wi), mm(xs, wg)
+    return dict(pair=pair, tok=tok, gate=gate, te=te, live=live, xs=xs,
+                hi=hi, hg=hg, ys=mm(_act(hg, hi), wo))
+
+
+def _share_span(x, gates, weights, layout, bm, chunk_rows):
+    g, d = x.shape
+    chunks = _live_chunks(layout, bm, chunk_rows)
+    n = layout.local.size
+
+    def body(c, y):
+        ch = _span_chunk(c, x, gates, weights, layout, bm, chunk_rows)
+        rows = jnp.where(
+            (ch["pair"] < n)[:, None],
+            ch["ys"].astype(jnp.float32) * ch["gate"][:, None], 0.0)
+        return y.at[ch["tok"]].add(rows, mode="drop")
+
+    y = jax.lax.fori_loop(0, chunks, body, jnp.zeros((g, d), jnp.float32))
+    return y.astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def share_span(x, gates, weights, layout, bm, chunk_rows):
+    """This chip's part of a span's routed sum, ``y[g] = sum over the
+    LOCAL choices k of gates[g, k] · expert(x[g])``, moving only the
+    rows that land here: a loop over the live prefix of the sorted rows
+    (:func:`span_layout`) in chunks of ``chunk_rows`` — gather the
+    chunk's rows of ``x``, the three grouped products on its live
+    tiles, the gate applied a row in float32, the result added to the
+    tokens' rows — under a trip count read from the layout, so the
+    device time follows the rows that landed on the held experts and
+    not ``G · k``.  ``x [G, D]``, ``gates [G, k]`` float32, ``weights =
+    (wi, wg, wo)`` stacked over the held experts.
+
+    The backward is the same loop: a chunk's products are made again,
+    ``dx`` and the gathered cotangent carry ``chunk_rows`` rows a
+    chunk (no cotangent of a dead row is added anywhere), and ``dw``
+    sums over the chunks in float32 (an expert's run may straddle a
+    chunk's edge).  The layout's integers take no gradient."""
+    return _share_span(x, gates, weights, layout, bm, chunk_rows)
+
+
+def _share_span_fwd(x, gates, weights, layout, bm, chunk_rows):
+    y = _share_span(x, gates, weights, layout, bm, chunk_rows)
+    return y, (x, gates, weights, layout)
+
+
+def _share_span_bwd(bm, chunk_rows, res, dy):
+    from tensorflowonspark_tpu.ops import gmm
+
+    x, gates, weights, layout = res
+    wi, wg, wo = weights
+    held = wi.shape[0]
+    chunks = _live_chunks(layout, bm, chunk_rows)
+    n = layout.local.size
+
+    def body(c, carry):
+        dx, dgate, dwi, dwg, dwo = carry
+        ch = _span_chunk(c, x, gates, weights, layout, bm, chunk_rows)
+        te, live, xs = ch["te"], ch["live"], ch["xs"]
+        dyr = jnp.take(dy, ch["tok"], axis=0, mode="fill",
+                       fill_value=0).astype(jnp.float32)
+        dgate = dgate.at[ch["pair"]].add(
+            jnp.sum(ch["ys"].astype(jnp.float32) * dyr, axis=-1),
+            mode="drop")
+        dys = (dyr * ch["gate"][:, None]).astype(xs.dtype)
+        a, act_vjp = jax.vjp(_act, ch["hg"], ch["hi"])
+
+        def dxt(dz, w):
+            out = gmm.gmm_dxt_call(dz, w, te, bm=bm, live_tiles=live)
+            if out is None:
+                out = gmm.gmm_call(dz, jnp.swapaxes(w, 1, 2), te, bm=bm,
+                                   live_tiles=live)
+            return out
+
+        def dw(acc, a, dz):
+            return acc + gmm.tgmm_call(
+                a, dz, te, held, bm=bm, live_tiles=live
+            ).astype(jnp.float32)
+
+        dhg, dhi = act_vjp(dxt(dys, wo))
+        dxs = dxt(dhg, wg) + dxt(dhi, wi)
+        dx = dx.at[ch["tok"]].add(dxs.astype(jnp.float32), mode="drop")
+        return (dx, dgate, dw(dwi, xs, dhi), dw(dwg, xs, dhg),
+                dw(dwo, a, dys))
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)  # noqa: E731
+    dx, dgate, dwi, dwg, dwo = jax.lax.fori_loop(0, chunks, body, (
+        zeros(x), jnp.zeros((n,), jnp.float32),
+        zeros(wi), zeros(wg), zeros(wo)))
+    return (dx.astype(x.dtype), dgate.reshape(gates.shape).astype(
+        gates.dtype), (dwi.astype(wi.dtype), dwg.astype(wg.dtype),
+                       dwo.astype(wo.dtype)), None)
+
+
+share_span.defvjp(_share_span_fwd, _share_span_bwd)
 
 
 def expert_capacity(num_tokens, num_experts, capacity_factor=1.25, k=2):

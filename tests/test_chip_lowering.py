@@ -301,3 +301,91 @@ def test_latent_prefill_layer_compiles_for_v5e_with_its_scores_in_vmem(
               if int(m) >= 2048]
     assert scores == []
     assert compiled.memory_analysis().temp_size_in_bytes < 3.45e9
+
+
+#: caps of the two routed-span programs below: what PR 35's builder
+#: read on 2026-10-04 (jax 0.9.0 / jaxlib 0.9.0 / libtpu 0.0.34) + 5%
+MOONLIGHT_LAYER_TEMP, MOONLIGHT_LAYER_CODE = 1.152e9, 26.0e6
+GLM_LAYER_TEMP, GLM_LAYER_CODE = 1.2e9, 14.5e6
+
+
+@pytest.mark.parametrize(
+    "name,tokens,d,m,k,held,experts,shared,grad,temp_cap,code_cap", [
+        # one sparse layer of the training cell: 2 rows of 8192, top-6
+        # of 64 with 8 held, 2 shared; forward and backward under the
+        # block's remat, which keeps the routing's integers.  Read:
+        # temporaries 1.097 GB, generated code 24.75 MB (27.39 MB
+        # where the block saved nothing and a chunk worked out its
+        # own rows' pairs)
+        ("moonlight-train", 16384, 2048, 1408, 6, 8, 64, 2, True,
+         MOONLIGHT_LAYER_TEMP, MOONLIGHT_LAYER_CODE),
+        # a sparse layer of the serving cell's 12288-token prefill
+        # bucket: top-8 of 256, 16 held, 1 shared.  Read: temporaries
+        # 1.141 GB, generated code 13.76 MB
+        ("glm-prefill", 12288, 6144, 2048, 8, 16, 256, 1, False,
+         GLM_LAYER_TEMP, GLM_LAYER_CODE),
+    ])
+def test_a_routed_span_compiles_for_v5e_small_and_with_no_row_of_every_pair(
+        mosaic, name, tokens, d, m, k, held, experts, shared, grad,
+        temp_cap, code_cap):
+    """``SigmoidMoE``'s span (``ops.moe.span_layout`` +
+    ``share_span``) at the two cells' widths: the loops over the live
+    chunks and the three kernels are in the program, nothing with
+    ``d`` or ``m`` columns has more rows than the span has tokens —
+    only 1-D integers are sized for every (token, choice) pair landing
+    here — and the executable stays small: ``setup_s`` loads it on
+    every run, and PR 34's form of this span lost its gain to 43 MB
+    more of a step's code (caps: what PR 35's builder read + 5%)."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from tensorflowonspark_tpu.models import moe
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
+        pytest.skip("no TPU AOT topology here: %s" % e)
+    dev = SingleDeviceSharding(topo.devices[0])
+    layer = moe.SigmoidMoE(
+        router_experts=experts, num_experts=held, mlp_dim=m, embed_dim=d,
+        k=k, scaling=2.5, shared_experts=shared, dtype="bfloat16")
+    x = jax.ShapeDtypeStruct((1, tokens, d), jnp.bfloat16, sharding=dev)
+    shapes = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, d), jnp.bfloat16)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype if grad else jnp.bfloat16, sharding=dev),
+        shapes["params"])
+
+    def forward(p, x):
+        return layer.apply({"params": p}, x, differentiable=grad)
+
+    if grad:
+        block = jax.checkpoint(
+            forward, policy=jax.checkpoint_policies.save_only_these_names(
+                *moe.SPAN_SAVED))
+        # the value too, as a step returns its loss: the forward
+        # runs, then the block again for the backward
+        step = jax.value_and_grad(lambda p, x: block(p, x).astype(
+            jnp.float32).sum(), argnums=(0, 1))
+    else:
+        step = forward
+    compiled = jax.jit(step).lower(params, x).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(
+        r"%(grouped_matmul(?:_dx|_dw)?)(?:\.\d+)? = ", text))
+    assert kernels == ({"grouped_matmul", "grouped_matmul_dx",
+                        "grouped_matmul_dw"} if grad
+                       else {"grouped_matmul"}), kernels
+    assert " while(" in text
+    wide = [int(rows) for rows, cols in re.findall(
+        r"(?:bf16|f32)\[(\d+),(\d+)\]", text) if int(cols) in (d, m)]
+    assert wide and max(wide) == tokens, max(wide)
+    plan = compiled.memory_analysis()
+    print("%s: temporaries %.3f GB, generated code %.2f MB" % (
+        name, plan.temp_size_in_bytes / 1e9,
+        plan.generated_code_size_in_bytes / 1e6))
+    assert plan.temp_size_in_bytes < temp_cap
+    assert plan.generated_code_size_in_bytes < code_cap

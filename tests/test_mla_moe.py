@@ -457,13 +457,14 @@ def test_no_assignment_is_dropped_at_batch_one_and_at_a_full_bucket(
         tokens, monkeypatch):
     # every token's routed sum equals the plain per-expert sum, also
     # when EVERY token picks the same held experts (a router no
-    # capacity could take) and when the tokens go through in chunks
+    # capacity could take) and when the sorted rows go through in
+    # chunks (640 tokens are a span: 1920 rows, all local, 5 chunks)
     cfg = model_dict()
     p = weights.block_params(
         cfg, weights.seed_key(4), 1, jnp.float32)["moe"]
     p = dict(p, router_bias=p["router_bias"].at[4:7].set(5.0))
     x = jax.random.normal(jax.random.PRNGKey(1), (1, tokens, 64))
-    monkeypatch.setattr(moe, "ROUTED_CHUNK", 128)
+    monkeypatch.setattr(moe, "SPAN_CHUNK_BYTES", 512 * 64 * 4)
     got, stats = _moe_layer(4, 4).apply(
         {"params": p}, x, mutable=["moe_stats"])
     want = ref.sparse_ffn(x[0], p, cfg, "f32")

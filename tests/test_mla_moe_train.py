@@ -198,6 +198,276 @@ def test_the_backward_skips_dead_tiles_and_is_the_skip_nothing_backward():
     assert np.all(np.isfinite(np.asarray(dx)[: live * bm]))
 
 
+# -- (d'): a span routed once, a chunk of the sorted rows at a time ------
+
+
+def _span_layer():
+    return moe.SigmoidMoE(
+        router_experts=16, num_experts=4, expert_first=4, mlp_dim=32,
+        embed_dim=64, k=3, scaling=2.446, shared_experts=2,
+        dtype="float32")
+
+
+def _plain_share(p, x):
+    """The reference's plain per-expert sum — every held expert over
+    EVERY token, weighed by the token's gate for it (zero where it did
+    not choose it), the shared experts once — and ``[tokens, 16]``
+    bool: the token chose that expert."""
+    return (ref.sparse_ffn(x, p, TINY, "f32")[0],
+            np.asarray(ref.route(x, p, TINY, "f32")[1]))
+
+
+#: name -> (tokens, experts the router's bias lifts over the others)
+SPANS = {
+    # the worst case: all 1920 choices land here, 640 rows an expert
+    # (three tiles: a run straddles a chunk's edge), every chunk runs
+    "every_choice_local": (640, (4, 5, 6)),
+    # no chunk runs: the shared expert's part alone
+    "nothing_local": (640, (0, 1, 2)),
+    # experts 4 and 5 take every token (runs of 640 rows over chunks
+    # of 512), 6 and 7 what the third choice sends them
+    "a_run_straddles_a_chunk_edge": (640, (4, 5)),
+    # a span that is a multiple of no tile and no chunk, as routed
+    "an_odd_span_length": (777, ()),
+}
+
+
+@pytest.fixture(params=sorted(SPANS))
+def span_case(request, monkeypatch):
+    monkeypatch.setattr(moe, "SPAN_CHUNK_BYTES", 512 * 64 * 4)
+    tokens, lifted = SPANS[request.param]
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, tokens, 64))
+    p = weights.block_params(
+        TINY, weights.seed_key(4), 1, jnp.float32)["moe"]
+    for e in lifted:
+        p["router_bias"] = p["router_bias"].at[e].set(5.0)
+    return request.param, _span_layer(), p, x
+
+
+def test_a_span_in_chunks_is_the_plain_per_expert_sum_and_its_gradient(
+        span_case):
+    name, layer, p, x = span_case
+    weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(
+        x.shape[1:])
+
+    def program(p, x):
+        return layer.apply({"params": p}, x, differentiable=True)[0]
+
+    def plain(p, x):
+        return _plain_share(p, x[0])[0]
+
+    got, want = program(p, x), plain(p, x)
+    assert float(jnp.max(jnp.abs(got - want))) < TOL
+    g_got = jax.grad(lambda p, x: jnp.sum(program(p, x) * weigh), (0, 1))(
+        p, x)
+    g_want = jax.grad(lambda p, x: jnp.sum(plain(p, x) * weigh), (0, 1))(
+        p, x)
+    gaps = jax.tree.map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b)) / (
+            jnp.max(jnp.abs(b)) + 1e-30)), g_got, g_want)
+    assert max(jax.tree.leaves(gaps)) < TOL, gaps
+    dp = g_got[0]
+    assert not np.any(np.asarray(dp["router_bias"]))
+    routed = [np.any(np.asarray(dp[k])) for k in ("wi", "wg", "wo")]
+    if name == "nothing_local":
+        # y is the shared expert's alone: no routed leaf and no gate
+        # takes a gradient
+        assert not any(routed) and not np.any(np.asarray(dp["router"]))
+    else:
+        assert all(routed) and np.any(np.asarray(dp["router"]))
+
+
+def test_a_span_s_four_counts_are_numpy_s(span_case):
+    name, layer, p, x = span_case
+    _, stats = layer.apply(
+        {"params": p}, x, differentiable=True, mutable=["moe_stats"])
+    got = {k: int(v[0]) for k, v in stats["moe_stats"].items()
+           if k != "held_choices"}
+    chosen = _plain_share(p, x[0])[1]
+    per_expert = chosen[:, 4:8].sum(0).tolist()
+    tiles = [-(-c // 256) for c in per_expert]
+    owners = np.repeat(np.arange(4), tiles)        # live tile -> expert
+    chunks = [owners[i:i + 2] for i in range(0, len(owners), 2)]
+    assert got == {
+        "local_assignments": sum(per_expert),
+        "experts_hit": sum(len(set(c)) for c in chunks),
+        "rows_multiplied": 256 * sum(tiles),
+        "rows_moved": 512 * len(chunks),
+    }
+    held = np.asarray(stats["moe_stats"]["held_choices"][0])
+    assert held.sum(0).tolist() == per_expert
+    if name == "nothing_local":
+        assert got["rows_moved"] == 0
+    if name == "every_choice_local":
+        assert got["local_assignments"] == chosen.sum() == 3 * 640
+        # three runs of 640 rows in tiles of 256: a chunk's edge cuts
+        # each, and the expert on both sides of it counts twice
+        assert got["experts_hit"] == 6 and got["rows_moved"] == 2560
+
+
+def test_a_run_cut_by_every_chunk_edge_sums_dw_over_the_chunks():
+    # the function alone at the smallest tile: expert 0's 40 rows lie
+    # over three chunks of 16 rows, expert 2 holds none, and rows of
+    # x / gates that no pair of the layout names are never read
+    rng, bm, rows = np.random.default_rng(0), 8, 16
+    g, k, held, d, f = 44, 2, 3, 16, 24
+    experts = np.stack([np.where(np.arange(g) < 40, 0, 5),
+                        np.where(np.arange(g) % 4 == 0, 1, 6)], axis=1)
+    gates = jnp.asarray(rng.uniform(0.2, 1.0, size=(g, k)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(g, d)), jnp.float32)
+    w = tuple(jnp.asarray(rng.normal(size=s) / 4, jnp.float32)
+              for s in ((held, d, f), (held, d, f), (held, f, d)))
+    lay = moe_ops.span_layout(jnp.asarray(experts), 0, held, bm, rows)
+    chunks, hit = moe_ops.span_chunks(lay, bm, rows)
+    # 40 rows -> 5 tiles, 11 rows -> 2 tiles: 4 chunks; expert 0 is
+    # read by three of them, expert 1 by two
+    assert (int(lay.live_tiles[0]), int(chunks), int(hit)) == (7, 4, 5)
+    weigh = jnp.sin(jnp.arange(g * d, dtype=jnp.float32)).reshape(g, d)
+
+    def program(x, gates, w):
+        return jnp.sum(weigh * moe_ops.share_span(
+            x, gates, w, lay, bm, rows))
+
+    def plain(x, gates, w):
+        wi, wg, wo = w
+        y = 0.0
+        for e in range(held):
+            gate = jnp.sum(jnp.where(experts == e, gates, 0.0), axis=-1)
+            y = y + gate[:, None] * (
+                (jax.nn.silu(x @ wg[e]) * (x @ wi[e])) @ wo[e])
+        return jnp.sum(weigh * y)
+
+    got = jax.grad(program, (0, 1, 2))(x, gates, w)
+    want = jax.grad(plain, (0, 1, 2))(x, gates, w)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5)
+    assert not np.any(np.asarray(got[2][0][2]))     # no row: dw == 0
+    # a gate of a pair routed elsewhere weighs nothing here
+    assert not np.any(np.asarray(got[1])[experts >= held])
+
+
+@pytest.mark.parametrize("load", [
+    "as_routed", "all_on_the_first_expert", "nothing_local",
+    "the_last_experts_empty"])
+def test_a_span_s_layout_is_the_one_pass_layout_of_the_same_choices(load):
+    # the span's layout (one comparison for a tile's expert, every
+    # sorted row's pair) against ``share_layout`` (a search, a slot ->
+    # token map): the same tiles, the same live prefix, the same token
+    # in every sorted row — also where trailing experts hold no row and
+    # the dead tiles must repeat the last LIVE tile's expert
+    rng = np.random.default_rng(5)
+    g, k, first, held, bm = 300, 3, 4, 4, 8
+    experts = rng.integers(0, 16, size=(g, k))
+    if load == "all_on_the_first_expert":
+        experts[:] = first
+    elif load == "nothing_local":
+        experts[:] = 1
+    elif load == "the_last_experts_empty":
+        experts = np.where(experts >= first + 2, 0, experts)
+    experts = jnp.asarray(experts, jnp.int32)
+    one = moe_ops.share_layout(experts, first, held, bm=bm)
+    span = moe_ops.span_layout(experts, first, held, bm, 4 * bm)
+    t = one.tile_expert.shape[0]
+    live = int(one.live_tiles[0])
+    assert int(span.live_tiles[0]) == live
+    assert np.array_equal(np.asarray(span.tile_expert[:t]),
+                          np.asarray(one.tile_expert))
+    assert np.all(np.asarray(span.tile_expert[t:])
+                  == np.asarray(one.tile_expert)[-1])
+    pairs = np.asarray(span.pairs)
+    token = np.where(pairs < g * k, pairs // k, g)
+    assert np.array_equal(token[:t * bm], np.asarray(one.slot_token))
+    assert np.all(token[live * bm:] == g)
+    assert np.array_equal(np.asarray(span.local), np.asarray(one.local))
+
+
+@pytest.mark.parametrize("what", ["gates", "their_gradient"])
+def test_the_masked_pick_reads_the_gathered_scores_to_the_bit(what):
+    # a span picks its chosen scores by a masked sum over the experts,
+    # a decode step gathers them: the same gates, the same gradient
+    scores = jax.nn.sigmoid(jax.random.normal(
+        jax.random.PRNGKey(3), (96, 16)))
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(4), (16,))
+    weigh = jnp.cos(jnp.arange(96 * 3, dtype=jnp.float32)).reshape(96, 3)
+
+    def gates(scores, masked):
+        return moe_ops.sigmoid_topk(
+            scores, bias, 3, 2.446, masked_pick=masked)
+
+    if what == "gates":
+        got, want = gates(scores, True), gates(scores, False)
+        assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    else:
+        got, want = (jax.grad(lambda s: jnp.sum(
+            gates(s, masked)[1] * weigh))(scores)
+            for masked in (True, False))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=0, atol=1e-7)
+        assert np.any(np.asarray(got))
+
+
+@pytest.mark.parametrize("policy", ["block_keeps_the_routing", "none"])
+def test_a_rematerialised_block_keeps_a_span_s_routing_and_no_row(policy):
+    # what the backward of a block under ``remat_policy="block"``
+    # starts from: the chosen experts and the sorted rows' pairs, 4 bytes a
+    # pair each, and no array with a token's row in it
+    from jax._src.ad_checkpoint import saved_residuals
+
+    layer = _span_layer()
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 640, 64))
+    p = weights.block_params(
+        TINY, weights.seed_key(4), 1, jnp.float32)["moe"]
+    names = moe.SPAN_SAVED if policy != "none" else ()
+    block = jax.checkpoint(
+        lambda p, x: layer.apply({"params": p}, x, differentiable=True),
+        policy=jax.checkpoint_policies.save_only_these_names(*names))
+    kept = [(aval.shape, str(aval.dtype)) for aval, why in
+            saved_residuals(block, p, x) if "argument" not in why]
+    # 1920 pairs + a tile an expert = 2944 sorted rows, in two chunks
+    # of 2560 (a balanced router's 480 rows in tiles + two tiles each)
+    want = [((640, 3), "int32"), ((5120,), "int32")] if names else []
+    assert sorted(kept) == sorted(want), kept
+    # ... and the gradient is the one the unwrapped layer gives
+    loss = lambda f: lambda p, x: jnp.sum(jnp.sin(f(p, x)))  # noqa: E731
+    got = jax.grad(loss(block), (0, 1))(p, x)
+    plain = jax.grad(loss(lambda p, x: layer.apply(
+        {"params": p}, x, differentiable=True)), (0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(plain)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("rows,differentiable", [
+    (17, False), (17, True), (511, False)])
+def test_fewer_rows_than_a_span_take_the_one_pass_over_tiles_of_16(
+        rows, differentiable):
+    # a decode step's rows (16 callers and one free lane) and anything
+    # under 512: no loop, no chunk, nothing named for a remat to keep,
+    # and the answer is the plain per-expert sum
+    layer = _span_layer()
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, rows, 64))
+    p = weights.block_params(
+        TINY, weights.seed_key(4), 1, jnp.float32)["moe"]
+
+    def program(p, x):
+        return layer.apply({"params": p}, x, differentiable=differentiable,
+                           mutable=["moe_stats"])
+
+    text = str(jax.make_jaxpr(program)(p, x))
+    assert "while" not in text and "name[" not in text
+    assert "custom_vjp" in text if differentiable else (
+        "custom_vjp" not in text)
+    got, stats = program(p, x)
+    assert float(jnp.max(jnp.abs(
+        got[0] - _plain_share(p, x[0])[0]))) < TOL
+    assert ("rows_moved" in stats["moe_stats"]) == differentiable
+    # a span of 512 rows does loop
+    x512 = jnp.zeros((1, 512, 64))
+    assert "while" in str(jax.make_jaxpr(program)(p, x512))
+
+
 # -- (e): flash attention with a value head of its own ------------------
 
 
