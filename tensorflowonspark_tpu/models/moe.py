@@ -12,6 +12,7 @@ Aux losses are reported through flax's ``sow`` under the ``"losses"``
 collection; :func:`moe_loss_fn` collects them.
 """
 
+import functools
 import logging
 
 import flax.linen as nn
@@ -309,6 +310,12 @@ class SigmoidMoE(nn.Module):
     scaling: float = 1.0
     shared_experts: int = 1
     dtype: str = "bfloat16"
+    #: "sigmoid" (above) | "softmax": ``s = softmax(x W_r)`` over all
+    #: ``router_experts`` in float32, the ``k`` largest, weights
+    #: normalised over the chosen (``norm_topk_prob``) times
+    #: ``scaling``; no correction bias (the parameter is not made).
+    #: Layout, products and counts are the same code either way
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x, differentiable=False):
@@ -327,7 +334,12 @@ class SigmoidMoE(nn.Module):
         xf = x.reshape(g, d)
         router = self.param(
             "router", nn.initializers.normal(stddev=0.02), (d, e))
-        bias = self.param("router_bias", nn.initializers.zeros, (e,))
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError("scoring %r is not built" % (self.scoring,))
+        score = (jax.nn.sigmoid if self.scoring == "sigmoid"
+                 else functools.partial(jax.nn.softmax, axis=-1))
+        bias = (self.param("router_bias", nn.initializers.zeros, (e,))
+                if self.scoring == "sigmoid" else jnp.zeros((e,)))
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
         wi = self.param("wi", init, (held, d, m)).astype(jdtype)
         wg = self.param("wg", init, (held, d, m)).astype(jdtype)
@@ -339,7 +351,7 @@ class SigmoidMoE(nn.Module):
         choice_bias = jax.lax.stop_gradient(bias) if differentiable else bias
 
         def route(xc, span=False):
-            scores = jax.nn.sigmoid(jnp.dot(
+            scores = score(jnp.dot(
                 xc, router.astype(xc.dtype),
                 preferred_element_type=jnp.float32))
             return moe_ops.sigmoid_topk(

@@ -19,6 +19,7 @@ Logical sharding axes (consumed by
 """
 
 import dataclasses
+import math
 
 import flax.linen as nn
 import jax
@@ -102,12 +103,24 @@ class TransformerConfig:
     #: right CPU serving choice; numerics match the multi-token path
     #: bit for bit).  Multi-token spans always use the gather path.
     paged_decode_impl: str = "kernel"
+    #: set by the SlotDecoder, not by hand, where every span its
+    #: contiguous banks see is a FRESH prompt at positions from 0 (no
+    #: prefix continuation, no verify block, no pages, no mesh).  Then
+    #: (a) a layer whose window is shorter than its bank keeps a RING
+    #: of :func:`ring_rows` rows, written and read modulo its length;
+    #: (b) a prompt attends over its own keys, not over the bank —
+    #: through the flash forward kernel where :func:`prefill_flash`
+    #: finds the shapes legal
+    fresh_prompts: bool = False
     # MoE: num_experts > 0 swaps the dense MLP for an expert-parallel
     # MoE FFN (models/moe.py) in every block
     num_experts: int = 0
     expert_k: int = 2
     capacity_factor: float = 1.25
-    #: "gather" (index dispatch, no permutation matmuls) | "einsum"
+    #: "gather" (index dispatch, no permutation matmuls) | "einsum" |
+    #: "dropless" (MoEMLP over row tiles of 256) | "share" (the
+    #: never-a-drop share layer ``SigmoidMoE`` under softmax scores:
+    #: a decode step over row tiles of 16, a prompt routed once)
     expert_dispatch: str = "gather"
     #: RMSNorm epsilon of every norm in the model
     rms_norm_eps: float = 1e-6
@@ -115,7 +128,21 @@ class TransformerConfig:
     #: (interleaved) or (i, i + D/2) (split halves)
     rope_theta: float = 10000.0
     rope_interleave: bool = False
+    #: RMS-norm each head's query and key over ``head_dim`` with a
+    #: learned scale, before the rotation (``attn/q_norm``, ``k_norm``)
+    qk_norm: bool = False
     # -- per-layer pattern --------------------------------------------
+    #: attention of each layer, "sliding_attention" (sees the last
+    #: ``sliding_window`` positions) | "full_attention" (full causal);
+    #: empty: every layer alike, under ``attention_window``
+    layer_types: tuple = ()
+    sliding_window: int = 0
+    #: RoPE of each layer TYPE, where the types differ: ``{type:
+    #: {"rope_theta", and for YaRN "rope_type": "yarn", "factor",
+    #: "original_max_position_embeddings", "beta_fast", "beta_slow",
+    #: "attention_factor"}}`` (the published ``rope_parameters``; held
+    #: as sorted item tuples).  Empty: ``rope_theta`` on every layer
+    layer_rope: tuple = ()
     #: FFN of each layer, "dense" | "sparse" (empty: every layer dense,
     #: or every layer the softmax MoE above when num_experts > 0)
     mlp_layer_types: tuple = ()
@@ -154,7 +181,13 @@ class TransformerConfig:
 
     def __post_init__(self):
         # a config read from JSON brings lists; flax hashes the config
-        for name in ("mlp_layer_types", "indexer_types"):
+        rope_of = self.layer_rope
+        if isinstance(rope_of, dict):
+            rope_of = tuple(sorted(
+                (kind, tuple(sorted(dict(v).items())))
+                for kind, v in rope_of.items()))
+        object.__setattr__(self, "layer_rope", tuple(rope_of or ()))
+        for name in ("mlp_layer_types", "indexer_types", "layer_types"):
             val = tuple(getattr(self, name) or ())
             object.__setattr__(self, name, val)
             if val and len(val) != self.num_layers:
@@ -167,7 +200,10 @@ class TransformerConfig:
         return jnp.dtype(self.dtype)
 
     def ffn_kind(self, layer):
-        """"dense", "moe" (softmax, MoEMLP) or "sigmoid_moe" of layer
+        """"dense", "moe" (MoEMLP: softmax scores, capacity or row
+        tiles of 256) or "sigmoid_moe" (the never-a-drop share layer
+        ``SigmoidMoE``, scored as ``router_scoring`` says: sigmoid
+        always, softmax under ``expert_dispatch="share"``) of layer
         ``layer``."""
         sparse = (
             self.mlp_layer_types[layer] == "sparse"
@@ -175,20 +211,84 @@ class TransformerConfig:
         )
         if not sparse:
             return "dense"
-        return "sigmoid_moe" if self.router_scoring == "sigmoid" else "moe"
+        share = (self.router_scoring == "sigmoid"
+                 or self.expert_dispatch == "share")
+        return "sigmoid_moe" if share else "moe"
+
+    def window_of(self, layer):
+        """Positions layer ``layer`` sees behind (and with) a query, 0
+        = all: ``sliding_window`` on a "sliding_attention" layer of
+        ``layer_types``, ``attention_window`` where no types are
+        named."""
+        if not self.layer_types:
+            return self.attention_window
+        sliding = self.layer_types[layer] == "sliding_attention"
+        return self.sliding_window if sliding else 0
+
+    def rope_of(self, layer):
+        """``(theta, inv_freq, factor)`` of layer ``layer``'s rotation:
+        ``inv_freq`` None and ``factor`` 1 under the default RoPE of
+        base ``theta``; under YaRN the layer type's blended
+        frequencies (:func:`yarn_inv_freq`) and the factor that
+        multiplies cos and sin."""
+        kinds = dict(self.layer_rope)
+        if not (self.layer_types and kinds):
+            return self.rope_theta, None, 1.0
+        p = dict(kinds[self.layer_types[layer]])
+        theta = float(p["rope_theta"])
+        if p.get("rope_type", "default") == "default":
+            return theta, None, 1.0
+        if p["rope_type"] != "yarn":
+            raise ValueError("rope_type %r is not built" % (p["rope_type"],))
+        return theta, yarn_inv_freq(
+            self.head_dim, theta, float(p["factor"]),
+            int(p["original_max_position_embeddings"]),
+            float(p.get("beta_fast", 32)), float(p.get("beta_slow", 1)),
+        ), float(p.get("attention_factor") or (
+            0.1 * math.log(float(p["factor"])) + 1.0))
 
 
-def rope(x, positions, max_wavelength=10000.0, interleave=False):
+def yarn_inv_freq(dim, theta, factor, original, beta_fast, beta_slow):
+    """YaRN's ``dim // 2`` inverse frequencies (float32 numpy), as
+    ``transformers``' ``_compute_yarn_parameters`` with ``truncate``:
+    pairs that turn more than ``beta_fast`` times over the ``original``
+    context keep ``theta ** (-2i / dim)``, pairs that turn fewer than
+    ``beta_slow`` times are slowed by ``factor``, a linear ramp
+    between."""
+    import numpy as np
+
+    half = dim // 2
+    extrap = theta ** (-np.arange(half, dtype=np.float64) * 2 / dim)
+    interp = extrap / factor
+
+    def turns_at(n):
+        return dim * math.log(original / (n * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    ramp = np.clip(
+        (np.arange(half, dtype=np.float64) - low) / max(high - low, 1e-3),
+        0, 1)
+    return (interp * ramp + extrap * (1 - ramp)).astype(np.float32)
+
+
+def rope(x, positions, max_wavelength=10000.0, interleave=False,
+         inv_freq=None, factor=1.0):
     """Rotary position embedding on ``[B, S, H, D]`` (D even): pair
     ``i`` is ``(i, i + D/2)``, or ``(2i, 2i + 1)`` with
-    ``interleave``."""
+    ``interleave``.  ``inv_freq`` (``[D/2]``) replaces the default
+    ``max_wavelength ** (-2i / D)`` and ``factor`` multiplies cos and
+    sin (YaRN: :meth:`TransformerConfig.rope_of`)."""
     d = x.shape[-1]
     freq = max_wavelength ** (
         -jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2)
-    )
+    ) if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freq  # [B,S,D/2]
     angles = angles[:, :, None, :]  # [B,S,1,D/2]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if factor != 1.0:
+        sin, cos = sin * factor, cos * factor
     x32 = x.astype(jnp.float32)
     if interleave:
         x1, x2 = x32[..., 0::2], x32[..., 1::2]
@@ -236,13 +336,112 @@ def decode_bank_block(cfg, bank_len):
     )
 
 
+def ring_rows(cfg, window):
+    """Rows of the ring a layer of ``window`` keeps under
+    ``cfg.fresh_prompts``: the window rounded out to whole blocks of
+    the decode kernel plus one block — a step's query at ``p`` reads
+    the blocks of ``[p - window + 1, p]``, up to ``window + block - 1``
+    positions counted from the first block's start, and none of them
+    may have been overwritten by ``p``'s own append (1280 for a window
+    of 1024 in blocks of 256).  Where the head size is not tile-legal
+    (CPU tests) there is no block to round to and the ring is the
+    window itself: the append at ``p`` takes the row of ``p - window``,
+    which has just left it."""
+    from tensorflowonspark_tpu.ops import paged_attention as pa
+
+    dtype = jnp.int8 if cfg.cache_dtype == "int8" else cfg.jdtype
+    t = pa.BANK_BLOCKS[0]
+    try:
+        pa.check_tiles(t, cfg.head_dim, dtype)
+    except pa.TileLegalityError:
+        return int(window)
+    return (-(-int(window) // t) + 1) * t
+
+
+def bank_rows(cfg, layer, length):
+    """Rows layer ``layer``'s key/value bank holds for a cache of
+    ``length`` positions: a ring (:func:`ring_rows`) where the layer's
+    window makes one shorter than the bank, else ``length``."""
+    window = cfg.window_of(layer)
+    if cfg.fresh_prompts and window and cfg.attention_kind != "mla":
+        return min(ring_rows(cfg, window), length)
+    return length
+
+
+def prefill_flash(cfg, span):
+    """Whether a fresh prompt of ``span`` (bucketed) tokens attends
+    through the flash forward kernel (``ops/flash_attention.py``,
+    banded under the layer's window) instead of masked
+    ``dot_attention`` with float32 scores ``[H, span, keys]`` in HBM.
+    Read from what the code can see, as :func:`decode_bank_block`: the
+    decoder's spans are fresh prompts (``fresh_prompts``: the kernel's
+    causal mask knows nothing of a bank's earlier rows), no mesh, the
+    banks hold what the projections gave (an int8 bank's prefill reads
+    its own rounding back), a head size of whole lanes, and a span the
+    CONFIGURED blocks divide — a long-prompt mix's buckets of 1024 do;
+    prompts shorter than a block keep the einsums they always had."""
+    return bool(
+        cfg.fresh_prompts and cfg.mesh is None
+        and cfg.cache_dtype != "int8" and cfg.head_dim % 128 == 0
+        and span >= cfg.block_q and span % cfg.block_q == 0
+        and span % cfg.block_k == 0)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
+    #: index of this layer in ``layer_types`` (its window, its RoPE)
+    layer: int = 0
+
+    def _prompt_attention(self, q, k, v, positions, pad_start, window):
+        """A fresh prompt over its OWN keys ``[B, S, ...]`` (positions
+        from 0, nothing in the bank before it): causal, the layer's
+        window, never the left pad ``[0, pad_start)``.  Through flash
+        (:func:`prefill_flash`) the rows are rotated left by
+        ``pad_start`` first, so the prompt starts at row 0 and the pad
+        rows come LAST: the kernel's causal mask then hides them from
+        every real query with no mask of their own (RoPE is already in
+        q and k, and the window is a difference of rows, which the
+        rotation keeps), and the result is rotated back; what the pad
+        queries read is never looked at."""
+        cfg = self.cfg
+        b, s = q.shape[0], q.shape[1]
+        pad = (pad_start if pad_start is not None
+               else jnp.zeros((b,), jnp.int32))
+        if prefill_flash(cfg, s):
+            from tensorflowonspark_tpu.ops.flash_attention import (
+                flash_attention,
+            )
+
+            turn = lambda t, by: jax.vmap(  # noqa: E731
+                lambda row, n: jnp.roll(row, n, axis=0))(t, by)
+            out = flash_attention(
+                turn(q, -pad), turn(k, -pad), turn(v, -pad), causal=True,
+                block_q=cfg.block_q, block_k=cfg.block_k, window=window,
+            )
+            return turn(out, pad)
+        from tensorflowonspark_tpu.ops.attention import dot_attention
+
+        pos = positions[0]
+        visible = pos[None, :] <= pos[:, None]
+        if window:
+            visible = jnp.logical_and(
+                visible, pos[None, :] > pos[:, None] - window)
+        # a pad query keeps itself, as under the bank-wide mask
+        visible = jnp.logical_or(
+            jnp.logical_and(
+                visible[None], pos[None, None, :] >= pad[:, None, None]),
+            (pos[None, :] == pos[:, None])[None],
+        )
+        return dot_attention(
+            q, k, v, causal=False,
+            mask=jnp.where(visible, 0.0, -jnp.inf)[:, None])
 
     @nn.compact
     def __call__(self, x, positions, decode=False, pad_start=None,
                  per_slot=False, block_tables=None):
         cfg = self.cfg
+        window = cfg.window_of(self.layer)
+        theta, inv_freq, rope_factor = cfg.rope_of(self.layer)
         h, d = cfg.num_heads, cfg.head_dim
         hkv = cfg.num_kv_heads or h
         if h % hkv != 0:
@@ -270,11 +469,16 @@ class Attention(nn.Module):
             q = dense("q", (h, d))(x)
             k = dense("k", (hkv, d))(x)
             v = dense("v", (hkv, d))(x)
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_interleave)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_interleave)
+        if cfg.qk_norm:
+            q = RMSNorm(eps=cfg.rms_norm_eps, name="q_norm")(q)
+            k = RMSNorm(eps=cfg.rms_norm_eps, name="k_norm")(k)
+        q = rope(q, positions, theta, cfg.rope_interleave, inv_freq,
+                 rope_factor)
+        k = rope(k, positions, theta, cfg.rope_interleave, inv_freq,
+                 rope_factor)
         if decode and cfg.kv_layout == "paged":
             return self._paged_decode(
-                x, q, k, v, positions, block_tables, hkv, d
+                x, q, k, v, positions, block_tables, hkv, d, window
             )
         if decode:
             # KV-cache autoregressive path: keys/values append at the
@@ -305,7 +509,21 @@ class Attention(nn.Module):
                 "cache", "cached_value", jnp.zeros,
                 (b, cfg.max_seq_len, hkv, d), bank_dtype,
             )
-            if per_slot:
+            # a ring (``fresh_prompts``, ring_rows): position p lives
+            # in row p % rows; the bank a decoder built says so by its
+            # length
+            rows = ck.value.shape[1]
+            ring = bool(cfg.fresh_prompts and window
+                        and rows == ring_rows(cfg, window))
+            if ring and not per_slot:
+                # a fresh prompt: its last ``rows`` positions, each to
+                # its own row (one scatter, rows all different)
+                def _write(bank, val):
+                    keep = min(val.shape[1], rows)
+                    at = (positions[0, val.shape[1] - keep:]) % rows
+                    return bank.at[:, at].set(
+                        val[:, val.shape[1] - keep:].astype(bank.dtype))
+            elif per_slot:
                 # continuous-batching slot mode: every batch lane is an
                 # independent request with its OWN write pointer
                 # (positions[:, 0]), so appends are per-row instead of
@@ -315,7 +533,7 @@ class Attention(nn.Module):
                 # as a loop over the slots, one small copy at a time
                 # (0.22 ms a bank at 64 slots, against 0.007 for the
                 # scatter: PERF.md section 5)
-                row_i = positions[:, 0]
+                row_i = positions[:, 0] % rows if ring else positions[:, 0]
 
                 def _write(bank, val):
                     if val.shape[1] == 1:
@@ -357,6 +575,10 @@ class Attention(nn.Module):
             else:
                 ck.value = _write(ck.value, k)
                 cv.value = _write(cv.value, v)
+            if (cfg.fresh_prompts and not per_slot
+                    and (ring or prefill_flash(cfg, x.shape[1]))):
+                return out_proj(self._prompt_attention(
+                    q, k, v, positions, pad_start, window))
             kpos = jnp.arange(ck.value.shape[1])
             qpos = positions[0]
             from tensorflowonspark_tpu.ops.attention import dot_attention
@@ -379,11 +601,32 @@ class Attention(nn.Module):
 
                 out = bank_attention(
                     q[:, 0], ck.value, cv.value, positions[:, 0], ps,
-                    window=cfg.attention_window,
+                    window=window,
+                    k_scale=cks.value if int8_cache else None,
+                    v_scale=cvs.value if int8_cache else None,
+                    ring=ring,
+                )
+                return out_proj(out[:, None])
+            if ring:
+                # one token a slot over a ring too small for the
+                # kernel's tiles: row r holds the newest position at or
+                # before the query's that is congruent to r
+                if not per_slot or x.shape[1] != 1:
+                    raise ValueError(
+                        "a ring bank takes one token a slot or a fresh "
+                        "prompt, not a span of %d" % x.shape[1])
+                qp = positions[:, :1]  # [B, 1]
+                held = qp - (qp - jnp.arange(rows)[None, :]) % rows
+                vis = jnp.logical_and(held >= 0, held > qp - window)
+                vis = jnp.logical_or(
+                    jnp.logical_and(vis, held >= ps[:, None]), held == qp)
+                out = dot_attention(
+                    q, ck.value, cv.value, causal=False,
+                    mask=jnp.where(vis, 0.0, -jnp.inf)[:, None, None],
                     k_scale=cks.value if int8_cache else None,
                     v_scale=cvs.value if int8_cache else None,
                 )
-                return out_proj(out[:, None])
+                return out_proj(out)
             if per_slot:
                 # per-row query positions: each slot sees its own
                 # causal horizon, window, and pad region.  Slots keep
@@ -392,11 +635,11 @@ class Attention(nn.Module):
                 # ragged pad-row case below).
                 qpos_r = positions  # [B, S]
                 vis = kpos[None, None, :] <= qpos_r[:, :, None]
-                if cfg.attention_window:
+                if window:
                     vis = jnp.logical_and(
                         vis,
                         kpos[None, None, :]
-                        > qpos_r[:, :, None] - cfg.attention_window,
+                        > qpos_r[:, :, None] - window,
                     )
                 vis = jnp.logical_or(
                     jnp.logical_and(
@@ -412,10 +655,10 @@ class Attention(nn.Module):
                 )
                 return out_proj(out)
             visible = kpos[None, :] <= qpos[:, None]
-            if cfg.attention_window:
+            if window:
                 visible = jnp.logical_and(
                     visible,
-                    kpos[None, :] > qpos[:, None] - cfg.attention_window,
+                    kpos[None, :] > qpos[:, None] - window,
                 )
             if pad_start is not None:
                 # ragged LEFT-padded batch: row r's cache slots before
@@ -454,11 +697,12 @@ class Attention(nn.Module):
                 seq_axis=cfg.seq_axis,
                 block_q=cfg.block_q,
                 block_k=cfg.block_k,
-                window=cfg.attention_window,
+                window=window,
             )
         return out_proj(out)
 
-    def _paged_decode(self, x, q, k, v, positions, block_tables, hkv, d):
+    def _paged_decode(self, x, q, k, v, positions, block_tables, hkv, d,
+                      window):
         """Paged-KV decode (``kv_layout="paged"``): the per-layer cache
         is ONE physical page pool ``[kv_pages, kv_page_tokens, Hkv,
         Dx]`` shared by every slot; ``block_tables [B, kv_slot_blocks]``
@@ -537,14 +781,14 @@ class Attention(nn.Module):
         if s == 1 and cfg.paged_decode_impl == "kernel":
             out = paged_attention(
                 q[:, 0], ck.value, cv.value, block_tables,
-                pos[:, 0] + 1, window=cfg.attention_window,
+                pos[:, 0] + 1, window=window,
                 k_scale_pool=ksp, v_scale_pool=vsp,
             )[:, None]
         else:
             out = paged_gather_attention(
                 q, ck.value, cv.value, block_tables, pos,
                 span=cfg.kv_span or None,
-                window=cfg.attention_window,
+                window=window,
                 k_scale_pool=ksp, v_scale_pool=vsp,
             )
         return nn.DenseGeneral(
@@ -597,7 +841,7 @@ class Block(nn.Module):
               pad_start=pad_start, per_slot=per_slot, sel=sel)
             x = x + att
         else:
-            x = x + Attention(cfg, name="attn")(
+            x = x + Attention(cfg, layer=self.layer, name="attn")(
                 norm("ln1")(x), positions, decode=decode,
                 pad_start=pad_start, per_slot=per_slot,
                 block_tables=block_tables,
@@ -617,6 +861,7 @@ class Block(nn.Module):
                 scaling=cfg.routed_scaling,
                 shared_experts=cfg.shared_experts,
                 dtype=cfg.dtype,
+                scoring=cfg.router_scoring,
                 name="moe",
             )(h, differentiable=not decode)
         elif kind == "moe":
@@ -821,14 +1066,23 @@ def init_cache(model, batch_size, cache_len=None):
             lambda x: jnp.zeros(x.shape, x.dtype), shapes["cache"]
         )
 
-    def _zero(x):
+    def _zero(x, rows=length):
         # [B, max_seq, ...] banks: keys and values [.., H, D], latent
         # rows and index keys [.., width]
         if x.ndim >= 3:
-            return jnp.zeros((x.shape[0], length) + x.shape[2:], x.dtype)
+            return jnp.zeros((x.shape[0], rows) + x.shape[2:], x.dtype)
         return jnp.zeros(x.shape, x.dtype)
 
-    return jax.tree.map(_zero, shapes["cache"])
+    if not model.cfg.fresh_prompts:
+        return jax.tree.map(_zero, shapes["cache"])
+    # a windowed layer's banks are rings where that is shorter
+    out = {}
+    for name, sub in shapes["cache"].items():
+        rows = length
+        if name.startswith("block_"):
+            rows = bank_rows(model.cfg, int(name.rsplit("_", 1)[1]), length)
+        out[name] = jax.tree.map(lambda x, rows=rows: _zero(x, rows), sub)
+    return out
 
 
 def sample_logits(logits, key, temperature=0.0, top_k=0, top_p=0.0):
@@ -1460,6 +1714,21 @@ class SlotDecoder:
                 import dataclasses as _dc
 
                 self.model = Transformer(_dc.replace(model.cfg, mesh=mesh))
+            if not (self._latent or self._use_prefix or self._spec
+                    or mesh is not None):
+                # every span these banks see is a fresh prompt: a
+                # windowed layer may keep a ring, a long prompt may go
+                # through flash (TransformerConfig.fresh_prompts)
+                import dataclasses as _dc
+
+                self.model = Transformer(
+                    _dc.replace(model.cfg, fresh_prompts=True))
+        #: rows of each layer's bank: a ring where the layer's window
+        #: makes one shorter than ``_bank_len`` (the paged pool and the
+        #: latent banks are not per-layer: ``_bank_len`` throughout)
+        self._layer_rows = [
+            bank_rows(self.model.cfg, i, self._bank_len)
+            for i in range(self.model.cfg.num_layers)]
         #: what the decode chunk's flagship attention was built with:
         #: "kernel" (block-walking, reads live blocks only), "dot"
         #: (masked einsums over the whole span; a speculative chunk's
@@ -1481,9 +1750,17 @@ class SlotDecoder:
             self._kv_block = decode_bank_block(
                 self.model.cfg, self._bank_len
             )
+        #: tokens a copy of the decode kernel reads of each layer's
+        #: bank (a ring's length has its own), None where that layer's
+        #: step reads the bank whole
+        self._layer_blocks = [
+            self._kv_block if rows == self._bank_len
+            else decode_bank_block(self.model.cfg, rows)
+            for rows in self._layer_rows]
         self.attn_impl = (
             "latent" if self._latent
-            else "kernel" if self._kv_block else "dot")
+            else "kernel" if all(self._layer_blocks)
+            else "dot" if not any(self._layer_blocks) else "mixed")
         self._np = np
         self._qz = qz
         self._rng = jax.random.PRNGKey(int(seed))
@@ -2177,14 +2454,18 @@ class SlotDecoder:
         latent span goes through the span kernel and ``"einsum"``
         where it keeps its einsums (``mla.span_blocks`` decides, the
         function the model itself asks); over K/V banks a span is
-        always ``"dot"`` (masked dot attention over the bank), over
-        pages ``"gather"``."""
+        ``"flash"`` where :func:`prefill_flash` sends a fresh prompt
+        through the flash forward kernel and else ``"dot"`` (masked
+        dot attention, over the bank or over the prompt's own keys),
+        over pages ``"gather"``."""
         if self._latent:
             from tensorflowonspark_tpu.models.mla import span_blocks
 
             return ("latent_span_kernel"
                     if span_blocks(self.model.cfg, True, int(bucket))
                     else "einsum")
+        if prefill_flash(self.model.cfg, int(bucket)):
+            return "flash"
         return "gather" if self._paged else "dot"
 
     def _suffix_bucket(self, suffix_len, kpref):
@@ -2715,51 +2996,91 @@ class SlotDecoder:
         :meth:`dispatch_chunk` / :meth:`resolve_chunk`)."""
         return self.resolve_chunk(self.dispatch_chunk())
 
+    def _layer_reads(self, live):
+        """Per layer, the key/value positions the next chunk's first
+        decode step reads, from the scheduler's own record ``live`` of
+        every request in flight (``(prompt_len, generated)`` since its
+        admit; no device pull).  A layer whose step goes through the
+        block-walking kernel reads, a slot, the blocks its live span
+        touches — a left-padded admit's span starts past its pad
+        region, the layer's OWN window cuts it from below, a ring holds
+        the same blocks modulo its length — and one block of a lane
+        nobody holds; a layer under masked einsums reads its bank
+        whole, ring or not."""
+        cfg = self.model.cfg
+        canonical = self._paged or self._use_prefix
+        spans = []
+        for n, gen in live:
+            first = 0 if canonical else self.bucket_len(n) - n
+            spans.append((first, first + n + gen - 1))
+        reads = {}  # (block, window) -> positions: layers repeat
+        out = []
+        for layer, (rows, t) in enumerate(
+                zip(self._layer_rows, self._layer_blocks)):
+            if not t:
+                out.append(self.num_slots * (
+                    self._blocks_per_slot * self._page_tokens
+                    if self._paged else rows))
+                continue
+            window = cfg.window_of(layer)
+            if (t, window) not in reads:
+                read = (self.num_slots - len(live)) * t
+                for first, last in spans:
+                    if window:
+                        first = max(first, last + 1 - window)
+                    read += (last // t - first // t + 1) * t
+                reads[t, window] = read
+            out.append(reads[t, window])
+        return out
+
     def kv_read_tokens(self, live):
-        """``(read, bank)``: key/value positions per layer that the
-        next chunk's first decode step reads, and what the slots'
-        banks hold (slots x bank length).  ``live`` is the scheduler's
-        own record of every request in flight, ``(prompt_len,
-        generated)`` since its admit — no device pull.  Under
-        ``attn_impl == "kernel"`` a slot reads the blocks its live
-        span touches (a left-padded admit's span starts past its pad
-        region, the window cuts it from below) and a lane nobody holds
-        reads one block; under ``"dot"`` every step reads every
-        bank whole."""
+        """``(read, bank)``, a layer (the mean over the layers where
+        they differ — rings beside whole banks): key/value positions
+        the next chunk's first decode step reads (:meth:`_layer_reads`)
+        and what the slots' banks hold."""
+        layers = len(self._layer_rows)
         if self._paged:
             bank = self.num_slots * self._blocks_per_slot * self._page_tokens
         else:
-            bank = self.num_slots * self._bank_len
-        t = self._kv_block
-        if not t:
-            # every bank whole under a mask
-            return bank, bank
-        window = self.model.cfg.attention_window
-        canonical = self._paged or self._use_prefix
-        read = (self.num_slots - len(live)) * t
-        for n, gen in live:
-            first = 0 if canonical else self.bucket_len(n) - n
-            last = first + n + gen - 1  # where the step's query sits
-            if window:
-                first = max(first, last + 1 - window)
-            read += (last // t - first // t + 1) * t
-        return read, bank
+            bank = self.num_slots * sum(self._layer_rows) // layers
+        return sum(self._layer_reads(live)) // layers, bank
 
     def attn_read_tokens(self, live):
         """``(read, context)`` summed over the layers: the positions
         the next chunk's first decode step reads — key/value or latent
-        rows on every layer, and the index keys of the layers that own
-        a sparse index — and the positions that are live (every
-        request's prompt and answer so far, a layer; what dense
-        attention over exactly the live keys would read).  ``live`` as
-        for :meth:`kv_read_tokens`: no device pull."""
+        rows on every layer, each by its own window and bank, and the
+        index keys of the layers that own a sparse index — and the
+        positions that are live (every request's prompt and answer so
+        far, a layer; what dense attention over exactly the live keys
+        would read).  ``live`` as for :meth:`_layer_reads`: no device
+        pull."""
         cfg = self.model.cfg
-        read, bank = self.kv_read_tokens(live)
         # the index scores every position of its bank
         index_layers = sum(t == "full" for t in cfg.indexer_types)
         context = sum(n + gen for n, gen in live)
-        return (read * cfg.num_layers + bank * index_layers,
+        return (sum(self._layer_reads(live))
+                + self.num_slots * self._bank_len * index_layers,
                 context * cfg.num_layers)
+
+    def kv_bank_bytes(self):
+        """Bytes the contiguous key/value banks hold, by kind:
+        ``{"ring", "whole"}`` — the windowed layers' rings and the
+        banks of ``_bank_len`` rows — and ``"unringed"``, what whole
+        banks on every layer would hold.  None for pages and latent
+        rows (their sizes are the pool's and the latent banks')."""
+        if self._paged or self._latent:
+            return None
+        row = sum(
+            leaf.size // (leaf.shape[0] * leaf.shape[1]) * leaf.dtype.itemsize
+            for leaf in jax.tree.leaves(self.cache["block_0"]))
+        ring = sum(r for r in self._layer_rows if r != self._bank_len)
+        whole = sum(r for r in self._layer_rows if r == self._bank_len)
+        return {
+            "ring": self.num_slots * ring * row,
+            "whole": self.num_slots * whole * row,
+            "unringed": self.num_slots * self._bank_len * row
+            * len(self._layer_rows),
+        }
 
     def reuse_stats(self):
         """Cross-request reuse counters: the prefix cache's

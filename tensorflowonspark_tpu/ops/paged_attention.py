@@ -168,7 +168,7 @@ def _live_blocks(length, start, page_tokens, window):
 
 def _decode_kernel(tbl_ref, len_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
                    slots, page_tokens, hkv, group, scale, window,
-                   int8_scales):
+                   int8_scales, ring=False):
     """The online softmax of ``slots`` slots a grid step, each over its
     own live blocks.  ``rest`` is ``[ks_hbm, vs_hbm,] o_ref, kbuf,
     vbuf, [ksbuf, vsbuf,] sem, par, own_ref``.
@@ -190,7 +190,14 @@ def _decode_kernel(tbl_ref, len_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
     positions outside the span (``own_ref``: 0 on a row's own head,
     ``NEG_INF`` elsewhere, built once), so their probabilities are
     zero and ``p @ v`` sums a head's own rows only — no per-head
-    slicing or transposes of the block."""
+    slicing or transposes of the block.
+
+    ``ring``: a slot's table is a RING — logical block ``j`` (positions
+    ``[j*T, (j+1)*T)``) lives in entry ``j % NB``.  The span, the
+    blocks walked and the masks are reckoned in logical positions as
+    ever; only the copy's source wraps.  The caller keeps the ring
+    long enough that no two live blocks share an entry and no visible
+    position has been overwritten."""
     from jax.experimental.pallas import tpu as pltpu
 
     if int8_scales:
@@ -212,7 +219,7 @@ def _decode_kernel(tbl_ref, len_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
         return _live_blocks(len_ref[slot], start_ref[slot], t, window)
 
     def copies(slot, block, half):
-        page = tbl_ref[slot, block]
+        page = tbl_ref[slot, block % tbl_ref.shape[1] if ring else block]
         return [
             pltpu.make_async_copy(
                 hbm.at[page], buf.at[half], sem.at[i, half]
@@ -299,7 +306,7 @@ def _decode_kernel(tbl_ref, len_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
                     starts=None, scale=None, window=0, k_scale_pool=None,
-                    v_scale_pool=None, interpret=None):
+                    v_scale_pool=None, interpret=None, ring=False):
     """Single-token decode attention over a paged KV pool.
 
     Args:
@@ -326,6 +333,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         scales for int8 pools (per-position/per-head, the int8-KV
         cache layout).
       interpret: force/deny interpret mode (default: off-TPU).
+      ring: each table row is a ring of ``NB`` blocks: logical block
+        ``j`` is entry ``j % NB`` (``lengths`` and ``starts`` stay
+        logical and may pass ``NB * T``; needs a ``window`` that, with
+        a block's slack, fits the ring).
     Returns ``[B, H, D]`` in ``q.dtype``.
     """
     if interpret is None:
@@ -350,17 +361,23 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
     if starts is None:
         starts = jnp.zeros((b,), jnp.int32)
     assert starts.shape == (b,), starts.shape
+    if ring and not 0 < window <= (nb - 1) * t:
+        raise ValueError(
+            "a ring of {0} blocks of {1} holds a window of at most "
+            "{2}, got {3}".format(nb, t, (nb - 1) * t, window))
     return _decode_call(
         q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(lengths, jnp.int32), jnp.asarray(starts, jnp.int32),
         k_scale_pool, v_scale_pool, scale=float(scale),
-        window=int(window), interpret=bool(interpret),
+        window=int(window), interpret=bool(interpret), ring=bool(ring),
     )
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("scale", "window", "interpret", "ring"))
 def _decode_call(q, k_pool, v_pool, block_tables, lengths, starts,
-                 k_scale_pool, v_scale_pool, *, scale, window, interpret):
+                 k_scale_pool, v_scale_pool, *, scale, window, interpret,
+                 ring=False):
     """The kernel's ``pallas_call``, under a jit of its own: a model
     calls it once a layer with the same shapes, and is then traced and
     lowered for Mosaic once, not once a layer (1.6 s at 16 layers)."""
@@ -375,7 +392,7 @@ def _decode_call(q, k_pool, v_pool, block_tables, lengths, starts,
     kernel = functools.partial(
         _decode_kernel,
         slots=slots, page_tokens=t, hkv=hkv, group=group,
-        scale=scale, window=window, int8_scales=int8_scales,
+        scale=scale, window=window, int8_scales=int8_scales, ring=ring,
     )
     slot_map = lambda gi, tbl, ln, st: (gi, 0, 0)  # noqa: E731
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -447,7 +464,8 @@ def bank_block(bank_len, head_dim, dtype):
 
 
 def bank_attention(q, k_bank, v_bank, positions, pad_start, *, scale=None,
-                   window=0, k_scale=None, v_scale=None, interpret=None):
+                   window=0, k_scale=None, v_scale=None, interpret=None,
+                   ring=False):
     """Single-token decode attention over contiguous per-slot banks
     ``[B, S, Hkv, D]``, reading only each slot's live span.
 
@@ -461,6 +479,10 @@ def bank_attention(q, k_bank, v_bank, positions, pad_start, *, scale=None,
     sees its own position, so an idle lane (``pad_start`` past its
     position) reads one block and attends itself alone.  Requires
     :func:`bank_block` to find a block size for the bank.
+
+    ``ring``: the bank is a ring of ``S`` rows, position ``p`` in row
+    ``p % S`` (``positions`` may pass ``S``); ``S`` is at least the
+    window rounded out to whole blocks plus one block.
     """
     b, s, hkv, d = k_bank.shape
     t = bank_block(s, d, k_bank.dtype)
@@ -481,7 +503,7 @@ def bank_attention(q, k_bank, v_bank, positions, pad_start, *, scale=None,
         jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb),
         positions + 1, starts=jnp.minimum(pad_start, positions),
         scale=scale, window=window, k_scale_pool=pool(k_scale),
-        v_scale_pool=pool(v_scale), interpret=interpret,
+        v_scale_pool=pool(v_scale), interpret=interpret, ring=ring,
     )
 
 

@@ -729,6 +729,14 @@ class ServingEngine(object):
         self._m_prompt_tokens = reg.histogram("serving.prompt_tokens")
         self._m_pool = reg.gauge("serving.pool_pages")
         self._m_pool_used = reg.gauge("serving.pool_pages_used")
+        # what the contiguous key/value banks hold, by kind: windowed
+        # layers' rings beside whole banks (SlotDecoder.kv_bank_bytes;
+        # nothing for pages, latent rows and tests' fakes)
+        bank_bytes = getattr(self.decoder, "kv_bank_bytes", None)
+        for kind, held in ((bank_bytes() if bank_bytes else None)
+                           or {}).items():
+            reg.gauge("serving.kv_bank_bytes_" + kind).set(int(held))
+            self.stats["kv_bank_bytes_" + kind] = int(held)
         # scalar knob retunes, queued by request_retune() and applied
         # between decode chunks on the scheduling pass (ISSUE 18: the
         # live re-planner's safe seam for non-geometry knobs)

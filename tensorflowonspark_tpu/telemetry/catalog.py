@@ -102,7 +102,14 @@ METRICS = tuple(
           "split bounds; trace-id exemplars)"),
          ("serving.queue_wait_sec", "admission-queue wait"))
     + _m(_G, "ServingEngine",
-         ("serving.weight_generation", "live weight generation tag"))
+         ("serving.weight_generation", "live weight generation tag"),
+         ("serving.kv_bank_bytes_ring",
+          "bytes of the contiguous KV banks that are rings (layers "
+          "whose window is shorter than the bank)"),
+         ("serving.kv_bank_bytes_whole",
+          "bytes of the contiguous KV banks of full length"),
+         ("serving.kv_bank_bytes_unringed",
+          "bytes whole banks on every layer would hold"))
     + _m(_C, "hot_swap.CheckpointWatcher",
          ("serving.checkpoints_quarantined",
           "serving exports rejected by the validation pipeline"))

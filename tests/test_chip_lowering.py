@@ -389,3 +389,90 @@ def test_a_routed_span_compiles_for_v5e_small_and_with_no_row_of_every_pair(
         plan.generated_code_size_in_bytes / 1e6))
     assert plan.temp_size_in_bytes < temp_cap
     assert plan.generated_code_size_in_bytes < code_cap
+
+
+def _v5e():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
+        pytest.skip("no TPU AOT topology here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_ring_decode_kernel_compiles_for_v5e_without_copying_a_ring(mosaic):
+    """The window-and-full cell's sliding layers (97 slots, rings of
+    1280 rows for a window of 1024, 4 kv heads of 128, 8 query heads
+    each): Mosaic must take the kernel with the wrapped block lookup,
+    and the ring stays where it is."""
+    from tensorflowonspark_tpu.ops import paged_attention as pa
+
+    dev = _v5e()
+    b, rows, h, hkv, d = 97, 1280, 32, 4, 128
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    bank = arg((b, rows, hkv, d), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v, pos, pad: pa.bank_attention(
+        q, k, v, pos, pad, window=1024, ring=True)).lower(
+            arg((b, h, d), jnp.bfloat16), bank, bank,
+            arg((b,), jnp.int32), arg((b,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ring_bytes = b * rows * hkv * d * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < ring_bytes // 8
+
+
+@pytest.mark.parametrize("layer,rows", [(0, 1280), (3, 10240)])
+def test_a_long_prompt_s_layer_compiles_for_v5e_with_its_scores_in_vmem(
+        mosaic, layer, rows):
+    """One attention layer of the window-and-full cell prefilling its
+    longest bucket (8192 tokens, 32 query heads over 4 kv heads of 128)
+    into one lane — a ring of 1280 on a sliding layer, a bank of 10240
+    on a full one: the prompt goes through the flash kernel (banded on
+    the sliding layer, YaRN on the full one), so no float32 score
+    tensor ``[32, 8192, keys]`` (10.7 GB over the bank) is ever in HBM:
+    the plan's temporaries stay under a gigabyte."""
+    from tensorflowonspark_tpu.models import transformer as tr
+
+    dev = _v5e()
+    cfg = tr.TransformerConfig(
+        num_heads=32, num_kv_heads=4, head_dim=128, embed_dim=2304,
+        max_seq_len=131072, qk_norm=True, num_layers=4,
+        layer_types=["sliding_attention"] * 3 + ["full_attention"],
+        sliding_window=1024, fresh_prompts=True, layer_rope={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 8192,
+                "beta_fast": 32, "beta_slow": 1,
+                "attention_factor": 1.2772588722239782},
+            "sliding_attention": {
+                "rope_type": "default", "rope_theta": 500000}})
+    assert tr.bank_rows(cfg, layer, 10240) == rows
+    assert tr.prefill_flash(cfg, 8192)
+    attn = tr.Attention(cfg, layer=layer)
+    x = jnp.zeros((1, 8192, cfg.embed_dim), jnp.bfloat16)
+    pos = jnp.zeros((1, 8192), jnp.int32)
+    shapes = jax.eval_shape(
+        lambda: attn.init(jax.random.PRNGKey(0), x[:, :1], pos[:, :1]))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.dtype == jnp.float32 else a.dtype,
+            sharding=dev), tree)
+
+    bank = jax.ShapeDtypeStruct((1, rows, 4, 128), jnp.bfloat16, sharding=dev)
+    cache = {"cached_key": bank, "cached_value": bank}
+
+    def prefill(params, cache, x, pos, pad):
+        return attn.apply({"params": params, "cache": cache}, x, pos,
+                          decode=True, pad_start=pad, mutable=["cache"])
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        on_chip(shapes["params"]), cache, on_chip(x), on_chip(pos),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=dev)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
