@@ -18,9 +18,12 @@ outputs are never gathered back.
 
 Kernel shapes (grid ``(F//bf, T)`` — row tiles innermost so that
 consecutive tiles of the same expert reuse the resident weight block;
-the full weight matrix is DMA'd exactly once per ``bf`` stripe):
+the full weight matrix is DMA'd exactly once per ``bf`` stripe, and
+each row tile of ``x`` once per stripe, ``F / bf`` times):
 
-- forward  ``y[t] = x[t] @ w[tile_expert[t]]``
+- forward  ``y[t] = x[t] @ w[tile_expert[t]]``, over ONE stripe of the
+  whole width where its blocks and float32 product fit VMEM (each row
+  tile and each expert's weights read once), else narrow stripes
 - dx       ``dx[t] = dy[t] @ w[tile_expert[t]].T`` with ``w`` read in
   its STORED ``[E, D, F]`` layout (lane-dim contraction, full-``F``
   resident blocks); falls back to a transposed HBM copy + the forward
@@ -114,6 +117,18 @@ def _pick_bf(bm, d, f, bf=None, itemsize=2):
     return best if best else f
 
 
+def _forward_bf(bm, d, f, bf=None, itemsize=2):
+    """The forward's stripe: the whole width ``f`` where its
+    double-buffered blocks and the float32 product fit the budget — one
+    stripe, so each row tile of ``x`` is read once and not ``f / bf``
+    times — else :func:`_pick_bf`'s, which also keeps a pinned ``bf``
+    the caller's."""
+    whole = 2 * itemsize * (bm * d + d * f + bm * f) + 4 * bm * f
+    if bf is None and whole <= _VMEM_BUDGET:
+        return f
+    return _pick_bf(bm, d, f, bf, itemsize=itemsize)
+
+
 def gmm_call(x, w, tile_expert, *, bm=256, bf=None, interpret=None,
              live_tiles=None):
     """Raw forward: ``y[N, F]`` for sorted ``x[N, D]``, ``w[E, D, F]``.
@@ -133,7 +148,7 @@ def gmm_call(x, w, tile_expert, *, bm=256, bf=None, interpret=None,
     assert n % bm == 0, (n, bm)
     t = n // bm
     assert tile_expert.shape == (t,), (tile_expert.shape, t)
-    bf = _pick_bf(bm, d, f, bf, itemsize=x.dtype.itemsize)
+    bf = _forward_bf(bm, d, f, bf, itemsize=x.dtype.itemsize)
     assert f % bf == 0, (f, bf)
     # the scalars the index maps see: tile_expert, and live_tiles when
     # only the first so many row tiles hold rows (the others stay on

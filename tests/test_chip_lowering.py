@@ -147,13 +147,19 @@ def test_bank_decode_kernel_compiles_for_v5e_without_copying_a_bank(
     assert compiled.memory_analysis().temp_size_in_bytes < bank_bytes // 8
 
 
-@pytest.mark.parametrize("rows,bm", [(17 * 8, 16), (1024 * 8, 256)])
+@pytest.mark.parametrize("held,d,f,rows,bm", [
+    (16, 6144, 2048, 17 * 8, 16), (16, 6144, 2048, 1024 * 8, 256),
+    (64, 2304, 896, 97 * 8, 16), (64, 2304, 896, 8192 * 8, 256),
+])
 def test_share_grouped_matmul_compiles_for_v5e_at_both_row_tiles(
-        mosaic, rows, bm):
-    """The routed experts of the latent-attention cell at published
-    widths (16 held experts, 6144 x 2048): a decode step's 136
+        mosaic, held, d, f, rows, bm):
+    """The routed experts at published widths: the latent-attention
+    cell's (16 held experts, 6144 x 2048: a decode step's 136
     assignments in row tiles of 16, a prompt chunk's 8192 in tiles of
-    256 — Mosaic must take the live-tile kernel at both."""
+    256) and the window-and-full cell's (64 held, 2304 x 896: 97
+    slots' 776 assignments, a prompt of 8192's 65,536), whose forward
+    takes each product's whole width as one stripe — Mosaic must take
+    the live-tile kernel at both."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -163,7 +169,6 @@ def test_share_grouped_matmul_compiles_for_v5e_at_both_row_tiles(
     except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
         pytest.skip("no TPU AOT topology here: %s" % e)
     dev = SingleDeviceSharding(topo.devices[0])
-    held, d, f = 16, 6144, 2048
     t = -(-rows // bm) + held
 
     def arg(shape, dtype):
