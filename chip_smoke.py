@@ -26,7 +26,11 @@ driver and executor processes stay off JAX as well.
 Exit code 0 and the verdict as the last stdout line — exactly
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``,
 the device as JAX reports it — only when every phase passed on a TPU; the
-line before it (``summary: {...}``) carries the per-phase detail.
+line before it (``summary: {...}``) carries the per-phase detail, each
+phase's ``compile_sec`` / ``cache_hits`` / ``cache_misses`` read from the
+program's ``jit.compile`` spans (a miss: a compile that asked the
+persistent cache and did not find its executable; all three None with
+``TFOS_TELEMETRY=0``).
 ``--tiny`` together with ``JAX_PLATFORMS=cpu`` runs the same code at toy
 sizes on the CPU (kernels interpreted) to debug the command; its output
 says it is not a chip run and its verdict names platform ``cpu``.
@@ -85,36 +89,32 @@ def sizes(tiny):
 # ----------------------------------------------------------------------
 
 
-class CompileMeter(object):
+def compile_report():
     """Seconds this process spent in XLA/Mosaic compiles (cache reads
-    included) and its persistent-cache hits/misses, from JAX's own
-    monitoring events."""
+    included) and its persistent-cache hits and misses: the program's
+    own ``jit.compile`` spans (``tracing.watch_jit``, installed with the
+    compile cache by :func:`claim_device`).  ``cache_misses`` counts the
+    compiles that asked the cache and did not find their executable
+    (before PR 38 it counted the cache's writes).  None for each where
+    telemetry is off (``TFOS_TELEMETRY=0``): no span was recorded."""
+    from tensorflowonspark_tpu import telemetry
 
-    def __init__(self):
-        import jax
+    tracer = telemetry.get_tracer()
+    if not tracer.enabled:
+        return dict.fromkeys(("compile_sec", "cache_hits", "cache_misses"))
+    spans = tracer.spans(name="jit.compile")
+    caches = [s["attrs"]["cache"] for s in spans]
+    return {
+        "compile_sec": round(sum(s["dur"] for s in spans), 2),
+        "cache_hits": caches.count("hit"),
+        "cache_misses": caches.count("miss"),
+    }
 
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._event)
 
-    def _dur(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def report(self):
-        return {
-            "compile_sec": round(self.seconds, 2),
-            "cache_hits": self.hits,
-            "cache_misses": self.misses,
-        }
+def _total(counts):
+    """The sum of the processes' counts; None where one read none."""
+    counts = list(counts)
+    return None if None in counts else sum(counts)
 
 
 def claim_device(tiny):
@@ -187,7 +187,6 @@ def emit(result):
 def phase_kernels(tiny):
     sz = sizes(tiny)
     device = claim_device(tiny)
-    meter = CompileMeter()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -345,7 +344,7 @@ def phase_kernels(tiny):
             )
 
     emit(dict(phase="kernels", device=device, interpreted=interpreted,
-              rel_err=errs, **meter.report()))
+              rel_err=errs, **compile_report()))
 
 
 # ----------------------------------------------------------------------
@@ -362,7 +361,6 @@ def _train_main(args, ctx):
     # device query (a no-op for a single-process cluster)
     ctx.initialize_distributed()
     device = claim_device(tiny)
-    meter = CompileMeter()
     import jax
     import jax.numpy as jnp
     import optax
@@ -442,7 +440,7 @@ def _train_main(args, ctx):
         state_devices=len(holders), mesh_devices=int(mesh.size),
         sharded_leaves=sharded, peak_gib_by_device=mem,
         build_sec=round(t_built - t_start, 1),
-        train_sec=round(t_trained - t_built, 1), **meter.report()
+        train_sec=round(t_trained - t_built, 1), **compile_report()
     ))
 
 
@@ -517,7 +515,7 @@ def run_cluster(tiny, label, mesh, executors=1, chips_per_node=None,
     for rep in reports:
         losses = [l for _, l in rep["losses"]]
         print("%s: mesh=%s params=%.0fM steps=%d losses=%s ring_records=%d "
-              "peak_GiB=%s build=%.1fs train=%.1fs compile=%.1fs" % (
+              "peak_GiB=%s build=%.1fs train=%.1fs compile=%ss" % (
                   label, rep["mesh"], rep["n_params"] / 1e6, rep["steps"],
                   ["%.4f" % l for l in losses],
                   rep["wire"]["ring_records"], rep["peak_gib_by_device"],
@@ -546,8 +544,8 @@ def run_cluster(tiny, label, mesh, executors=1, chips_per_node=None,
         ring_records=[r["wire"]["ring_records"] for r in reports],
         peak_gib_by_device=[r["peak_gib_by_device"] for r in reports],
         compile_sec=first["compile_sec"],
-        cache_hits=sum(r["cache_hits"] for r in reports),
-        cache_misses=sum(r["cache_misses"] for r in reports),
+        cache_hits=_total(r["cache_hits"] for r in reports),
+        cache_misses=_total(r["cache_misses"] for r in reports),
     )
 
 
@@ -651,7 +649,6 @@ def _serve_once(predict, rows, sz, replicas=1):
 
 def phase_serve(tiny):
     device = claim_device(tiny)
-    meter = CompileMeter()
     from tensorflowonspark_tpu.models import transformer as tr
 
     sz, params, rows, config = _serve_setup(tiny)
@@ -688,14 +685,13 @@ def phase_serve(tiny):
     emit(dict(phase="serve", device=device, layouts=report,
               layout_token_agreement=round(share, 4),
               first_decoded_agreement=round(first_decoded, 4),
-              **meter.report()))
+              **compile_report()))
 
 
 def phase_mc_replicas(tiny):
     """predict_rows(replicas=4): one replica per chip — four distinct
     devices across the replicas' weights and KV pools."""
     device = claim_device(tiny)
-    meter = CompileMeter()
     import jax
 
     from tensorflowonspark_tpu.models import transformer as tr
@@ -733,7 +729,7 @@ def phase_mc_replicas(tiny):
         raise RuntimeError(
             "replicas do not own one distinct device each: %s" % placements)
     emit(dict(phase="mc_replicas", device=device, placements=placements,
-              **meter.report()))
+              **compile_report()))
 
 
 PHASES = {
@@ -864,8 +860,8 @@ def main(argv=None):
         added = cache_entries(cache) - before
         res.update(wall_sec=round(wall, 1), cache_entries_added=added)
         results[name] = res
-        print("phase %-13s ok: wall %.1fs, compiling %.1fs, cache %s "
-              "+%d entries (%d hits / %d misses)" % (
+        print("phase %-13s ok: wall %.1fs, compiling %ss, cache %s "
+              "+%d entries (%s hits / %s misses)" % (
                   name, wall, res.get("compile_sec", 0.0), cache, added,
                   res.get("cache_hits", 0), res.get("cache_misses", 0)),
               flush=True)

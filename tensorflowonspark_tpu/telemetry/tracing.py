@@ -26,6 +26,11 @@ beside the device, and the same region can be read from the ring and
 from the capture.  :func:`attribute` says which span covers each piece
 of a set of intervals (the device's idle gaps, say).
 
+:func:`watch_jit` puts JAX's own set-up in the same ring: the backend's
+creation (``setup.device``) and every outermost trace, lowering and
+compile (``jit.trace`` / ``jit.lower`` / ``jit.compile``), with JAX's own
+starts and ends.
+
 Export is Chrome-trace JSON (``{"traceEvents": [...]}``) loadable in
 ``chrome://tracing`` / Perfetto; ``ts`` is Unix microseconds.
 
@@ -37,6 +42,7 @@ no-op — nothing allocates, nothing is retained.
 import collections
 import itertools
 import json
+import logging
 import os
 import sys
 import threading
@@ -465,6 +471,153 @@ def profile_start_ns(xplane_path):
             start = dict(plane.stats).get("profile_start_time")
             return None if start is None else int(start)
     return None
+
+
+#: JAX's compile pipeline as it reports itself on ``jax.monitoring``
+#: (``jax/_src/dispatch.py``, JAX 0.9): a scalar at each stage's entry
+#: (its start), then a time span at its end, both with ``fun_name``
+_JIT_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+#: persistent-cache events fired inside ``compile_or_get_cached``, on
+#: the compiling thread: the request used the cache (a miss unless a
+#: hit follows), and the executable was read back
+_CACHE_STATES = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+#: what ``jax/_src/xla_bridge.py`` logs (at DEBUG) round each backend's
+#: creation — TPU init, on a chip
+_BACKEND_LOGGER = "jax._src.xla_bridge"
+_BACKEND_START = "Initializing backend '%s'"
+_BACKEND_END = "Backend '%s' initialized"
+
+_WATCH_LOCK = threading.Lock()
+_WATCHING = False
+_watch_local = threading.local()
+
+
+def _open_stages():
+    """This thread's stages that have begun and not ended, outermost
+    first: ``[stage, inner traces, cache]`` each."""
+    stages = getattr(_watch_local, "stages", None)
+    if stages is None:
+        stages = _watch_local.stages = []
+    return stages
+
+
+def _on_stage_entry(event, value, **_):
+    stage = _JIT_STAGES.get(event)
+    if stage is not None and get_tracer().enabled:
+        _open_stages().append([stage, 0, "off"])
+
+
+def _on_cache_event(event, **_):
+    state = _CACHE_STATES.get(event)
+    if state is None:
+        return
+    for frame in reversed(_open_stages()):
+        if frame[0] == "jit.compile":
+            frame[2] = state
+            return
+
+
+def _on_stage_end(event, start, end, fun_name=None, **_):
+    stage = _JIT_STAGES.get(event)
+    tracer = get_tracer()
+    if stage is None or not tracer.enabled:
+        return
+    stages = _open_stages()
+    at = next((i for i in range(len(stages) - 1, -1, -1)
+               if stages[i][0] == stage), None)
+    if at is None:
+        return  # its entry came before the watch, or with tracing off
+    _, nested, cache = stages[at]
+    del stages[at:]
+    if stage == "jit.trace" and stages:
+        # a trace inside another trace, or inside a lowering (rules
+        # lowered through traced Python): counted by the stage that
+        # holds it, never a span of its own (a step holds thousands)
+        stages[-1][1] += 1 + nested
+        return
+    attrs = {"fun": fun_name, "nested": nested}
+    if stage == "jit.compile":
+        attrs["cache"] = cache
+    tracer.add(stage, start, end - start, trace="jit", **attrs)
+
+
+class _BackendInitFilter(logging.Filter):
+    """Turns the backend-creation records of ``jax._src.xla_bridge``
+    into ``setup.device`` spans, one a platform, and lets through to the
+    handlers only the records the logger would have let through without
+    the watch: its own level where it had one, else its parents' level
+    as it is now."""
+
+    def __init__(self, logger):
+        super(_BackendInitFilter, self).__init__()
+        self.logger = logger
+        self.own = logger.level
+        self._started = {}
+
+    def filter(self, record):
+        tracer = get_tracer()
+        if tracer.enabled and record.msg in (_BACKEND_START, _BACKEND_END):
+            platform = str(record.args[0])
+            if record.msg == _BACKEND_START:
+                self._started[platform] = tracer.now()
+            elif record.msg == _BACKEND_END and platform in self._started:
+                t0 = self._started.pop(platform)
+                tracer.add("setup.device", t0, tracer.now() - t0,
+                           trace="setup", platform=platform)
+        floor = self.own or self.logger.parent.getEffectiveLevel()
+        return record.levelno >= floor
+
+
+def watch_jit():
+    """Record JAX's own set-up as spans of the process-wide tracer, from
+    now on; idempotent (one registration a process).
+
+    - ``jit.trace``: each OUTERMOST trace of a program's Python;
+    - ``jit.lower``: each lowering to MLIR;
+    - ``jit.compile``: each ``compile_or_get_cached``, with ``cache``
+      — ``hit`` (the executable was read back), ``miss`` (the cache
+      was asked and compiled) or ``off`` (no cache);
+    - ``setup.device``: each backend's creation, with ``platform``,
+      from the two DEBUG records ``jax._src.xla_bridge`` logs round it
+      (JAX has no monitoring event there).  To have them made, the
+      watch sets that ONE logger's level to DEBUG; a filter lets through
+      to the handlers only what the logger would have let through
+      without it (its own level before the watch, else its parents'),
+      so the logs read as before.  A JAX that rewords the two records,
+      or a logger set above DEBUG later, loses this span alone
+      (``tests/test_setup_spans.py`` fails on the first).
+
+    Each ``jit.*`` span has ``fun`` and ``nested``: the traces that ran
+    inside it on its thread (inner ``jit`` calls; Python traced while a
+    rule lowers), counted there and never spans of their own, so the
+    sum over the spans of ``nested``, plus one a ``jit.trace``, is every
+    trace JAX reported.  Trace ids ``jit`` and ``setup``; starts and ends are JAX's own,
+    Unix seconds, so the spans land on the profiler's timeline beside
+    every other.  Nothing is paid on a warm call of a compiled program
+    (JAX reports nothing there); with the tracer disabled the listeners
+    return at once.  Installed by
+    :func:`~tensorflowonspark_tpu.utils.compile_cache.ensure_compile_cache`,
+    which every chip owner calls before its first program."""
+    global _WATCHING
+    with _WATCH_LOCK:
+        if _WATCHING:
+            return
+        from jax import monitoring
+
+        monitoring.register_scalar_listener(_on_stage_entry)
+        monitoring.register_event_listener(_on_cache_event)
+        monitoring.register_event_time_span_listener(_on_stage_end)
+        logger = logging.getLogger(_BACKEND_LOGGER)
+        logger.addFilter(_BackendInitFilter(logger))
+        logger.setLevel(logging.DEBUG)
+        _WATCHING = True
 
 
 _GLOBAL = None

@@ -14,6 +14,11 @@ runners, the test session):
   ``chdir`` into per-run temp directories — and never a per-user or
   ``/tmp`` path: the directory is part of every cache key, so a cache
   that moves never hits.
+
+Either way :func:`ensure_compile_cache` also installs
+``telemetry.tracing.watch_jit``: from then on the process's traces,
+lowerings, compiles (cache hit or miss) and backend creation are spans
+of its tracer (trace ids ``jit`` and ``setup``).
 """
 
 import os
@@ -37,9 +42,13 @@ def cache_dir():
 
 
 def ensure_compile_cache():
-    """Point this process's JAX at :func:`cache_dir`; call before the
-    first compile.  A no-op when the environment already placed the
+    """Point this process's JAX at :func:`cache_dir` and watch its
+    compile pipeline (``tracing.watch_jit``); call before the first
+    compile.  Places nothing when the environment already placed the
     cache.  Returns the directory in effect."""
+    from tensorflowonspark_tpu.telemetry import tracing
+
+    tracing.watch_jit()
     if ENV_VAR not in os.environ:
         import jax
 
