@@ -228,8 +228,8 @@ SPAN_ROWS = 512
 SPAN_CHUNK_BYTES = 256 * 2 ** 20
 #: names (``jax.ad_checkpoint.checkpoint_name``) of a span's routing
 #: that a rematerialised block keeps for its backward
-#: (``Transformer``'s ``remat_policy="block"`` saves these and nothing
-#: else): the chosen experts and the sorted rows' pairs, 4 bytes a
+#: (``Transformer``'s ``remat_policy="block"`` saves them beside flash's
+#: ``FLASH_SAVED``): the experts chosen and the sorted rows' pairs, 4 bytes a
 #: pair each (393 + 401 KB a Moonlight layer).  The backward then
 #: starts from the saved routing: no second top-k, no second sort — a
 #: fifth of what the span adds to a v5e step's executable

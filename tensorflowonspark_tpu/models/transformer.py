@@ -53,8 +53,11 @@ class TransformerConfig:
     mesh: object = None
     seq_axis: str = "seq"
     remat: bool = False  # jax.checkpoint each block (HBM for FLOPs)
-    #: remat granularity when ``remat`` is set: ``"block"`` recomputes
-    #: the whole block in backward (max HBM savings, ~+1/3 step FLOPs);
+    #: remat granularity when ``remat`` is set: ``"block"`` keeps the
+    #: block's input, a sigmoid-routed span's routing
+    #: (``moe.SPAN_SAVED``) and the flash kernels' output and lse
+    #: (``flash_attention.FLASH_SAVED``) and recomputes the rest of the
+    #: block in backward (max HBM savings, ~+1/3 step FLOPs);
     #: ``"dots"`` saves matmul outputs and recomputes only elementwise
     #: ops (checkpoint_policies.dots_with_no_batch_dims_saveable) — the
     #: MXU does no second pass, so MFU stays at the 6N accounting.
@@ -965,16 +968,20 @@ class Transformer(nn.Module):
             # remat is a training trade (recompute in backward); decode
             # has no backward, and the wrapped call must not see the
             # python-bool flag (jax.checkpoint would try to trace it)
-            # "block" keeps the block's input and, of a sigmoid-routed
-            # span, its routing's integers (a block that names nothing
+            # "block" keeps the block's input, of a sigmoid-routed span
+            # its routing's integers, and of attention through the flash
+            # kernels their output and lse (a block that names nothing
             # saves nothing, as under no policy at all)
             from tensorflowonspark_tpu.models.moe import SPAN_SAVED
+            from tensorflowonspark_tpu.ops.flash_attention import (
+                FLASH_SAVED,
+            )
 
             policy = (
                 jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                 if cfg.remat_policy == "dots"
                 else jax.checkpoint_policies.save_only_these_names(
-                    *SPAN_SAVED)
+                    *SPAN_SAVED, *FLASH_SAVED)
             )
             block = nn.remat(Block, static_argnums=(), policy=policy)
             sel = None

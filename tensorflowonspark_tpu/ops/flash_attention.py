@@ -38,11 +38,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from tensorflowonspark_tpu import compat
 
 NEG_INF = -1e30  # finite mask sentinel: keeps exp() at 0 without NaNs
+#: names (``jax.ad_checkpoint.checkpoint_name``) of the differentiated
+#: forward's output and log-sum-exp, the residuals the backward kernels
+#: read besides q, k and v.  ``Transformer``'s ``remat_policy="block"``
+#: keeps them, so a rematerialised block's backward makes q, k and v
+#: again but runs no second forward kernel.  The primal-only path
+#: (serving prefill) names nothing
+FLASH_SAVED = ("flash_out", "flash_lse")
 
 
 def _scratch(shape, dtype):
@@ -568,7 +576,13 @@ def _flash(q, k, v, scale, causal, block_q, block_k, window):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window):
-    return _fwd(q, k, v, scale, causal, block_q, block_k, window)
+    # the named output is both what the caller gets and the residual:
+    # a rematerialised block that keeps FLASH_SAVED (the out-projection's
+    # backward reads the same value) never runs this kernel again
+    out, (*_, lse) = _fwd(q, k, v, scale, causal, block_q, block_k, window)
+    out = checkpoint_name(out, FLASH_SAVED[0])
+    lse = checkpoint_name(lse, FLASH_SAVED[1])
+    return out, (q, k, v, out, lse)
 
 
 _flash.defvjp(_flash_fwd, _bwd)

@@ -439,6 +439,99 @@ def test_a_rematerialised_block_keeps_a_span_s_routing_and_no_row(policy):
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
+#: a dense model of two layers through the same flash kernels
+DENSE = tr.TransformerConfig(
+    vocab_size=128, num_layers=2, num_heads=4, head_dim=16, embed_dim=64,
+    mlp_dim=96, max_seq_len=SEQ, dtype="float32", attention_impl="flash",
+    block_q=32, block_k=32, remat=True)
+
+
+def _kernels(jaxpr, found=None):
+    """``{kernel function's name: pallas calls}`` over a jaxpr and every
+    jaxpr inside it."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["jaxpr"].debug_info.func_src_info.split()[0]
+            found[name] = found.get(name, 0) + 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernels(sub, found)
+    return found
+
+
+@pytest.fixture(scope="module", params=["mla", "dense"])
+def remat_case(request):
+    """A 2-layer model through flash under ``remat_policy="block"``:
+    what its gradient runs, what its remat saves, and its gradient, each
+    as the block's policy is now and as it was when it kept a span's
+    routing alone."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        1, 128, (ROWS, SEQ)), jnp.int32)
+    if request.param == "mla":
+        cfg = dict(REMAT, num_hidden_layers=2)
+        params = weights.make_params(cfg, 2 ** 31 + 5, jnp.float32)
+        loss_of = moe.sigmoid_moe_loss_fn(runner.program_model(cfg, SEQ))
+
+        def loss(p):
+            return loss_of(p, {"tokens": tokens}, None)[0]
+    else:
+        model = tr.Transformer(DENSE)
+        params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+        loss_of = tr.loss_fn(model)
+
+        def loss(p):
+            return loss_of(p, {"tokens": tokens}, None)
+
+    def reading():
+        return (_kernels(jax.make_jaxpr(jax.grad(loss))(params).jaxpr),
+                jax.jit(jax.grad(loss))(params))
+
+    kernels, grads = reading()
+    kept = [(aval.shape, why) for aval, why in saved_residuals(loss, params)
+            if "flash_attention" in why]
+    keep = jax.checkpoint_policies.save_only_these_names
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax.checkpoint_policies, "save_only_these_names",
+                   lambda *names: keep(*(n for n in names
+                                         if n not in fa.FLASH_SAVED)))
+        kernels_before, grads_before = reading()
+    return kernels, kernels_before, kept, grads, grads_before
+
+
+def test_a_rematerialised_block_runs_flash_s_forward_once_a_layer(
+        remat_case):
+    kernels, before, _, _, _ = remat_case
+    # two layers: a forward, a dq and a dk/dv kernel each, where the
+    # block's backward ran the forward kernel again to get its residuals
+    assert {k: kernels[k] for k in ("_fwd_kernel", "_dq_kernel",
+                                    "_dkv_kernel")} == {
+        "_fwd_kernel": 2, "_dq_kernel": 2, "_dkv_kernel": 2}, kernels
+    assert before["_fwd_kernel"] == 4, before
+
+
+def test_a_rematerialised_block_keeps_flash_s_output_and_lse(remat_case):
+    _, _, kept, _, _ = remat_case
+    # each layer's [B, S, H, dv] context and [B, H, S, 1] lse, and
+    # nothing else of the kernel (q, k and v are made again)
+    assert sorted(shape for shape, _ in kept) == sorted(
+        [(ROWS, SEQ, 4, 16)] * 2 + [(ROWS, 4, SEQ, 1)] * 2), kept
+    assert all("named 'flash_lse'" in why
+               for shape, why in kept if shape[-1] == 1), kept
+
+
+def test_a_rematerialised_block_s_gradient_is_the_one_it_made_twice(
+        remat_case):
+    _, _, _, grads, before = remat_case
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(before)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert any(np.any(np.asarray(g)) for g in jax.tree.leaves(grads))
+
+
 @pytest.mark.parametrize("rows,differentiable", [
     (17, False), (17, True), (511, False)])
 def test_fewer_rows_than_a_span_take_the_one_pass_over_tiles_of_16(
@@ -508,14 +601,16 @@ def test_flash_with_its_own_value_head_is_the_einsum_form(
             np.asarray(got), np.asarray(ref_), rtol=0, atol=1e-4)
 
 
-#: sha256 of the traced program (forward and the three gradient
-#: kernels, their grids and block maps) of ``flash_attention`` at EQUAL
-#: head sizes, recorded from the parent commit (e82dcf5): the same
-#: characters are the same arithmetic, so the cells that train through
-#: these kernels at one head size get bit-equal numbers
+#: sha256 of the lowered StableHLO (forward and the three gradient
+#: kernels, their grids and block maps) of ``flash_attention``'s
+#: gradient at EQUAL head sizes, recorded from the parent commit
+#: (ac6ae75): the same characters are the same arithmetic, so the cells
+#: that train through these kernels at one head size get bit-equal
+#: numbers.  The lowered text and not the jaxpr: a checkpoint name is an
+#: equation of the jaxpr and lowers to nothing
 PARENT_FLASH_PROGRAMS = {
-    (0, 2): "6b501788adb5661d339b7cab95fd0c43db0af3b5100b4b52e53e8a3a0039622c",
-    (160, 1): "61d2666efe89d70944437e2c7f4ac0bfdc5795b741ee96b8e438d7dfab537ff1",
+    (0, 2): "9975f1d2be12dd97e8b2700fde7eeca02ee7b1c8910c106fb964e5b730cf9d7c",
+    (160, 1): "91dfe9d2bb86152d91c700dbf96f94e15951524b152921e3611cf7c4160d6c0e",
 }
 
 
@@ -529,8 +624,13 @@ def test_flash_at_equal_head_sizes_is_the_parent_s_program(window, hkv):
             q, k, v, causal=True, block_q=128, block_k=128,
             window=window).astype(jnp.float32).sum()
 
-    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k))
-    text = re.sub(r" at [^\s\]]+:\d+", "", text)   # no source positions
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).as_text()   # no source positions in it
+    # private functions renumbered in order of appearance: the numbers
+    # the lowering gives them count more than what the module keeps
+    seen = {}
+    text = re.sub(r"@(\w+?)_\d+\b", lambda m: "@%s_%d" % (
+        m.group(1), seen.setdefault(m.group(0), len(seen))), text)
     assert hashlib.sha256(text.encode()).hexdigest() == (
         PARENT_FLASH_PROGRAMS[window, hkv])
 
