@@ -134,6 +134,10 @@ class TransformerConfig:
     #: RMS-norm each head's query and key over ``head_dim`` with a
     #: learned scale, before the rotation (``attn/q_norm``, ``k_norm``)
     qk_norm: bool = False
+    #: "" (none) | "per-head": each head's output times ``sigmoid(x
+    #: W_g)``, one scalar a head and token from the layer's normed
+    #: input (``attn/gate``, ``[embed, heads]``), before ``out``
+    gating: str = ""
     # -- per-layer pattern --------------------------------------------
     #: attention of each layer, "sliding_attention" (sees the last
     #: ``sliding_window`` positions) | "full_attention" (full causal);
@@ -143,9 +147,15 @@ class TransformerConfig:
     #: RoPE of each layer TYPE, where the types differ: ``{type:
     #: {"rope_theta", and for YaRN "rope_type": "yarn", "factor",
     #: "original_max_position_embeddings", "beta_fast", "beta_slow",
-    #: "attention_factor"}}`` (the published ``rope_parameters``; held
-    #: as sorted item tuples).  Empty: ``rope_theta`` on every layer
+    #: "attention_factor"; "partial_rotary_factor": the leading share
+    #: of ``head_dim`` that rotates, 1 if absent}}`` (the published
+    #: ``rope_parameters``; held as sorted item tuples).  Empty:
+    #: ``rope_theta`` on every layer
     layer_rope: tuple = ()
+    #: query heads of each layer, where they differ by layer (empty:
+    #: ``num_heads`` on every layer); needs ``num_kv_heads``, which
+    #: must divide each
+    num_attention_heads_per_layer: tuple = ()
     #: FFN of each layer, "dense" | "sparse" (empty: every layer dense,
     #: or every layer the softmax MoE above when num_experts > 0)
     mlp_layer_types: tuple = ()
@@ -190,13 +200,20 @@ class TransformerConfig:
                 (kind, tuple(sorted(dict(v).items())))
                 for kind, v in rope_of.items()))
         object.__setattr__(self, "layer_rope", tuple(rope_of or ()))
-        for name in ("mlp_layer_types", "indexer_types", "layer_types"):
+        for name in ("mlp_layer_types", "indexer_types", "layer_types",
+                     "num_attention_heads_per_layer"):
             val = tuple(getattr(self, name) or ())
             object.__setattr__(self, name, val)
             if val and len(val) != self.num_layers:
                 raise ValueError(
                     "{0} names {1} layers, num_layers is {2}".format(
                         name, len(val), self.num_layers))
+        if self.num_attention_heads_per_layer and not self.num_kv_heads:
+            # the banks hold num_kv_heads on every layer
+            raise ValueError(
+                "num_attention_heads_per_layer needs num_kv_heads")
+        if self.gating not in ("", "per-head"):
+            raise ValueError("gating %r is not built" % (self.gating,))
 
     @property
     def jdtype(self):
@@ -228,12 +245,30 @@ class TransformerConfig:
         sliding = self.layer_types[layer] == "sliding_attention"
         return self.sliding_window if sliding else 0
 
+    def heads_of(self, layer):
+        """Query heads of layer ``layer``."""
+        if self.num_attention_heads_per_layer:
+            return int(self.num_attention_heads_per_layer[layer])
+        return self.num_heads
+
+    def rotary_of(self, layer):
+        """Leading dimensions of a head that layer ``layer`` rotates:
+        ``head_dim`` times its type's ``partial_rotary_factor`` (all of
+        ``head_dim`` where none is given); the rest pass unrotated."""
+        kinds = dict(self.layer_rope)
+        if not (self.layer_types and kinds):
+            return self.head_dim
+        share = dict(kinds[self.layer_types[layer]]).get(
+            "partial_rotary_factor", 1)
+        return int(self.head_dim * float(share))
+
     def rope_of(self, layer):
         """``(theta, inv_freq, factor)`` of layer ``layer``'s rotation:
         ``inv_freq`` None and ``factor`` 1 under the default RoPE of
         base ``theta``; under YaRN the layer type's blended
-        frequencies (:func:`yarn_inv_freq`) and the factor that
-        multiplies cos and sin."""
+        frequencies over the rotated width (:func:`yarn_inv_freq`,
+        :meth:`rotary_of`) and the factor that multiplies cos and
+        sin."""
         kinds = dict(self.layer_rope)
         if not (self.layer_types and kinds):
             return self.rope_theta, None, 1.0
@@ -244,7 +279,7 @@ class TransformerConfig:
         if p["rope_type"] != "yarn":
             raise ValueError("rope_type %r is not built" % (p["rope_type"],))
         return theta, yarn_inv_freq(
-            self.head_dim, theta, float(p["factor"]),
+            self.rotary_of(layer), theta, float(p["factor"]),
             int(p["original_max_position_embeddings"]),
             float(p.get("beta_fast", 32)), float(p.get("beta_slow", 1)),
         ), float(p.get("attention_factor") or (
@@ -277,12 +312,21 @@ def yarn_inv_freq(dim, theta, factor, original, beta_fast, beta_slow):
 
 
 def rope(x, positions, max_wavelength=10000.0, interleave=False,
-         inv_freq=None, factor=1.0):
+         inv_freq=None, factor=1.0, rotary=None):
     """Rotary position embedding on ``[B, S, H, D]`` (D even): pair
     ``i`` is ``(i, i + D/2)``, or ``(2i, 2i + 1)`` with
     ``interleave``.  ``inv_freq`` (``[D/2]``) replaces the default
     ``max_wavelength ** (-2i / D)`` and ``factor`` multiplies cos and
-    sin (YaRN: :meth:`TransformerConfig.rope_of`)."""
+    sin (YaRN: :meth:`TransformerConfig.rope_of`).  ``rotary`` (even,
+    under ``D``): the leading ``rotary`` dimensions rotate as a head of
+    that size would — its pairs and frequencies reckoned over
+    ``rotary`` — and the rest pass through
+    (:meth:`TransformerConfig.rotary_of`)."""
+    if rotary is not None and rotary < x.shape[-1]:
+        return jnp.concatenate([
+            rope(x[..., :rotary], positions, max_wavelength, interleave,
+                 inv_freq, factor),
+            x[..., rotary:]], axis=-1)
     d = x.shape[-1]
     freq = max_wavelength ** (
         -jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2)
@@ -445,7 +489,8 @@ class Attention(nn.Module):
         cfg = self.cfg
         window = cfg.window_of(self.layer)
         theta, inv_freq, rope_factor = cfg.rope_of(self.layer)
-        h, d = cfg.num_heads, cfg.head_dim
+        rotary = cfg.rotary_of(self.layer)
+        h, d = cfg.heads_of(self.layer), cfg.head_dim
         hkv = cfg.num_kv_heads or h
         if h % hkv != 0:
             raise ValueError(
@@ -456,10 +501,19 @@ class Attention(nn.Module):
         dense = lambda name, feats: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, dtype=cfg.jdtype, name=name
         )
-        out_proj = lambda o: nn.DenseGeneral(  # noqa: E731
-            cfg.embed_dim, axis=(-2, -1), use_bias=False,
-            dtype=cfg.jdtype, name="out",
-        )(o)
+
+        def out_proj(o):
+            if cfg.gating:
+                # one gate a head and token, from the layer's normed
+                # input, on the head's output before it is mixed
+                gate = jax.nn.sigmoid(
+                    dense("gate", h)(x).astype(jnp.float32))
+                o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
+            return nn.DenseGeneral(
+                cfg.embed_dim, axis=(-2, -1), use_bias=False,
+                dtype=cfg.jdtype, name="out",
+            )(o)
+
         if cfg.fused_qkv:
             if hkv != h:
                 raise ValueError(
@@ -476,13 +530,13 @@ class Attention(nn.Module):
             q = RMSNorm(eps=cfg.rms_norm_eps, name="q_norm")(q)
             k = RMSNorm(eps=cfg.rms_norm_eps, name="k_norm")(k)
         q = rope(q, positions, theta, cfg.rope_interleave, inv_freq,
-                 rope_factor)
+                 rope_factor, rotary)
         k = rope(k, positions, theta, cfg.rope_interleave, inv_freq,
-                 rope_factor)
+                 rope_factor, rotary)
         if decode and cfg.kv_layout == "paged":
-            return self._paged_decode(
+            return out_proj(self._paged_decode(
                 x, q, k, v, positions, block_tables, hkv, d, window
-            )
+            ))
         if decode:
             # KV-cache autoregressive path: keys/values append at the
             # write pointer (cache stores POST-rope keys — RoPE is
@@ -706,7 +760,8 @@ class Attention(nn.Module):
 
     def _paged_decode(self, x, q, k, v, positions, block_tables, hkv, d,
                       window):
-        """Paged-KV decode (``kv_layout="paged"``): the per-layer cache
+        """Paged-KV decode (``kv_layout="paged"``), up to the output
+        projection, which the caller applies: the per-layer cache
         is ONE physical page pool ``[kv_pages, kv_page_tokens, Hkv,
         Dx]`` shared by every slot; ``block_tables [B, kv_slot_blocks]``
         maps each slot's logical blocks to physical pages.  New K/V
@@ -794,13 +849,7 @@ class Attention(nn.Module):
                 window=window,
                 k_scale_pool=ksp, v_scale_pool=vsp,
             )
-        return nn.DenseGeneral(
-            cfg.embed_dim,
-            axis=(-2, -1),
-            use_bias=False,
-            dtype=cfg.jdtype,
-            name="out",
-        )(out)
+        return out
 
 
 class MLP(nn.Module):
@@ -3038,6 +3087,30 @@ class SlotDecoder:
                     read += (last // t - first // t + 1) * t
                 reads[t, window] = read
             out.append(reads[t, window])
+        return out
+
+    def kv_read_by_kind(self, live):
+        """``{"ring", "whole"}``: the positions of :meth:`_layer_reads`
+        summed over the layers that keep rings and over those whose
+        banks are whole."""
+        out = {"ring": 0, "whole": 0}
+        for rows, read in zip(self._layer_rows, self._layer_reads(live)):
+            out["whole" if rows == self._bank_len else "ring"] += read
+        return out
+
+    def prefill_pairs(self, bucket):
+        """``{"window", "full"}``: the query-key pairs a prompt of
+        ``bucket`` tokens attends over (causal, and on a windowed layer
+        inside its window), times the layer's query heads, summed over
+        the windowed layers and over the full ones."""
+        cfg = self.model.cfg
+        n = int(bucket)
+        out = {"window": 0, "full": 0}
+        for layer in range(cfg.num_layers):
+            w = cfg.window_of(layer)
+            m = min(n, w) if w else n
+            pairs = m * (m + 1) // 2 + (n - m) * w
+            out["window" if w else "full"] += pairs * cfg.heads_of(layer)
         return out
 
     def kv_read_tokens(self, live):

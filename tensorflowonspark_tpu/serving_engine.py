@@ -1274,6 +1274,12 @@ class ServingEngine(object):
                                 self.decoder, "prefill_attn", None)
                             if attn_of is not None:
                                 sp.set("attn", attn_of(bucket))
+                            pairs_of = getattr(
+                                self.decoder, "prefill_pairs", None)
+                            for kind, n in (
+                                    pairs_of(bucket) if pairs_of else {}
+                            ).items():
+                                sp.set("attn_pairs_" + kind, n)
                         cached = int(getattr(
                             self.decoder, "last_admit_cached_tokens", 0
                         ))
@@ -1501,8 +1507,11 @@ class ServingEngine(object):
         from this scheduler's own record of every request in flight),
         ``kv_bank_tokens`` (what the banks hold) and, summed over the
         layers, ``attn_read_tokens`` against ``attn_context_tokens``
-        (positions read, index keys included, against positions live).
-        Nothing for a decoder that does not say (tests' fakes)."""
+        (positions read, index keys included, against positions live),
+        and ``kv_read_ring`` / ``kv_read_whole``: the positions read
+        summed over the layers that keep rings and over those whose
+        banks are whole.  Nothing for a decoder that does not say
+        (tests' fakes)."""
         reads = getattr(self.decoder, "kv_read_tokens", None)
         if reads is None:
             return {}
@@ -1517,6 +1526,9 @@ class ServingEngine(object):
         if over_layers is not None:
             attrs["attn_read_tokens"], attrs["attn_context_tokens"] = (
                 over_layers(live))
+        by_kind = getattr(self.decoder, "kv_read_by_kind", None)
+        for kind, n in (by_kind(live) if by_kind else {}).items():
+            attrs["kv_read_" + kind] = n
         return attrs
 
     def _run_chunk(self):
