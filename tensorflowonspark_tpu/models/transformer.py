@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from tensorflowonspark_tpu.models import base
 from tensorflowonspark_tpu.ops.attention import attention
+from tensorflowonspark_tpu.parallel import sharding as sh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -485,7 +486,9 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, decode=False, pad_start=None,
-                 per_slot=False, block_tables=None):
+                 per_slot=False, block_tables=None, stream=None):
+        """``stream``: the shardings of :func:`residual_stream`, where
+        ``x`` is the token-split normed stream of a training step."""
         cfg = self.cfg
         window = cfg.window_of(self.layer)
         theta, inv_freq, rope_factor = cfg.rope_of(self.layer)
@@ -507,19 +510,34 @@ class Attention(nn.Module):
                 # one gate a head and token, from the layer's normed
                 # input, on the head's output before it is mixed
                 gate = jax.nn.sigmoid(
-                    dense("gate", h)(x).astype(jnp.float32))
+                    (dense("gate", h)(x) if stream is None
+                     else gated).astype(jnp.float32))
                 o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
             return nn.DenseGeneral(
                 cfg.embed_dim, axis=(-2, -1), use_bias=False,
                 dtype=cfg.jdtype, name="out",
             )(o)
 
-        if cfg.fused_qkv:
-            if hkv != h:
-                raise ValueError(
-                    "fused_qkv requires equal q/kv head counts; use "
-                    "separate projections with num_kv_heads"
-                )
+        if cfg.fused_qkv and hkv != h:
+            raise ValueError(
+                "fused_qkv requires equal q/kv head counts; use "
+                "separate projections with num_kv_heads"
+            )
+        if stream is not None:
+            # every product of the normed input in one all-gather
+            shapes = ((("qkv", (3, h, d)),) if cfg.fused_qkv else (
+                ("q", (h, d)), ("k", (hkv, d)), ("v", (hkv, d))))
+            if cfg.gating:
+                shapes += (("gate", (h,)),)
+            outs = sh.gathered_products(x, tuple(
+                Kernel(x.shape[-1], f, name=n)() for n, f in shapes),
+                stream, cfg.jdtype)
+            if cfg.fused_qkv:
+                q, k, v = (outs[0][..., i, :, :] for i in range(3))
+            else:
+                q, k, v = outs[:3]
+            gated = outs[-1] if cfg.gating else None
+        elif cfg.fused_qkv:
             qkv = dense("qkv", (3, h, d))(x)  # [B,S,3,H,D]
             q, k, v = (qkv[..., i, :, :] for i in range(3))
         else:
@@ -852,17 +870,57 @@ class Attention(nn.Module):
         return out
 
 
+class Kernel(nn.Module):
+    """The ``kernel`` an ``nn.DenseGeneral(features, axis=-1)`` of the
+    same name holds, initialised alike, for products another function
+    makes (:func:`~tensorflowonspark_tpu.parallel.sharding.
+    gathered_products`)."""
+
+    in_dim: int
+    features: tuple
+
+    @nn.compact
+    def __call__(self):
+        def init(rng, shape, dtype=jnp.float32):
+            flat = (shape[0], math.prod(shape[1:]))
+            return jnp.reshape(
+                nn.initializers.lecun_normal()(rng, flat, dtype), shape)
+
+        return self.param(
+            "kernel", init, (self.in_dim,) + tuple(self.features))
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, stream=None):
+        """``stream`` as :class:`Attention` takes it."""
         cfg = self.cfg
-        wi = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.jdtype, name="wi")(x)
-        wg = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.jdtype, name="wg")(x)
+        if stream is None:
+            wi = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.jdtype, name="wi")(x)
+            wg = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.jdtype, name="wg")(x)
+        else:
+            wi, wg = sh.gathered_products(x, tuple(
+                Kernel(x.shape[-1], (cfg.mlp_dim,), name=n)()
+                for n in ("wi", "wg")), stream, cfg.jdtype)
         return nn.Dense(
             cfg.embed_dim, use_bias=False, dtype=cfg.jdtype, name="wo"
         )(nn.silu(wg) * wi)
+
+
+def residual_stream(cfg, x, decode):
+    """The shardings :func:`~tensorflowonspark_tpu.parallel.sharding.
+    residual_shardings` gives a training step's residual stream
+    ``[batch, seq, embed]`` (batch and seq: ``x``'s first two dims) —
+    tokens split over the ``model`` axis between blocks, gathered whole
+    inside each projection — or ``None``: decoding, a mesh without a
+    ``model`` axis the layout fits, or a model with latent attention or
+    routed experts, whose projections read whole tokens as given."""
+    if decode or cfg.attention_kind == "mla" or any(
+            cfg.ffn_kind(i) != "dense" for i in range(cfg.num_layers)):
+        return None
+    return sh.residual_shardings(cfg.mesh, x.shape[0], x.shape[1])
 
 
 class Block(nn.Module):
@@ -881,6 +939,12 @@ class Block(nn.Module):
         cfg = self.cfg
         norm = lambda name: RMSNorm(  # noqa: E731
             eps=cfg.rms_norm_eps, name=name)
+        # where the stream is split over tokens, norms and adds run on
+        # this chip's tokens; the projections gather them whole and
+        # hand back partial sums, summed into this chip's share
+        stream = residual_stream(cfg, x, decode)
+        add = lambda a: a if stream is None else sh.split_tokens(  # noqa: E731
+            a, stream)
         if cfg.attention_kind == "mla":
             from tensorflowonspark_tpu.models.mla import MLAttention
 
@@ -893,11 +957,11 @@ class Block(nn.Module):
               pad_start=pad_start, per_slot=per_slot, sel=sel)
             x = x + att
         else:
-            x = x + Attention(cfg, layer=self.layer, name="attn")(
+            x = x + add(Attention(cfg, layer=self.layer, name="attn")(
                 norm("ln1")(x), positions, decode=decode,
                 pad_start=pad_start, per_slot=per_slot,
-                block_tables=block_tables,
-            )
+                block_tables=block_tables, stream=stream,
+            ))
         h = norm("ln2")(x)
         kind = cfg.ffn_kind(self.layer)
         if kind == "sigmoid_moe":
@@ -943,8 +1007,8 @@ class Block(nn.Module):
                 name="moe",
             )(h)
         else:
-            ff = MLP(cfg, name="mlp")(h)
-        x = x + ff
+            ff = MLP(cfg, name="mlp")(h, stream=stream)
+        x = x + add(ff)
         return (x, sel) if cfg.attention_kind == "mla" else x
 
 
@@ -982,6 +1046,14 @@ class Transformer(nn.Module):
             (cfg.vocab_size, cfg.embed_dim),
         )
         x = emb[tokens].astype(cfg.jdtype)
+        stream = residual_stream(cfg, tokens, decode)
+        if not decode:
+            from tensorflowonspark_tpu import telemetry
+
+            telemetry.get_registry().gauge("train.residual_shards").set(
+                1 if stream is None else cfg.mesh.shape["model"])
+        if stream is not None:
+            x = sh.split_tokens(x, stream)
         if decode:
             # absolute positions continue from the cache write pointer
             # (one shared counter; the per-layer Attention counters
@@ -1052,9 +1124,15 @@ class Transformer(nn.Module):
         x = RMSNorm(eps=cfg.rms_norm_eps, name="ln_f")(x)
         # tied output head would shard awkwardly under TP; a separate
         # vocab projection keeps the ``vocab`` logical axis clean
-        logits = nn.Dense(
-            cfg.vocab_size, use_bias=False, dtype=cfg.jdtype, name="lm_head"
-        )(x)
+        if stream is not None and not last_only:
+            # whole tokens out: the loss slices positions
+            logits, = sh.gathered_products(x, (Kernel(
+                cfg.embed_dim, (cfg.vocab_size,), name="lm_head")(),),
+                stream, cfg.jdtype)
+        else:
+            logits = nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=cfg.jdtype,
+                name="lm_head")(x)
         return logits.astype(jnp.float32)
 
 

@@ -12,6 +12,7 @@ same spec is skipped (a mesh axis may shard at most one dimension of a
 given array).
 """
 
+import functools
 import logging
 
 import jax
@@ -198,6 +199,108 @@ def canonicalize_on_mesh(tree, mesh):
         return jax.device_put(x, replicated(mesh))
 
     return jax.tree.map(_fix, tree)
+
+
+def residual_shardings(mesh, batch, seq):
+    """``(split, whole)``: the two layouts of a training step's residual
+    stream ``[batch, seq, embed]`` under a ``model`` axis (Megatron's
+    sequence parallelism).  ``split`` puts tokens over ``model``: the
+    stream between blocks, where norms and residual adds run on each
+    chip's share of the tokens.  ``whole`` is the layout a
+    column-parallel projection reads (:func:`gathered_products`).
+    Batch goes over the data axes as :func:`batch_sharding` places it.
+    ``None`` where the layout does not engage: no mesh, a ``model`` axis
+    of 1, a ``seq`` axis wider than 1 (context parallelism lays tokens
+    out itself) or a sequence the ``model`` axis does not divide."""
+    shape = mesh.shape if mesh is not None else {}
+    tp = shape.get("model", 1)
+    if tp < 2 or seq % tp or shape.get("seq", 1) > 1:
+        return None
+    axes = tuple(a for a in ("data", "fsdp") if shape.get(a, 1) > 1)
+    if batch % int(np.prod([shape[a] for a in axes])):
+        axes = ()
+    return (NamedSharding(mesh, PartitionSpec(axes or None, "model", None)),
+            NamedSharding(mesh, PartitionSpec(axes or None, None, None)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def split_tokens(x, shardings):
+    """``x`` — partial sums on each chip: a row-parallel projection's
+    (attention's ``out``, the MLP's ``wo``) or the vocabulary-parallel
+    embedding's — summed into the ``split`` layout of
+    :func:`residual_shardings`.  Summed whole and sliced, which XLA
+    fuses into one reduce-scatter (constrained to ``split`` alone, GSPMD
+    may gather the embedding's table instead).  The gradient goes back
+    whole, an all-gather, before the projection's transpose reads it
+    (left to itself, GSPMD would keep it split and gather the
+    projection's weights)."""
+    split, whole = shardings
+    return jax.lax.with_sharding_constraint(
+        jax.lax.with_sharding_constraint(x, whole), split)
+
+
+def _split_tokens_fwd(x, shardings):
+    return split_tokens(x, shardings), None
+
+
+def _split_tokens_bwd(shardings, _, ct):
+    return (jax.lax.with_sharding_constraint(ct, shardings[1]),)
+
+
+split_tokens.defvjp(_split_tokens_fwd, _split_tokens_bwd)
+
+
+def _contract_last(x, kernel):
+    # x [..., E] . kernel [E, *features]: nn.Dense's and
+    # nn.DenseGeneral(axis=-1)'s product
+    return jax.lax.dot_general(x, kernel, (((x.ndim - 1,), (0,)), ((), ())))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def gathered_products(x, kernels, shardings, dtype):
+    """``x`` in the ``split`` layout of :func:`residual_shardings`
+    times each of ``kernels`` (``[E, *features]``, column-parallel:
+    split over ``model`` on a feature dim) with tokens whole, the
+    operands in ``dtype`` as ``nn.Dense(dtype=dtype)`` computes them.
+    Forward, an all-gather of ``x``.  Backward, ``x`` is gathered AGAIN
+    for the kernels' gradients rather than kept whole from the forward
+    (the whole copy would cost a chip ``S x E`` per projection input
+    for every layer), and ``dx`` — partial sums over ``model`` — is
+    reduce-scattered back to ``split``."""
+    whole = jax.lax.with_sharding_constraint(x, shardings[1]).astype(dtype)
+    return tuple(_contract_last(whole, k.astype(dtype)) for k in kernels)
+
+
+def _gathered_products_fwd(x, kernels, shardings, dtype):
+    return gathered_products(x, kernels, shardings, dtype), (x, kernels)
+
+
+def _gathered_products_bwd(shardings, dtype, res, cts):
+    x, kernels = res
+    split, whole = shardings
+    # split on both sides of a barrier that waits for the cotangents:
+    # else XLA's CSE, or the propagation of ``whole`` through the
+    # barrier, turns this gather back into the forward's, kept alive
+    x = jax.lax.with_sharding_constraint(x, split)
+    x, cts = jax.lax.optimization_barrier((x, cts))
+    x = jax.lax.with_sharding_constraint(x, split)
+    xw = jax.lax.with_sharding_constraint(x, whole).astype(dtype)
+    lead = tuple(range(x.ndim - 1))
+    dks = tuple(
+        jax.lax.dot_general(xw, ct, ((lead, lead), ((), ()))).astype(k.dtype)
+        for k, ct in zip(kernels, cts))
+    dx = None
+    for k, ct in zip(kernels, cts):
+        part = jax.lax.dot_general(
+            ct, k.astype(dtype),
+            ((tuple(range(x.ndim - 1, ct.ndim)), tuple(range(1, k.ndim))),
+             ((), ())))
+        dx = part if dx is None else jax.lax.add(dx, part)
+    dx = split_tokens(dx, shardings)
+    return dx.astype(x.dtype), dks
+
+
+gathered_products.defvjp(_gathered_products_fwd, _gathered_products_bwd)
 
 
 def batch_sharding(mesh, data_axes=("data", "fsdp")):

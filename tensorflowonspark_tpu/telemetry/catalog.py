@@ -144,6 +144,11 @@ METRICS = tuple(
          ("train.feed_wait_sec", "feed-starvation wait per step"),
          ("train.h2d_sec", "host→device transfer (straggler phase series)"),
          ("train.dispatch_sec", "step dispatch (straggler phase series)"))
+    + _m(_G, "Transformer (traced for training)",
+         ("train.residual_shards",
+          "token shards of the residual stream between blocks in the "
+          "training step last traced: the model axis's size where the "
+          "stream is split over it, else 1"))
     # --- parameter-server wire (parallel/ps.py) ---
     + _m(_C, "PSClient",
          ("ps.bytes_sent", "exact frame bytes onto the wire"),

@@ -481,3 +481,68 @@ def test_a_long_prompt_s_layer_compiles_for_v5e_with_its_scores_in_vmem(
         jax.ShapeDtypeStruct((1,), jnp.int32, sharding=dev)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_a_tp_training_step_compiles_for_v5e_with_its_stream_split_over_tokens(
+        mosaic):
+    """The training cell's step on a 2x2 v5e (``data=2 x model=2``,
+    flash at its blocks of 1024, narrow widths): between blocks the
+    residual stream holds half the tokens, so every sum of a row-parallel
+    projection's partial products over ``model`` is a reduce-scatter
+    (XLA:TPU's ``all-reduce-scatter`` fusion: all-reduce and slice in
+    one) and every column-parallel projection reads an all-gather — no
+    all-reduce of the stream is left on its own.  The Mosaic kernel
+    runs inside its ``shard_map`` on one chip's batch rows and heads."""
+    import re
+
+    from jax.experimental import topologies
+
+    from tensorflowonspark_tpu.models import transformer as tr
+    from tensorflowonspark_tpu.parallel import mesh as pmesh, sharding as sh
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu AOT in this env
+        pytest.skip("no TPU AOT topology here: %s" % e)
+    mesh = pmesh.build_mesh({"data": 2, "model": 2}, devices=topo.devices)
+    rows, seq, d, layers = 4, 2048, 256, 2
+    fields = dict(vocab_size=512, num_layers=layers, num_heads=4,
+                  num_kv_heads=2, head_dim=128, embed_dim=d, mlp_dim=512,
+                  max_seq_len=seq, block_q=1024, block_k=1024)
+    params = jax.eval_shape(lambda: tr.Transformer(tr.TransformerConfig(
+        **fields)).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    )["params"]
+    specs = sh.param_specs(params, sh.RULES_TP, mesh, tr.logical_axes(params))
+    params = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, s)), params, specs)
+    tokens = jax.ShapeDtypeStruct(
+        (rows, seq), jnp.int32, sharding=NamedSharding(mesh, P("data")))
+    loss = tr.loss_fn(tr.Transformer(tr.TransformerConfig(
+        **fields, attention_impl="flash", mesh=mesh)))
+    text = jax.jit(jax.value_and_grad(
+        lambda p, t: loss(p, {"tokens": t}, None))).lower(
+            params, tokens).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert kernels and all("[2,2,%d,128]" % seq in k or "[2,%d,2,128]" % seq
+                           in k for k in kernels)
+    stream = r"(bf16|f32)\[%d,%d,%d\]" % (rows // 2, seq, d)
+    where, scattered, reduced, gathered = None, 0, [], 0
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) ", line)
+        if head:
+            where = head.group(1)
+        elif re.search(r"= %s\S* all-reduce\(" % stream, line):
+            reduced.append(where)
+        elif re.search(r"= %s\S* all-gather\(" % stream.replace(
+                "(bf16|f32)", "bf16"), line):
+            assert "replica_groups=[2,2]<=[4]" in line
+            gathered += 1
+        if head and where.startswith("all-reduce-scatter"):
+            scattered += 1
+    # the embedding, and attention's out and the MLP's wo a layer, in
+    # the forward; the projections' dx and the head's in the backward
+    assert scattered >= 2 * (2 * layers + 1)
+    assert reduced and all(w.startswith("all-reduce-scatter") for w in reduced)
+    assert gathered >= 2 * layers + 1

@@ -3,6 +3,8 @@
 reference's analogue was a 2-worker local Spark Standalone cluster,
 reference: test/run_tests.sh:16-27)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -281,3 +283,189 @@ def test_multi_step_on_device_matches_multi_step():
     w_dev, l_dev = run(True)
     np.testing.assert_allclose(w_host, w_dev, rtol=1e-6)
     np.testing.assert_allclose(l_host, l_dev, rtol=1e-6)
+
+
+# ----------------------------------------------------------------------
+# the residual stream split over tokens between blocks under `model`
+# ----------------------------------------------------------------------
+
+TINY_LM = dict(
+    vocab_size=64, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8,
+    embed_dim=32, mlp_dim=64, max_seq_len=16, dtype="float32",
+    attention_impl="dot",
+)
+#: rows and tokens of the tiny step: 4 rows of 16, so 2 rows a data
+#: shard and 8 tokens a model shard
+TOKENS_SHAPE = (4, 16)
+
+
+def _tiny_step(axes):
+    """Loss and gradients of one step of the tiny LM under a mesh of
+    ``axes`` (None: no mesh), the same parameters and rows each time;
+    the step's compiled text and the ``train.residual_shards`` gauge
+    its trace set."""
+    from tensorflowonspark_tpu import telemetry
+    from tensorflowonspark_tpu.models import transformer as tr
+
+    params = tr.Transformer(tr.TransformerConfig(**TINY_LM)).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))["params"]
+    tokens = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(1), TOKENS_SHAPE, 0, 64), np.int32)
+    mesh = None
+    if axes is not None:
+        mesh = mesh_mod.build_mesh(
+            axes, devices=jax.devices()[:int(np.prod(list(axes.values())))])
+        params = sh.shard_params(
+            params, sh.RULES_TP, mesh, tr.logical_axes(params))
+        tokens = sh.shard_batch(tokens, mesh)
+    loss = tr.loss_fn(tr.Transformer(tr.TransformerConfig(
+        **TINY_LM, mesh=mesh)))
+    step = jax.jit(jax.value_and_grad(
+        lambda p, t: loss(p, {"tokens": t}, None)))
+    telemetry.get_registry().gauge("train.residual_shards").set(0)
+    compiled = step.lower(params, tokens).compile()
+    shards = telemetry.get_registry().snapshot()["gauges"][
+        "train.residual_shards"]
+    value, grads = step(params, tokens)
+    return float(value), jax.tree.map(np.asarray, grads), compiled, shards
+
+
+@pytest.fixture(scope="module")
+def unsharded_step():
+    return _tiny_step(None)
+
+
+@pytest.mark.parametrize("axes,shards", [
+    ({"data": 2, "model": 2}, 2),
+    ({"data": 2}, 1),
+    (None, 1),
+], ids=["data2-model2", "data2", "no-mesh"])
+def test_a_token_split_residual_stream_takes_the_same_step(
+        unsharded_step, axes, shards):
+    value, grads, compiled, gauge = _tiny_step(axes)
+    want_value, want_grads = unsharded_step[:2]
+    assert gauge == shards
+    np.testing.assert_allclose(value, want_value, rtol=1e-6)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
+    if shards == 1:
+        return
+    # the stream [rows of a data shard, S, D] is gathered whole over
+    # `model` (pairs of devices) inside the projections: on the CPU a
+    # reduce-scatter is an all-reduce sliced by its consumers, so the
+    # TPU form is held in tests/test_chip_lowering.py
+    text = compiled.as_text()
+    gathers = [line for line in text.splitlines()
+               if re.search(r"= f32\[2,16,32\]\S* all-gather\(", line)]
+    assert len(gathers) >= 2 * TINY_LM["num_layers"] + 1
+    assert all("replica_groups=[2,2]<=[4]" in g and "dimensions={1}" in g
+               for g in gathers)
+
+
+def text_of(lowered):
+    """A lowered program's StableHLO text with private functions
+    renumbered in order of appearance (their numbers depend on what the
+    process traced before)."""
+    seen = {}
+    return re.sub(r"@(\w+?)_\d+\b", lambda m: "@%s_%d" % (
+        m.group(1), seen.setdefault(m.group(0), len(seen))),
+        lowered.as_text())
+
+
+def bypassed_programs():
+    """``{program: sha256 of its StableHLO}`` of the programs the token
+    split must leave as they were — a training step on Moonlight's
+    shape of mesh (``{"data": 1}``), a SlotDecoder's prefill and decode
+    chunk with no mesh and under ``serving_mesh(tp=2)`` — and
+    ``"traces"``, the ``jit.trace`` count (with nested ones) of the tiny
+    step on the 2x2 mesh.  Run in a fresh process: JAX's caches of inner
+    traces make the count depend on what ran before."""
+    import hashlib
+    import time
+
+    from tensorflowonspark_tpu import telemetry
+    from tensorflowonspark_tpu.models import transformer as tr
+    from tensorflowonspark_tpu.telemetry import tracing
+
+    tracing.watch_jit()
+    out = {}
+    params = tr.Transformer(tr.TransformerConfig(**TINY_LM)).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))["params"]
+    tokens = jnp.zeros(TOKENS_SHAPE, jnp.int32)
+
+    def train_step(axes):
+        mesh = mesh_mod.build_mesh(
+            axes, devices=jax.devices()[:int(np.prod(list(axes.values())))])
+        loss = tr.loss_fn(tr.Transformer(tr.TransformerConfig(
+            **TINY_LM, mesh=mesh)))
+        p = sh.shard_params(params, sh.RULES_TP, mesh,
+                            tr.logical_axes(params))
+        return jax.jit(jax.value_and_grad(
+            lambda p, t: loss(p, {"tokens": t}, None))).lower(
+                p, sh.shard_batch(tokens, mesh))
+
+    lowered = {"train_data1": train_step({"data": 1})}
+    for name, tp in (("", None), ("_tp2", 2)):
+        from tensorflowonspark_tpu.parallel.mesh import serving_mesh
+
+        dec = tr.SlotDecoder(
+            tr.Transformer(tr.TransformerConfig(**TINY_LM)), params, 2, 4,
+            cache_len=32, chunk_size=2, pad_multiple=8,
+            mesh=serving_mesh(tp=tp))
+        lowered["prefill" + name] = dec._prefill_jit.lower(
+            dec._params, dec._dparams, dec.cache, dec.draft_cache,
+            dec.state, jnp.int32(0), jnp.zeros((1, 8), jnp.int32),
+            jnp.zeros((1,), jnp.int32), dec._next_key())
+        lowered["chunk" + name] = dec._chunk_jit.lower(
+            dec._params, dec.cache, dec.state,
+            jnp.zeros((2,), bool), None, dec._next_key(dec.chunk_size))
+    out = {k: hashlib.sha256(text_of(v).encode()).hexdigest()
+           for k, v in lowered.items()}
+    since = time.time()
+    train_step({"data": 2, "model": 2})
+    out["traces"] = sum(
+        1 + s["attrs"]["nested"]
+        for s in telemetry.get_tracer().spans(trace="jit")
+        if s["t0"] >= since and s["name"] == "jit.trace")
+    return out
+
+
+#: :func:`bypassed_programs` on the commit before the token split
+#: (jax 0.9.0)
+PARENT_PROGRAMS = {
+    "train_data1":
+        "931417f464ceaed0c6f56d40db309a34f83fa27b4c88774c20211f5738fbf3d7",
+    "prefill":
+        "a64c9ecbacc2325cf164e0d15bbefa095cd916224aa16e94f8f7f8389b415e38",
+    "chunk":
+        "76f1d67c687ca23701bad683cd90b93923c73790528df75cf7670da21e1986f5",
+    "prefill_tp2":
+        "c9bde7ed2e906bd798eeb86ed5be9a7c619990eb64920e50d23872b533b4f850",
+    "chunk_tp2":
+        "f893f9c757886301bd225f1751b8e14d4cf059fc97457477bb5927ee0faf509c",
+    "traces": 211,
+}
+
+
+def test_programs_off_the_token_split_lower_as_before():
+    import json
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = ("import json, sys; sys.path[:0] = [%r, %r]; "
+            "import test_parallel as t; "
+            "print(json.dumps(t.bypassed_programs()))") % (
+                here, os.path.dirname(here))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=here,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    traces = got.pop("traces")
+    want = dict(PARENT_PROGRAMS)
+    assert got == {k: v for k, v in want.items() if k != "traces"}
+    # the set-up budget: the split's step traces at most 1% more
+    assert traces <= want["traces"] * 1.01
